@@ -19,10 +19,9 @@ that ROADMAP items 1-3 keep piling more logic onto:
 ``transfer``
     No host-transfer primitives (``io_callback`` / ``pure_callback`` /
     ``debug_callback``, infeed/outfeed) inside a compiled hot program:
-    every device->host sync costs ~100-214 ms of round-trip latency on
-    a remote-device link regardless of size (docs/BENCH_NOTES_r05.md
-    "sync-latency model") — one stray ``jax.debug.print`` in the step
-    would erase the single-sync launch work.
+    every device->host sync stalls the launch for a host round trip —
+    one stray ``jax.debug.print`` in the step would erase the
+    single-sync launch work.
 
 ``donation``
     Every ``donate_argnums`` declaration that CAN alias (a donated
@@ -82,7 +81,7 @@ _ALIAS_ATTR = "tf.aliasing_output"
 # jaxpr plumbing
 # ---------------------------------------------------------------------------
 def _subjaxprs(param):
-    import jax.core as jc
+    import jax.extend.core as jc
 
     if isinstance(param, jc.ClosedJaxpr):
         return [param.jaxpr]
@@ -172,8 +171,8 @@ def _check_transfer(ep, closed) -> List[Finding]:
         Finding(
             ep.name, 0, "transfer",
             f"host-transfer primitive `{prim}` (x{n}) inside a compiled "
-            "hot program — every sync costs ~100-214 ms on a remote link "
-            "(docs/BENCH_NOTES_r05.md)",
+            "hot program — every sync stalls the launch for a host "
+            "round trip",
         )
         for prim, n in sorted(hits.items())
     ]
@@ -222,7 +221,7 @@ def _check_donation(ep, closed, args, traced) -> List[Finding]:
 
 
 def _check_g_last(ep, closed, G: int) -> List[Finding]:
-    import jax.core as jc
+    import jax.extend.core as jc
 
     seen: Dict[Tuple[str, tuple], int] = {}
     for eqn in _iter_eqns(closed.jaxpr):

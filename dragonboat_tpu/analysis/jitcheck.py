@@ -5,9 +5,9 @@ it cannot see drift that only exists at runtime: a shape that varies
 launch-to-launch, a weak-typed scalar leaking into an operand, an
 uncommitted array keying a second executable (jax keys compiled
 programs on shape/dtype/weak-type/sharding/committed-ness of every
-argument).  Each such retrace stalls a launch pipeline for seconds on
-a remote device (the r5 mid-run-compile finding: commits arrived ~25 s
-late), so the engines go to great lengths to pre-compile every shape
+argument).  Each such retrace stalls the launch pipeline for seconds
+(r5: commits arrived ~25 s late; PR 21 on four v5e chips: a 17 s
+compile inside the first write), so the engines go to great lengths to pre-compile every shape
 they will ever use (``VectorStepEngine._warm`` and the colocated
 ladder-tier warm).  This module turns that effort into a checked
 invariant:
@@ -40,11 +40,6 @@ def enable(on: bool = True) -> None:
     ENABLED = on
 
 
-def _cache_size(fn) -> int:
-    get = getattr(fn, "_cache_size", None)
-    return int(get()) if callable(get) else 0
-
-
 class Sentry:
     """Trace-cache watcher over a (name, jitted fn) list.
 
@@ -63,7 +58,7 @@ class Sentry:
         return registry.runtime_entry_points()
 
     def snapshot(self) -> Dict[str, int]:
-        return {name: _cache_size(fn) for name, fn in self.entries()}
+        return {name: fn._cache_size() for name, fn in self.entries()}
 
     def mark(self) -> None:
         """Declare 'warmup is complete as of now'."""

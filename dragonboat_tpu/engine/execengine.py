@@ -148,6 +148,13 @@ class ExecEngine:
         self._step_iters = self.metrics.counter(
             "raft_engine_step_iterations_total"
         )
+        # step_shards calls that raised: the worker survives (a raft
+        # library must outlive a failed launch) but the failure is
+        # counted — a plain int too, because a disabled registry no-ops
+        self.step_worker_failures = 0
+        self._step_failures = self.metrics.counter(
+            "raft_engine_step_worker_failures_total"
+        )
         # obs tentpole: the step-batch-size distribution is THE signal
         # separating "many idle wakeups" from "healthy batching" (the
         # single-fsync-per-iteration trick only pays when batches > 1);
@@ -257,6 +264,8 @@ class ExecEngine:
                 self._step_batch_hist.observe(len(nodes))
                 self._step_iters.add()
             except Exception:  # noqa: BLE001
+                self.step_worker_failures += 1
+                self._step_failures.add()
                 _log.exception("step worker %d failed", worker_id)
             # shards with remaining work re-arm immediately
             for n in nodes:

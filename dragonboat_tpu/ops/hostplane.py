@@ -1,6 +1,6 @@
 """Array-at-once host-plane machinery for the colocated launch path.
 
-The r5 ledger (docs/BENCH_NOTES_r05.md, Config 4) showed that at 250k
+An r5 run of Config 4 (50k shards, mixed 3/5/7) showed that at 250k
 replica rows the DEVICE plane costs ~4 s of a 2,731 s 50k-shard
 election while ``t_plan`` (887 s) and ``t_updates`` (538 s) — per-row
 Python in the colocated engine's plan and merge stages — dominate.

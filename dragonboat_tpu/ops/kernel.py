@@ -1728,10 +1728,7 @@ def make_step_sharded(  # mesh-hot
     """
     import jax as _jax
 
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:  # pragma: no cover - older jax spelling
-        from jax.experimental.shard_map import shard_map as _shard_map
+    from jax import shard_map as _shard_map
     from jax.sharding import PartitionSpec as _PS
 
     if len(mesh.axis_names) != 1:
@@ -1760,10 +1757,8 @@ def make_step_sharded(  # mesh-hot
     return _jax.jit(
         _shard_map(
             _local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            # the step body carries a lax.while_loop (slot
-            # compaction); jax 0.4.x has no replication rule for
-            # while under shard_map's rep checker — the specs
-            # here are all-sharded, so the check is vacuous
-            check_rep=False,
+            # every spec here is sharded, so the varying-axes check
+            # has nothing to verify; skip its trace-time cost
+            check_vma=False,
         )
     )

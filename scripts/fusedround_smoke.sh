@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# Fused-commit-round smoke (ISSUE 15, docs/BENCH_NOTES_r10.md): boot a
+# Fused-commit-round smoke (ISSUE 15): boot a
 # 3-replica colocated cluster with the launch pipeline at depth 2, a
 # 10 ms simulated sync floor and fused waves at the product default
 # (K=3), drive a small proposal workload with the hostplane parity

@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
-# Launch-pipeline smoke (double-buffered generations, docs/
-# BENCH_NOTES_r07.md): boot a 3-replica colocated cluster with the
+# Launch-pipeline smoke (double-buffered generations): boot a
+# 3-replica colocated cluster with the
 # pipeline at depth 2 and a 10 ms simulated sync floor
 # (DRAGONBOAT_TPU_SYNC_FLOOR_MS semantics via the engine kwarg), drive
 # a small proposal workload, then assert

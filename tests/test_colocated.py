@@ -9,7 +9,11 @@ steady state, payloads reconstruct across replicas through the shared
 entry cache, and the cold paths (reads, membership, restart) still
 work through the same materialize/re-upload dance as the base engine.
 """
+import json
+import os
 import shutil
+import subprocess
+import sys
 import time
 
 import pytest
@@ -447,3 +451,150 @@ class TestColocatedQuiesce:
         finally:
             for nh in nhs.values():
                 nh.close()
+
+
+class TestLaunchFailureIsCounted:
+    """A launch that dies must not be silent: the cluster survives it
+    (rows roll back and re-upload) and the failure shows in a counter
+    (``pipeline_resets`` on the core, ``step_worker_failures`` on the
+    exec engine) — what chip_smoke.py requires to be zero."""
+
+    def test_failed_launch_bumps_pipeline_resets(self, ccluster, monkeypatch):
+        from dragonboat_tpu.ops import colocated
+
+        group, nhs = ccluster
+        wait_for_leader(nhs)
+        s = nhs[1].get_noop_session(1)
+        propose_r(nhs[1], s, set_cmd("a", b"1"))
+        assert group.core.stats["pipeline_resets"] == 0
+
+        real = colocated._select_and_blob
+        fired = []
+
+        def select_raises_once(*a, **kw):
+            if not fired:
+                fired.append(1)
+                raise RuntimeError("injected launch failure")
+            return real(*a, **kw)
+
+        monkeypatch.setattr(colocated, "_select_and_blob",
+                            select_raises_once)
+        deadline = time.time() + 10.0
+        while not fired and time.time() < deadline:
+            time.sleep(0.01)
+        propose_r(nhs[1], s, set_cmd("b", b"2"), deadline=30.0)
+        assert group.core.stats["pipeline_resets"] == 1
+        assert sum(nh.engine.step_worker_failures
+                   for nh in nhs.values()) == 1
+        for rid in ADDRS:
+            assert read_r(nhs[rid], 1, "b") == b"2"
+
+    def test_raising_step_engine_bumps_step_worker_failures(
+        self, ccluster, monkeypatch
+    ):
+        group, nhs = ccluster
+        wait_for_leader(nhs)
+        eng = nhs[2].engine
+        real = eng.step_engine.step_shards
+        fired = []
+
+        def step_raises_once(nodes, worker_id):
+            if not fired:
+                fired.append(1)
+                raise RuntimeError("injected step failure")
+            return real(nodes, worker_id)
+
+        monkeypatch.setattr(eng.step_engine, "step_shards",
+                            step_raises_once)
+        deadline = time.time() + 10.0
+        while not fired and time.time() < deadline:
+            time.sleep(0.01)
+        assert eng.step_worker_failures == 1
+        s = nhs[1].get_noop_session(1)
+        propose_r(nhs[1], s, set_cmd("c", b"3"), deadline=30.0)
+        for rid in ADDRS:
+            assert read_r(nhs[rid], 1, "c") == b"3"
+
+
+class TestChipSmoke:
+    """chip_smoke.py is the script the driver runs on the accelerator;
+    here only its control flow can be checked, at 8 groups on the CPU."""
+
+    SMOKE = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "chip_smoke.py",
+    )
+
+    def _run(self, *flags):
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        return subprocess.run(
+            [sys.executable, self.SMOKE, *flags], env=env,
+            capture_output=True, text=True, timeout=120,
+        )
+
+    def test_dry_run_passes_and_is_labelled(self):
+        p = self._run("--allow-cpu", "--shards", "8")
+        assert p.returncode == 0, p.stderr[-2000:]
+        # two JSON lines: the report, then the driver's verdict last
+        rep, verdict = map(json.loads, p.stdout.strip().splitlines()[-2:])
+        assert set(verdict) == {"ok", "device"}
+        assert verdict["ok"] is True
+        assert set(verdict["device"]) == {"platform", "kind", "count"}
+        assert verdict["device"]["platform"] == "cpu"
+        assert isinstance(verdict["device"]["count"], int)
+        assert rep["ok"] is True and rep["dryrun"] is True
+        assert rep["device"] == verdict["device"]
+        assert set(rep) >= {
+            "ok", "device", "dryrun", "chips", "versions",
+            "compile_cache", "wal_writer", "wal_fs", "shards",
+            "replicas", "capacity", "seed", "setup_s",
+            "leader_coverage", "writes", "reads", "jitcheck_retraces",
+            "step_worker_failures", "host_rows_stepped_in_write_window",
+            "leaked_threads", "engine", "engine_write_window",
+            "blob_wait_ms_per_launch", "readback_probe", "checks",
+            "total_s",
+        }
+        assert set(rep["setup_s"]) == {"warmup_s", "boot_s", "election_s"}
+        assert set(rep["versions"]) == {"python", "jax", "jaxlib", "libtpu"}
+        assert rep["wal_writer"] == "native"
+        assert rep["leader_coverage"] == "8/8"
+        assert rep["writes"]["acked"] == 8 and rep["writes"]["failed"] == 0
+        assert rep["reads"] == {"linearizable_ok": "8/8",
+                                "replica_ok": "24/24"}
+        assert rep["jitcheck_retraces"] == []
+        assert all(rep["checks"].values()), rep["checks"]
+        for k in ("launches", "pipeline_resets", "t_plan_ms",
+                  "t_upload_ms", "t_dev_step_ms", "t_dev_route_ms",
+                  "t_dev_sel_ms", "t_dev_blob_ms", "t_detail_ms",
+                  "t_updates_ms", "t_persist_ms"):
+            assert k in rep["engine"], k
+
+    def test_refuses_to_run_without_an_accelerator(self):
+        p = self._run()
+        assert p.returncode != 0
+        assert p.stdout.strip() == ""
+        assert "no accelerator" in p.stderr
+
+    def test_compile_cache_rule(self, monkeypatch, tmp_path):
+        from dragonboat_tpu.ops import placement
+
+        class FakeJax:
+            class config:
+                calls = []
+
+                @classmethod
+                def update(cls, key, value):
+                    cls.calls.append((key, value))
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert placement.configure_compile_cache(FakeJax) == str(tmp_path)
+        # JAX reads the variable itself: no directory is set in code
+        assert [k for k, _ in FakeJax.config.calls] == [
+            "jax_persistent_cache_min_compile_time_secs"
+        ]
+
+        FakeJax.config.calls.clear()
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(os.path.dirname(self.SMOKE), ".jax_cache")
+        assert placement.configure_compile_cache(FakeJax) == want
+        assert ("jax_compilation_cache_dir", want) in FakeJax.config.calls

@@ -488,3 +488,32 @@ def test_mesh_tables_and_xbudget():
     assert xb >= 4
     with pytest.raises(ValueError):
         R.build_route_tables_mesh(shard_ids, replica_ids, peer_ids, 5)
+
+
+def test_engine_warm_set_covers_proposal_launch_on_mesh():
+    """The product engine's ``mesh=`` path: a host inbox that went
+    through ``_scatter_inbox_rows`` (the first launch carrying a
+    proposal) keys its own step executable under a mesh, because its
+    entry lanes come out row-sharded where the tick-built inbox leaves
+    them replicated.  ``chip_smoke.py --chips 4`` met it on the v5e as
+    a 17 s compile inside the first write (PR 21); ``_warm()`` must
+    have compiled it."""
+    from dragonboat_tpu.ops import colocated as C
+    from dragonboat_tpu.ops.types import I32, Inbox
+
+    G, M, E, O = 16, 8, 2, 16
+    core = C.ColocatedVectorEngine(
+        capacity=G, P=3, W=16, M=M, E=E, O=O, budget=4, mesh=_mesh(4)
+    )
+    warmed = C._assemble_and_step._cache_size()
+    combo = core._put_rows(jnp.zeros((G, 4), jnp.int32))
+    host = C._host_inbox_from_ticks(combo, M=M, E=E)
+    host = C._scatter_inbox_rows(
+        host,
+        core._put_rows(jnp.full((G,), -1, jnp.int32)),
+        core._put(Inbox(*(jnp.zeros((1,) + f.shape[1:], I32)
+                          for f in host))),
+    )
+    C._assemble_and_step(core._state, host, core._pending, combo,
+                         out_capacity=O)
+    assert C._assemble_and_step._cache_size() == warmed

@@ -446,8 +446,7 @@ class TestLaneSlotPersistReadback:
     via ``_hs_sync``) must compose for replicas that have ONLY ever
     saved through the lane path — such a replica has no classic node
     store yet, and an early-return on that miss read its durable lane
-    words back as None (the PR-15 db-parity rot recorded in
-    docs/BENCH_NOTES_r10.md, fixed this PR)."""
+    words back as None (the PR-15 db-parity rot, since fixed)."""
 
     def test_lane_only_replica_reads_back(self):
         from dragonboat_tpu.storage.logdb import InMemLogDB
