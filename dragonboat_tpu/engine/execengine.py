@@ -24,6 +24,7 @@ from typing import Dict, List, Optional, TYPE_CHECKING
 
 from ..logger import get_logger
 from ..metrics import MetricsRegistry
+from ..profiling import annotate
 from ..utils.stopper import Stopper
 
 if TYPE_CHECKING:
@@ -164,6 +165,11 @@ class ExecEngine:
             bounds=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024),
         )
         self._apply_hist = self.metrics.histogram("raft_engine_apply_seconds")
+        # what the apply workers did, always on: per worker slot
+        # [batches, entries, seconds inside node.apply(), seconds the
+        # batches waited between hand-off and apply].  One writer a
+        # slot; apply_totals() sums them for a reader on any thread
+        self._apply_acc = [[0, 0, 0.0, 0.0] for _ in range(apply_workers)]
         self.step_ready = WorkReady(step_workers)
         self.apply_ready = WorkReady(apply_workers)
         self.step_engine = step_engine or HostStepEngine(logdb)
@@ -248,6 +254,11 @@ class ExecEngine:
     def notify_many(self, shard_ids) -> None:
         self.step_ready.notify_all(shard_ids)
 
+    def apply_totals(self) -> tuple:
+        """``(apply_batches, apply_entries, t_apply_s, t_apply_wait_s)``
+        over all apply workers since start."""
+        return tuple(sum(a[i] for a in self._apply_acc) for i in range(4))
+
     # -- workers ----------------------------------------------------------
     def _step_worker_main(self, worker_id: int) -> None:
         while not self._stop.is_set():
@@ -279,11 +290,18 @@ class ExecEngine:
                 return
             with self._nodes_lock:
                 nodes = [self._nodes[s] for s in ready if s in self._nodes]
+            acc = self._apply_acc[worker_id]
             for node in nodes:
                 try:
                     t0 = time.perf_counter()
-                    node.apply()
-                    self._apply_hist.observe(time.perf_counter() - t0)
+                    with annotate("raft-apply"):
+                        batches, entries, wait_s = node.apply()
+                    dt = time.perf_counter() - t0
+                    self._apply_hist.observe(dt)
+                    acc[0] += batches
+                    acc[1] += entries
+                    acc[2] += dt
+                    acc[3] += wait_s
                 except Exception:  # noqa: BLE001
                     _log.exception(
                         "apply worker %d shard %d failed", worker_id, node.shard_id

@@ -41,6 +41,8 @@ from typing import Dict, Optional
 from ..client import LatencyBudget, Session
 from ..logger import get_logger
 from ..metrics import MetricsRegistry
+from ..node import LEASE_HELD, LEASE_MISS_UNREPORTED
+from ..profiling import annotate
 from ..readplane import (
     BOUND_TICKS_DEFAULT,
     Consistency,
@@ -60,6 +62,17 @@ from .admission import AdmissionController
 from .routing import RoutingCache
 
 _log = get_logger("gateway")
+
+_WORKER_COUNTERS = ("proposed", "t_queue_wait_ms", "t_ack_lag_ms",
+                    "poll_checks", "poll_passes")
+# stats() keys "read_fallback_<name>", in node.LEASE_MISS_* order from 1
+_LEASE_MISS_KEYS = ("not_leader", "no_commit_in_term", "apply_lag",
+                    "lease_expiring")
+
+# orders GatewayFuture.add_done_callback against _complete.  One lock
+# for every future, not one each: it is held for two stores, and a
+# future per request should stay an Event and four slots
+_CALLBACKS_LOCK = threading.Lock()
 
 
 class GatewayBusy(SystemBusy):
@@ -135,19 +148,48 @@ class _ShardLoadState:
 
 
 class GatewayFuture:
-    """Completion future for one gateway proposal."""
+    """Completion future for one gateway proposal.  ``t_done`` is
+    ``time.monotonic()`` at completion (0.0 until then)."""
 
-    __slots__ = ("_event", "_result", "_exc")
+    __slots__ = ("_event", "_result", "_exc", "_callbacks", "t_done")
 
     def __init__(self):
         self._event = threading.Event()
         self._result = None
         self._exc: Optional[BaseException] = None
+        self._callbacks: Optional[list] = None  # under _CALLBACKS_LOCK
+        self.t_done = 0.0
 
     def _complete(self, result=None, exc: Optional[BaseException] = None):
         self._result = result
         self._exc = exc
-        self._event.set()
+        self.t_done = time.monotonic()
+        with _CALLBACKS_LOCK:
+            # set under the lock: add_done_callback decides under it
+            # whether to queue or to call, so no callback is lost
+            self._event.set()
+            callbacks, self._callbacks = self._callbacks, None
+        for fn in callbacks or ():
+            self._call(fn)
+
+    def _call(self, fn) -> None:
+        try:
+            fn(self)
+        except Exception:  # noqa: BLE001 — a caller's callback must not
+            # take down the completing thread (a gateway worker)
+            _log.exception("gateway future callback raised")
+
+    def add_done_callback(self, fn) -> None:
+        """Call ``fn(future)`` once when the future completes, on the
+        completing thread; at once, on this thread, if it already has.
+        An exception out of ``fn`` is logged and swallowed."""
+        with _CALLBACKS_LOCK:
+            if not self._event.is_set():
+                if self._callbacks is None:
+                    self._callbacks = []
+                self._callbacks.append(fn)
+                return
+        self._call(fn)
 
     def done(self) -> bool:
         return self._event.is_set()
@@ -164,7 +206,7 @@ class GatewayFuture:
 
 class _GwReq:
     __slots__ = ("handle", "cmd", "deadline", "future", "t_admit",
-                 "ambiguous")
+                 "ambiguous", "proposed")
 
     def __init__(self, handle, cmd: bytes, deadline: float):
         self.handle = handle
@@ -172,6 +214,7 @@ class _GwReq:
         self.deadline = deadline
         self.future = GatewayFuture()
         self.t_admit = time.monotonic()
+        self.proposed = False  # its first nh.propose went out
         # True once ANY attempt of this op may have committed (a node-
         # side timeout, or termination with the outcome unobserved):
         # the series must then be burned on EVERY terminal path, not
@@ -269,6 +312,10 @@ class Gateway:
         self._fallback_reads = self.metrics.counter(
             "gateway_read_fallback_total"
         )
+        # why each read left the lease, indexed by node.LEASE_MISS_*
+        # (slot 0, LEASE_HELD, stays 0); bumped beside _fallback_reads
+        # under the same lock-free-ish convention
+        self._lease_miss = [0] * (LEASE_MISS_UNREPORTED + 1)
         # read-plane counters (docs/READPLANE.md): one per served path
         # plus sheds; pre-resolved so the read path never takes the
         # registry lock (counter() locks on lookup)
@@ -320,6 +367,22 @@ class Gateway:
         self._wake_events = [
             threading.Event() for _ in range(self.config.workers)
         ]
+        # always-on counters of the propose path, one dict a worker
+        # (each written by that worker alone) and summed in stats()
+        self._worker_acc = [
+            dict.fromkeys(_WORKER_COUNTERS, 0)
+            for _ in range(self.config.workers)
+        ]
+        # both sets as gauges too, read at scrape
+        for key in _WORKER_COUNTERS:
+            self.metrics.gauge(
+                "gateway_" + key, lambda k=key: self._worker_total(k)
+            )
+        for i, name in enumerate(_LEASE_MISS_KEYS):
+            self.metrics.gauge(
+                "gateway_read_fallback_" + name,
+                lambda i=i: self._lease_miss[i + 1],
+            )
         self._workers = [
             threading.Thread(
                 target=self._worker_main,
@@ -630,22 +693,26 @@ class Gateway:
         The poll cadence (5ms with work in flight) bounds the added
         completion latency."""
         ev = self._wake_events[idx]
+        acc = self._worker_acc[idx]
         pending = []  # (req, rs) submitted, awaiting completion
         while not self._closed:
             ev.wait(timeout=0.005 if pending else 0.05)
             ev.clear()
-            for sid in self._my_lanes(idx):
-                for req in self._drain(sid, self.config.max_batch):
-                    rs = self._propose_once(req)
-                    if rs is not None:
-                        pending.append((req, rs))
-            if pending:
-                still = []
-                for req, rs in pending:
-                    nrs = self._poll_finish(req, rs)
-                    if nrs is not None:
-                        still.append((req, nrs))
-                pending = still
+            with annotate("gateway-poll"):
+                for sid in self._my_lanes(idx):
+                    for req in self._drain(sid, self.config.max_batch):
+                        rs = self._propose_once(req, acc)
+                        if rs is not None:
+                            pending.append((req, rs))
+                if pending:
+                    acc["poll_passes"] += 1
+                    acc["poll_checks"] += len(pending)
+                    still = []
+                    for req, rs in pending:
+                        nrs = self._poll_finish(req, rs, acc)
+                        if nrs is not None:
+                            still.append((req, nrs))
+                    pending = still
         for req, _rs in pending:
             # submitted but unresolved at close: may still commit
             req.ambiguous = True
@@ -673,10 +740,12 @@ class Gateway:
                 continue
         return None
 
-    def _propose_once(self, req: _GwReq):
+    def _propose_once(self, req: _GwReq, acc: dict):
         """One submission attempt; completes the future on terminal
-        errors, returns the RequestState otherwise."""
-        remaining = req.deadline - time.monotonic()
+        errors, returns the RequestState otherwise.  ``acc`` is the
+        calling worker's counters."""
+        now = time.monotonic()
+        remaining = req.deadline - now
         if remaining <= 0:
             # expired while queued (e.g. behind a retrying predecessor
             # on its handle): fail BEFORE submission — a doomed submit
@@ -692,6 +761,10 @@ class Gateway:
             self._fail(req, ShardNotFound(
                 f"no live host for shard {req.handle.shard_id}"))
             return None
+        acc["proposed"] += 1
+        if not req.proposed:
+            req.proposed = True
+            acc["t_queue_wait_ms"] += (now - req.t_admit) * 1000.0
         try:
             return nh.propose(req.handle.session, req.cmd, remaining)
         except Exception as e:  # noqa: BLE001 — classified below
@@ -699,11 +772,12 @@ class Gateway:
             self._fail(req, e)
             return None
 
-    def _poll_finish(self, req: _GwReq, rs):
+    def _poll_finish(self, req: _GwReq, rs, acc: dict):
         """Non-blocking completion check for one submitted request.
         Returns None when the gateway future was completed (done,
         failed, or timed out), else the RequestState — possibly a NEW
-        one after a dedupe-safe resubmission — to keep polling."""
+        one after a dedupe-safe resubmission — to keep polling.
+        ``acc`` is the calling worker's counters."""
         from ..nodehost import _CODE_ERRORS, TimeoutError_
 
         if not rs._event.is_set():
@@ -720,7 +794,9 @@ class Gateway:
             return None
         code = rs.code
         if code == RequestResultCode.COMPLETED:
-            lat = time.monotonic() - req.t_admit
+            now = time.monotonic()
+            lat = now - req.t_admit
+            acc["t_ack_lag_ms"] += (now - rs.t_notified) * 1000.0
             if req.handle.is_exactly_once():
                 req.handle.session.proposal_completed()
             self.budget.observe(lat)
@@ -758,7 +834,7 @@ class Gateway:
         if retryable and req.deadline - time.monotonic() > 0.01:
             # pacing comes from the node round trip + the poll cadence
             self.routes.invalidate(req.handle.shard_id)
-            return self._propose_once(req)  # None => future completed
+            return self._propose_once(req, acc)  # None => future completed
         err = _CODE_ERRORS.get(code, TimeoutError_)
         self._fail(req, err(code.name if code is not None else "unknown"))
         return None
@@ -842,14 +918,15 @@ class Gateway:
             nh = self._live_hosts().get(key)
             if nh is not None and not getattr(nh, "_closed", False):
                 try:
-                    ok, val = nh.try_lease_read(
+                    why, val = nh.lease_read(
                         shard_id, query,
                         margin_ticks=self.config.lease_margin_ticks,
                     )
-                    if ok:
+                    if why == LEASE_HELD:
                         self._lease_reads.add()
                         self._count_read(PATH_LEASE)
                         return ReadResult(val, PATH_LEASE, host=key)
+                    self._lease_miss[why] += 1
                 except Exception:  # noqa: BLE001 — host/shard stopping:
                     # fall through to the quorum path
                     self.routes.invalidate(shard_id)
@@ -1046,6 +1123,9 @@ class Gateway:
         _log.warning("gateway overload: %s", dump[:4000])
 
     # -- observability ----------------------------------------------------------
+    def _worker_total(self, key: str):
+        return sum(acc[key] for acc in self._worker_acc)
+
     def stats(self) -> dict:
         with self._done_lock:
             committed = self._committed.value
@@ -1057,6 +1137,16 @@ class Gateway:
             "shed_dumps": self.admission.dumps,
             "lease_reads": self._lease_reads.value,
             "read_fallbacks": self._fallback_reads.value,
+            # why: the four sum to read_fallbacks less the reads that
+            # had no route, whose host raised, or whose host is remote
+            **{
+                "read_fallback_" + name: self._lease_miss[i + 1]
+                for i, name in enumerate(_LEASE_MISS_KEYS)
+            },
+            # the propose path, summed over the workers: submissions
+            # (retries included), admit -> first propose, node's notify
+            # -> the poll that saw it, pending pairs examined and passes
+            **{k: self._worker_total(k) for k in _WORKER_COUNTERS},
             # per-consistency-path serve counts + the router's observed
             # per-replica p99 (the read plane's ledger row inputs)
             "read_paths": dict(self._read_paths),

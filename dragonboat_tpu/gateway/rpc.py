@@ -43,6 +43,7 @@ from typing import Dict, Optional
 
 from ..client import SERIES_ID_FIRST_PROPOSAL, Session
 from ..logger import get_logger
+from ..node import LEASE_HELD, LEASE_MISS_UNREPORTED
 from ..obs.fleetscope import ObsService, ObsUnsupported
 from ..obs.trace import UNSAMPLED
 from ..nodehost import (
@@ -507,7 +508,8 @@ class _RemoteCall:
     ``_event.is_set()`` without any lock)."""
 
     __slots__ = ("req_id", "op", "noop", "sent", "expires", "code",
-                 "result", "resp", "error", "span", "traced", "_event")
+                 "result", "resp", "error", "span", "traced", "_event",
+                 "t_notified")
 
     def __init__(self, req_id: int, op: int, noop: bool, expires: float):
         self.req_id = req_id
@@ -524,6 +526,7 @@ class _RemoteCall:
         self.span = None
         self.traced = False
         self._event = threading.Event()
+        self.t_notified = 0.0  # as RequestState's
 
     def notify(self, code: RequestResultCode, result=None, resp=None,
                error: str = "") -> None:
@@ -531,6 +534,7 @@ class _RemoteCall:
         self.result = result
         self.resp = resp
         self.error = error
+        self.t_notified = time.monotonic()
         self._event.set()
         sp = self.span
         if sp is not None:
@@ -954,6 +958,12 @@ class RemoteHostHandle:
         if rc.wait(self._lease_timeout + 0.25) != RequestResultCode.COMPLETED:
             return False, None
         return True, decode_rpc_value(rc.result.data)
+
+    def lease_read(self, shard_id: int, query, margin_ticks: int = 2):
+        """``NodeHost.lease_read``'s shape for the gateway.  The wire
+        carries no reason, so a miss reads ``LEASE_MISS_UNREPORTED``."""
+        ok, value = self.try_lease_read(shard_id, query, margin_ticks)
+        return (LEASE_HELD if ok else LEASE_MISS_UNREPORTED), value
 
     def sync_read(self, shard_id: int, query, timeout: float = 5.0):
         rc = self._submit(

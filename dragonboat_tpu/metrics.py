@@ -326,6 +326,3 @@ class MetricsRegistry:
             out.append(f"{base}_count{suffix} {h.count}")
         return "\n".join(out) + "\n"
 
-
-# module-level default used by components not owned by a NodeHost
-global_registry = MetricsRegistry(enabled=True)

@@ -12,6 +12,7 @@ import dataclasses
 import os
 import random
 import threading
+import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 from .client import Session
@@ -63,6 +64,14 @@ from .storage.snapshotio import SnapshotReader, _try_snappy
 _SYSRAND = random.SystemRandom()
 
 _log = get_logger("nodehost")
+
+# Node.lease_probe's answers: held, or the condition that failed first
+LEASE_HELD = 0
+LEASE_MISS_NOT_LEADER = 1  # not leader, stopping, or check_quorum off
+LEASE_MISS_NO_COMMIT_IN_TERM = 2  # a fresh leader: commit index unproven
+LEASE_MISS_APPLY_LAG = 3  # last_applied < committed
+LEASE_MISS_EXPIRING = 4  # margin_ticks or fewer left (or the probe raced a step)
+LEASE_MISS_UNREPORTED = 5  # a remote host's miss: the RPC carries no reason
 
 
 class StepInputs:
@@ -1011,9 +1020,11 @@ class Node:
     # ------------------------------------------------------------------
     # post-save processing (owning step worker; logdb write already done)
     # ------------------------------------------------------------------
-    def process_update(self, u: Update) -> bool:
+    def process_update(self, u: Update, now: float = 0.0) -> bool:
         """reference: node.processRaftUpdate + commitRaftUpdate [U].
-        Returns True if apply work was scheduled."""
+        Returns True if apply work was scheduled.  ``now`` is the
+        caller's ``perf_counter()`` stamp when it hands a whole batch of
+        updates over (one clock read a batch, not one an update)."""
         if self._trace_spans:
             self._trace_update(u)
         scheduled = False
@@ -1053,9 +1064,10 @@ class Node:
             # the read index may already be applied (idle shard): complete now
             self.pending_read_index.applied(self.sm.last_applied)
         if u.committed_entries:
-            self.sm.task_queue.add(
-                Task(type=TaskType.ENTRIES, entries=u.committed_entries)
-            )
+            self.sm.task_queue.add(Task(
+                type=TaskType.ENTRIES, entries=u.committed_entries,
+                t_handoff=now or time.perf_counter(),
+            ))
             scheduled = True
         self.peer.commit(u)
         return scheduled
@@ -1068,17 +1080,25 @@ class Node:
     # ------------------------------------------------------------------
     # apply path (owning apply worker only)
     # ------------------------------------------------------------------
-    def apply(self) -> None:
+    def apply(self) -> tuple:
         """Drain the task queue through the RSM (reference:
-        engine applyWorkerMain -> rsm Handle [U])."""
+        engine applyWorkerMain -> rsm Handle [U]).  Returns ``(batches,
+        entries, wait_s)``: ENTRIES tasks applied, their entries, and
+        the seconds they sat between hand-off and this drain."""
         with self._apply_lock:
             if self.stopped:
-                return
-            self._apply_locked()
+                return 0, 0, 0.0
+            return self._apply_locked()
 
-    def _apply_locked(self) -> None:
+    def _apply_locked(self) -> tuple:
+        batches = entries = 0
+        wait_s = 0.0
+        t_start = time.perf_counter()
         for task in self.sm.task_queue.get_all():
             if task.type == TaskType.ENTRIES:
+                batches += 1
+                entries += len(task.entries)
+                wait_s += t_start - task.t_handoff
                 results = self.sm.handle(task)
                 self._complete_applied(results)
                 self._applied_since_snapshot += len(task.entries)
@@ -1093,6 +1113,7 @@ class Node:
             self._applied_since_snapshot = 0
             with self._qlock:
                 self._snapshot_reqs.append((0, self.config.compaction_overhead))
+        return batches, entries, wait_s
 
     def _complete_applied(self, results: List[ApplyResult]) -> None:
         for r in results:
@@ -1349,8 +1370,11 @@ class Node:
     # ------------------------------------------------------------------
     # leader-lease reads (gateway/ front plane; docs/GATEWAY.md)
     # ------------------------------------------------------------------
-    def lease_remaining_ticks(self) -> int:
-        """Ticks of CheckQuorum leader lease left, or 0 when no lease.
+    def lease_probe(self, margin_ticks: int = 0) -> Tuple[int, int]:
+        """Why this replica may not serve a lease read, and the ticks of
+        CheckQuorum leader lease left: ``(LEASE_HELD, n)`` with ``n >
+        margin_ticks``, or one of the four ``LEASE_MISS_*`` reasons
+        (the gateway counts them, ``read_fallback_*``).
 
         The lease argument (docs/GATEWAY.md "Lease-read safety"): with
         ``check_quorum`` on, every follower refuses to grant votes while
@@ -1371,34 +1395,42 @@ class Node:
 
         Callers keep a safety margin (ticks are per-host logical
         clocks; the hosts' tickers drift) — see
-        ``NodeHost.try_lease_read``.  Lock-free probe off producer
+        ``NodeHost.lease_read``.  Lock-free probe off producer
         threads: every field read is one GIL-atomic load, and a lease
-        lost immediately after a True answer is exactly the race the
+        lost immediately after a held answer is exactly the race the
         margin exists for."""
         if self.stopped or self.stopping:
-            return 0
+            return LEASE_MISS_NOT_LEADER, 0
         r = self.peer.raft
         if not r.check_quorum or not self.peer.is_leader():
-            return 0
+            return LEASE_MISS_NOT_LEADER, 0
         try:
             if not r.committed_entry_in_current_term():
-                return 0
+                return LEASE_MISS_NO_COMMIT_IN_TERM, 0
             if self.sm.last_applied < r.log.committed:
-                return 0
+                return LEASE_MISS_APPLY_LAG, 0
             # inside the guard too: it copies the membership dicts,
             # which a concurrently-applying config change mutates
             # (review finding — "dictionary changed size" would crash
             # a metrics scrape)
-            return r.lease_remaining_ticks()
+            left = r.lease_remaining_ticks()
         except Exception:  # noqa: BLE001 — racing a concurrent step's
             # log/membership mutation (compaction/append/config
             # change): no lease this probe
-            return 0
+            return LEASE_MISS_EXPIRING, 0
+        if left > margin_ticks:
+            return LEASE_HELD, left
+        return LEASE_MISS_EXPIRING, left
+
+    def lease_remaining_ticks(self) -> int:
+        """Ticks of CheckQuorum leader lease left, or 0 when no lease
+        (:meth:`lease_probe` says which condition failed)."""
+        return self.lease_probe()[1]
 
     def lease_held(self, margin_ticks: int = 2) -> bool:
         """True when the CheckQuorum lease has more than ``margin_ticks``
         left — the gateway's fast-read gate."""
-        return self.lease_remaining_ticks() > margin_ticks
+        return self.lease_probe(margin_ticks)[0] == LEASE_HELD
 
     def bounded_read_probe(self, bound_ticks: int) -> tuple:
         """BOUNDED_STALENESS serving gate (readplane/,

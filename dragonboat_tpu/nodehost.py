@@ -19,7 +19,7 @@ from .engine.execengine import ExecEngine
 from .events import EventFanout
 from .logger import get_logger
 from .metrics import MetricsRegistry
-from .node import Node
+from .node import LEASE_HELD, Node
 from .obs.trace import UNSAMPLED
 from .pb import (
     ConfigChange,
@@ -310,6 +310,26 @@ class NodeHost:
                 metrics=self.metrics,
             )
             self.engine.start()
+            # the always-on counters of the engine and of this host's
+            # apply workers (docs/OBSERVABILITY.md "Counters"), read at
+            # scrape: nothing on the hot path.  A colocated core is
+            # shared, so every member exports the same engine numbers
+            stats = getattr(step_engine, "stats", None) or {}
+            for key in stats:
+                if key.startswith(("t_", "wal_", "device_rows_")) or (
+                    key == "launches"
+                ):
+                    self.metrics.gauge(
+                        "raft_engine_" + key, lambda k=key: stats[k]
+                    )
+            for i, key in enumerate((
+                "apply_batches", "apply_entries", "t_apply_s",
+                "t_apply_wait_s",
+            )):
+                self.metrics.gauge(
+                    "raft_nodehost_" + key,
+                    lambda i=i: self.engine.apply_totals()[i],
+                )
 
             self._ticks_paused = False
             self._ticker_stop = threading.Event()
@@ -842,17 +862,28 @@ class NodeHost:
         per-read ReadIndex quorum round trip, iff this replica holds a
         CheckQuorum leader lease with more than ``margin_ticks`` to
         spare (gateway/ fast-read path; safety argument in
-        ``Node.lease_remaining_ticks`` and docs/GATEWAY.md).  Returns
+        ``Node.lease_probe`` and docs/GATEWAY.md).  Returns
         ``(True, value)`` on a lease-served read, ``(False, None)``
         when the caller must fall back to :meth:`read_index`/
         :meth:`sync_read`.  The margin absorbs tick drift between
         hosts and the probe-to-lookup race; requires the shard's
         ``Config.check_quorum`` or the lease is never held."""
+        why, value = self.lease_read(shard_id, query, margin_ticks)
+        return why == LEASE_HELD, value
+
+    def lease_read(
+        self, shard_id: int, query, margin_ticks: int = 2
+    ) -> tuple:
+        """:meth:`try_lease_read` with the reason: ``(LEASE_HELD,
+        value)`` on a lease-served read, else ``(why, None)`` with
+        ``why`` one of ``node.LEASE_MISS_*`` — what the gateway counts
+        as ``read_fallback_*`` to say why reads leave the lease."""
         node = self._get_node(shard_id)
-        if not node.lease_held(margin_ticks):
-            return False, None
+        why = node.lease_probe(margin_ticks)[0]
+        if why != LEASE_HELD:
+            return why, None
         self._count_read("lease")
-        return True, node.lookup(query)
+        return LEASE_HELD, node.lookup(query)
 
     def lease_status(self, shard_id: int) -> dict:
         """Lease observability probe (tests, metrics scrapes)."""

@@ -44,13 +44,17 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os as _os
 import threading
+import time as _time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+# resolved once, here: the launch loop opens a dozen regions a launch
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from ..analysis import jitcheck
 from ..engine.execengine import IStepEngine
@@ -117,13 +121,9 @@ from .types import (
     make_inbox,
     make_state,
 )
-from ..metrics import global_registry as _metrics
 
 _log = get_logger("engine")
-
-import os as _os
-
-_DEBUG_LAUNCH = _os.environ.get("COLOC_DEBUG_LAUNCH", "") == "1"
+_perf = _time.perf_counter
 
 # -- double-buffered generations (the launch pipeline) -----------------
 # DRAGONBOAT_TPU_PIPELINE_DEPTH: how many generations may be in flight
@@ -603,6 +603,13 @@ class ColocatedVectorEngine(VectorStepEngine):
         # one generation's effects merge into another replica's row)
         self._free_pending: List[int] = []
         self._last_worker_id = 0
+        # the launch clock (see _phase): the counter being charged, and
+        # since when.  Between step calls that is t_between_ms
+        self._ph_key = "t_between_ms"
+        self._ph_t = _perf()
+        # the member NodeHosts' facades, whose apply workers' totals
+        # _fold_apply folds into stats
+        self._members: List["_ColocatedFacade"] = []
         super().__init__(None, capacity=capacity, P=P, W=W, M=M, E=E, O=O,
                          device=device, mesh=mesh)
         # nemesis escalations are consumed at plan time here: routed
@@ -616,11 +623,36 @@ class ColocatedVectorEngine(VectorStepEngine):
         self.stats.update(
             launches=0, routed_delivered=0, routed_host_carried=0,
             routed_dropped=0, coalesced_rows=0, shard_rebases=0,
-            # cumulative wall-time breakdown (ms) of the launch path —
-            # the single-core CPU backend hides where a 65k-row launch
-            # goes without it
-            t_coalesce_ms=0, t_plan_ms=0, t_upload_ms=0, t_device_ms=0,
-            t_detail_ms=0, t_updates_ms=0, t_persist_ms=0,
+            # ---- the account of the core's wall time (ms), always on;
+            # docs/OBSERVABILITY.md "Counters".  A step call's time is
+            # t_launch_ms and the time between calls t_between_ms: the
+            # two add up to the wall clock.  Inside a call every
+            # millisecond is charged to exactly ONE phase (_phase: a
+            # phase that runs inside another, as a lane persist does
+            # inside the detail pass, is taken out of the outer one),
+            # so the phases add up to t_launch_ms; t_misc_ms is the glue
+            # between them (polling the in-flight readbacks for
+            # readiness, in-flight bookkeeping, the retry drain)
+            t_launch_ms=0.0, t_between_ms=0.0, t_misc_ms=0.0,
+            t_coalesce_ms=0.0, t_plan_ms=0.0, t_upload_ms=0.0,
+            t_encode_ms=0.0, t_dispatch_ms=0.0, t_dev_blob_ms=0.0,
+            t_merge_ms=0.0, t_detail_ms=0.0, t_updates_ms=0.0,
+            t_persist_ms=0.0, t_wake_ms=0.0,
+            # not phases: the wait of step workers for the core lock
+            # (summed over workers, so it overlaps other workers'
+            # launches), and the part of t_persist_ms spent inside the
+            # log database's save calls
+            t_lock_wait_ms=0.0, t_wal_ms=0.0,
+            # what those saves cost the WAL (ILogDB.wal_counts deltas)
+            wal_appends=0, wal_bytes=0, wal_records=0,
+            # batch rows whose host inbox held more than ticks
+            # (proposals, host-carried messages, reads), as opposed to
+            # device_rows_stepped, which counts tick-only rows too
+            device_rows_active=0,
+            # the apply workers' totals over every member NodeHost,
+            # folded in once a step call (_fold_apply)
+            apply_batches=0, apply_entries=0, t_apply_ms=0.0,
+            t_apply_wait_ms=0.0,
             # pipeline observability: host work overlapped with an
             # in-flight readback request (the double-buffering win),
             # fences (drains to depth 0 forced by membership mutation),
@@ -645,6 +677,80 @@ class ColocatedVectorEngine(VectorStepEngine):
     def _compute_base(self, r) -> int:
         # the SHARD's shared base, not a per-row quantity — see __init__
         return self._shard_base.get(r.shard_id, 0)
+
+    # -- the launch clock ------------------------------------------------
+    def _phase(self, key: str) -> str:
+        """Charge the time since the last switch to the phase that was
+        running, and run ``key`` from now.  Returns the phase that was
+        running, for the caller to switch back to.  Caller holds the
+        core lock: the clock is the core's, not a thread's."""
+        now = _perf()
+        prev = self._ph_key
+        self.stats[prev] += (now - self._ph_t) * 1000.0
+        self._ph_key = key
+        self._ph_t = now
+        return prev
+
+    def _enter(self, key: str, region: str) -> Tuple:
+        """Open a phase: the clock, and the same extent as a named
+        region on the profiler's clock.  Plain calls, not a ``with``:
+        the phases are long stretches of the launch path, and a phase
+        left open by an exception is closed by the next step call."""
+        ann = _TraceAnnotation(region)
+        ann.__enter__()
+        return self._phase(key), ann
+
+    def _leave(self, tok: Tuple) -> None:
+        self._phase(tok[0])
+        tok[1].__exit__(None, None, None)
+
+    def _fold_apply(self) -> None:
+        """Add what the members' apply workers did since the last call.
+        They are other threads and count on their own ExecEngine; the
+        launch thread reads the totals here, under the core lock, so
+        ``stats`` keeps one writer and lags by one step call at most."""
+        st = self.stats
+        for member in self._members:
+            tot = member.apply_totals()
+            seen = member.apply_folded
+            if tot != seen:
+                st["apply_batches"] += tot[0] - seen[0]
+                st["apply_entries"] += tot[1] - seen[1]
+                st["t_apply_ms"] += (tot[2] - seen[2]) * 1000.0
+                st["t_apply_wait_ms"] += (tot[3] - seen[3]) * 1000.0
+                member.apply_folded = tot
+
+    # -- persist: one phase over every call, the WAL's share inside -----
+    def _persisting(self, persist, work, worker_id: int) -> None:
+        if not work:
+            return
+        tok = self._enter("t_persist_ms", "raft-colocated-persist")
+        try:
+            persist(work, worker_id)
+        finally:
+            self._leave(tok)
+
+    def _persist_and_process(self, updates, worker_id: int) -> None:
+        self._persisting(super()._persist_and_process, updates, worker_id)
+
+    def _persist_lane_batches(self, batches, worker_id: int) -> None:
+        self._persisting(super()._persist_lane_batches, batches, worker_id)
+
+    def _persist_lane_rows(self, rows, worker_id: int) -> None:
+        self._persisting(super()._persist_lane_rows, rows, worker_id)
+
+    def _db_save(self, db, save, *args) -> None:
+        before = db.wal_counts()
+        t0 = _perf()
+        try:
+            save(*args)
+        finally:
+            st = self.stats
+            st["t_wal_ms"] += (_perf() - t0) * 1000.0
+            after = db.wal_counts()
+            st["wal_appends"] += after[0] - before[0]
+            st["wal_bytes"] += after[1] - before[1]
+            st["wal_records"] += after[2] - before[2]
 
     def _lease_pass(self, live, flags, vals_np, pos_sum,
                     tick_fed) -> None:
@@ -1259,8 +1365,6 @@ class ColocatedVectorEngine(VectorStepEngine):
         only the remainder — the overlap the pipeline exists for."""
         if self._sync_floor_s <= 0:
             return
-        import time as _time
-
         rem = self._sync_floor_s - (_time.monotonic() - t_req)
         if rem > 0:
             _time.sleep(rem)
@@ -1333,8 +1437,6 @@ class ColocatedVectorEngine(VectorStepEngine):
         # the room anyway).  Racy peeks of the in-flight deque are
         # benign — the in-lock paths re-check everything.
         if self._sync_floor_s > 0 and self._inflight:
-            import time as _time
-
             # bounded at ONE floor from entry: under multi-worker
             # contention the oldest in-flight keeps getting fresher
             # (another worker merges + redispatches), and an unbounded
@@ -1358,8 +1460,20 @@ class ColocatedVectorEngine(VectorStepEngine):
                 ):
                     break
                 _time.sleep(min(rem, 0.002))
+        t_wait = _perf()
+        waiting = _TraceAnnotation("raft-colocated-lockwait")
+        waiting.__enter__()
         with self._lock:
-            self._step_colocated(nodes, worker_id)
+            waiting.__exit__(None, None, None)
+            self.stats["t_lock_wait_ms"] += (_perf() - t_wait) * 1000.0
+            self._phase("t_misc_ms")  # closes t_between_ms
+            t_in = self._ph_t
+            try:
+                self._step_colocated(nodes, worker_id)
+            finally:
+                self._phase("t_between_ms")
+                self.stats["t_launch_ms"] += (self._ph_t - t_in) * 1000.0
+                self._fold_apply()
 
     def _coalesce(self, nodes) -> List:
         """Pull every other attached node with queued work into this
@@ -1378,8 +1492,6 @@ class ColocatedVectorEngine(VectorStepEngine):
         # work was notified, so its own exec worker delivers it in
         # `nodes` on an upcoming generation; coalescing is a batching
         # optimization, not a delivery guarantee.
-        import time as _time
-
         now = _time.monotonic()
         # interval scales with the measured scan cost (>=10x) so the
         # scan can never consume more than ~10% of wall time: at 250k
@@ -1488,8 +1600,6 @@ class ColocatedVectorEngine(VectorStepEngine):
         return super()._plan_device(node, si, mirror_leader, g)
 
     def _step_colocated(self, nodes, worker_id: int) -> None:
-        import time as _time
-
         self._last_worker_id = worker_id
         # ---- opportunistic completion: the earliest ripe sync -------
         # Merge any in-flight generation whose readback has LANDED
@@ -1502,9 +1612,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         while self._inflight:
             rec = self._inflight[0]
             if self._sync_floor_s > 0:
-                import time as _t
-
-                if _t.monotonic() - rec.t_req < self._sync_floor_s:
+                if _time.monotonic() - rec.t_req < self._sync_floor_s:
                     break
             # EVERY round's blobs must have landed: the merge may read
             # any round's detail payload too, and blocking the core
@@ -1528,11 +1636,11 @@ class ColocatedVectorEngine(VectorStepEngine):
         updates: List[Tuple] = []
         host_rows: List[Tuple] = []
         batch: List[Tuple] = []
-        _t0 = _time.perf_counter()
+        tok = self._enter("t_coalesce_ms", "raft-colocated-coalesce")
         nodes = self._coalesce(nodes)
         self._maybe_rebase_shards(nodes)
-        self.stats["t_coalesce_ms"] += (_time.perf_counter() - _t0) * 1000.0
-        _t0 = _time.perf_counter()
+        self._leave(tok)
+        tok = self._enter("t_plan_ms", "raft-colocated-plan")
         n_fast = 0
         # ---- batched plan classifier --------------------------------
         # ONE vectorized pass over the SoA lanes (ops/hostplane.py)
@@ -1669,11 +1777,11 @@ class ColocatedVectorEngine(VectorStepEngine):
             self.stats["fast_lane_rows"] = self.stats.get(
                 "fast_lane_rows", 0
             ) + n_fast
-        self.stats["t_plan_ms"] += (_time.perf_counter() - _t0) * 1000.0
+        self._leave(tok)
         launched = False
         if batch or self._pending_live:
             if self._pending_live or any(plan for _, _, _, plan in batch):
-                _t0 = _time.perf_counter()
+                tok = self._enter("t_upload_ms", "raft-colocated-upload")
                 dirty_lane = self._lanes.dirty  # one load; np bool [G]
                 self._upload_rows(
                     [
@@ -1682,12 +1790,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                         if dirty_lane[g]
                     ]
                 )
-                # float ms: lazy upload streams many sub-ms batches and
-                # int truncation under-reports the aggregate (same fix
-                # as t_up_pack_ms/t_up_scatter_ms)
-                self.stats["t_upload_ms"] += (
-                    (_time.perf_counter() - _t0) * 1000.0
-                )
+                self._leave(tok)
                 self._launch_generation(batch)
                 launched = True
             else:
@@ -1726,9 +1829,7 @@ class ColocatedVectorEngine(VectorStepEngine):
 
         self._drain_update_retries(updates)
         if updates:
-            _t0 = _time.perf_counter()
             self._persist_and_process(updates, worker_id)
-            self.stats["t_persist_ms"] += (_time.perf_counter() - _t0) * 1000.0
         if self._inflight:
             # completion guarantee: a dispatched generation must be
             # merged even if no member ever has work again — poke ONE
@@ -1738,6 +1839,7 @@ class ColocatedVectorEngine(VectorStepEngine):
             # batch measurably serialized the 1-core bench.  A
             # pending-live-only launch has an EMPTY batch (review
             # finding), so fall back to any alive resident node.
+            tok = self._enter("t_wake_ms", "raft-colocated-wake")
             poked = False
             for node, _g, _si, _plan in batch:
                 if not node.stopped and node.notify_work is not None:
@@ -1754,6 +1856,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                     ):
                         meta.node.notify_work()
                         break
+            self._leave(tok)
 
     def _sel_cover(self, G, caps, counts, sel_rows, sets):  # hostplane-hot
         """Index-array coverage of the device's single-sync row
@@ -2015,6 +2118,10 @@ class ColocatedVectorEngine(VectorStepEngine):
                 self._persist_and_process(
                     room_updates, self._last_worker_id
                 )
+        # encode: the batch into inbox rows, the per-launch [G] inputs
+        # and the host inbox onto the device — everything between the
+        # row upload and the first program of the wave
+        tok = self._enter("t_encode_ms", "raft-colocated-encode")
         G, M, E, P, B = self.capacity, self.M, self.E, self.P, self.budget
         # staging keys in ASSEMBLED coordinates: the routed regions
         # (width P*B) come first, host slots after (see _assemble_inbox)
@@ -2103,7 +2210,6 @@ class ColocatedVectorEngine(VectorStepEngine):
                 rounds = self._fuse_rounds
                 self.stats["fused_waves"] += 1
                 self.stats["fused_rounds_stepped"] += rounds
-                _metrics.counter("fused_waves_total").add(1)
             else:
                 self.stats["fused_fences"] += 1
         combo_np[:, _C_ALIVE] = alive_np
@@ -2135,37 +2241,16 @@ class ColocatedVectorEngine(VectorStepEngine):
             )
 
         old_state = self._state
-        import time as _time
-
-        from ..profiling import annotate
-
         if self._pending is None:
             # a prior launch failure consumed the donated pending inbox
             # and could not rebuild it (see the handler below)
             self._pending = self._put_rows(make_inbox(G, P * B, E))
-        if _DEBUG_LAUNCH:
-            # debug-only sync, FUSED into one device_get (three
-            # separate gets are three round trips): how much PRIOR
-            # device work (uploads, materialize, scatters) is in flight?
-            import sys as _sys
-            _td = _time.perf_counter()
-            # raftlint: ignore[sync-budget] debug-gated pre-launch probe, one fused get
-            _t1g, _occ_h, _occ_p = jax.device_get((
-                old_state.term[:1],
-                (host_inbox.mtype != 0).sum(axis=1),
-                (self._pending.mtype != 0).sum(axis=1),
-            ))
-            print(
-                f"[pre ] prior-work wait "
-                f"{(_time.perf_counter() - _td) * 1000:.0f} ms "
-                f"n_occ_max={int((_occ_h + _occ_p).max())} "
-                f"occ_mean={float((_occ_h + _occ_p).mean()):.2f} "
-                f"ticks_max={int(tick_counts.max())}",
-                file=_sys.stderr, flush=True,
-            )
-        _t0 = _time.perf_counter()
+        self._leave(tok)
+        # dispatch: host time to enqueue the wave's programs (the device
+        # runs them behind the host; its own time is in the trace)
+        dispatching = self._phase("t_dispatch_ms")
         try:
-            with annotate("raft-colocated-step"):
+            with _TraceAnnotation("raft-colocated-step"):
                 # fused assemble+step with host/pending donated, and
                 # new_state donated into route (dead after the merge):
                 # minimizes per-generation device allocations (see
@@ -2174,19 +2259,12 @@ class ColocatedVectorEngine(VectorStepEngine):
                     old_state, host_inbox, self._pending, combo,
                     out_capacity=self.O,
                 )
-                self.stats["t_dev_step_ms"] = self.stats.get(
-                    "t_dev_step_ms", 0
-                ) + (_time.perf_counter() - _t0) * 1000.0
-                _t1 = _time.perf_counter()
                 merged, regions, stats_dev, packed_dev, flags_dev = (
                     _route_step(
                         old_state, new_state, out, self._dest_dev,
                         self._rank_dev, combo, PB=P * B, E=E, budget=B,
                     )
                 )
-                self.stats["t_dev_route_ms"] = self.stats.get(
-                    "t_dev_route_ms", 0
-                ) + (_time.perf_counter() - _t1) * 1000.0
         except BaseException:
             # self._pending was DONATED above; leaving the deleted
             # buffer in place would poison every later generation with
@@ -2211,8 +2289,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         self._pending = regions
         self._state = merged
         try:
-            with annotate("raft-colocated-select"):
-                _t1 = _time.perf_counter()
+            with _TraceAnnotation("raft-colocated-select"):
                 # the wave's one commit-proving readback, requested NOW
                 # and collected at merge time: flags + delivered +
                 # counts + row ids + vals in each round's head, heavy
@@ -2267,25 +2344,14 @@ class ColocatedVectorEngine(VectorStepEngine):
                     merged_l.append(merged_k)
                     out_l.append(out_k)
                     _sel(merged_k, out_k, stats_k, packed_k, flags_k)
-                self.stats["t_dev_sel_ms"] = self.stats.get(
-                    "t_dev_sel_ms", 0
-                ) + (_time.perf_counter() - _t1) * 1000.0
         except BaseException:
             self._reset_after_pipeline_failure()
             raise
-        self.stats["t_device_ms"] += (_time.perf_counter() - _t0) * 1000.0
+        self._phase(dispatching)
         self.stats["launches"] += 1
         self.stats["device_steps"] += rounds
         self.stats["device_rows_stepped"] += len(batch)
-        if _DEBUG_LAUNCH:
-            import sys as _sys
-
-            print(
-                f"[launch {self.stats['launches']}] tier="
-                f"{self._sel_tier} batch={len(batch)} rounds={rounds} "
-                f"inflight={len(self._inflight) + 1}",
-                file=_sys.stderr, flush=True,
-            )
+        self.stats["device_rows_active"] += len(sparse)
         self._inflight.append(_InFlightGen(
             batch=batch, staging=staging, alive_np=alive_np,
             batch_gs=batch_gs, prop_gs=prop_gs, caps=caps,
@@ -2381,8 +2447,6 @@ class ColocatedVectorEngine(VectorStepEngine):
           (new words vs last HOST sync) sees it.  Skipping this leg
           stranded mid-wave commits' futures forever (found by the
           one-readback test's first soak)."""
-        import time as _time
-
         G = self.capacity
         n_buf_d, n_slot_d, n_need_d, n_append_d, n_sum_d = (
             int(x) for x in sel_counts
@@ -2407,7 +2471,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                 "detail_skipped", 0
             ) + 1
             return
-        _t0 = _time.perf_counter()
+        tok = self._enter("t_updates_ms", "raft-colocated-updates")
         cover = self._sel_cover(
             G, caps,
             (n_buf_d, n_slot_d, n_need_d, n_append_d, n_sum_d),
@@ -2618,7 +2682,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                 w_abs[_R_COMMIT] += b_abs
                 w_abs[_R_LAST] += b_abs
                 self._ulanes.words[:, gs_ok] = w_abs
-        self.stats["t_updates_ms"] += (_time.perf_counter() - _t0) * 1000.0
+        self._leave(tok)
 
     def _complete_generation(self, rec: _InFlightGen) -> List[Tuple]:  # sync-hot
         """Merge one in-flight generation: collect each round's head
@@ -2633,8 +2697,6 @@ class ColocatedVectorEngine(VectorStepEngine):
         the wave's end state, so every row emits at most ONE update
         per wave.  Caller holds the core lock; generations complete in
         dispatch order (_complete_oldest)."""
-        import time as _time
-
         G, M, E, P, B = self.capacity, self.M, self.E, self.P, self.budget
         batch, staging, caps = rec.batch, rec.staging, rec.caps
         alive_np, batch_gs, prop_gs = (
@@ -2659,9 +2721,13 @@ class ColocatedVectorEngine(VectorStepEngine):
         for rnd in range(K):
             final = rnd == K - 1
             round_props = prop_gs if rnd == 0 else empty_gs
-            _t0 = _time.perf_counter()
+            tok = self._enter("t_dev_blob_ms", "raft-colocated-readback")
             _tc = _time.monotonic()
             head = self._collect_blob(rec.head_dev[rnd], rec.t_req)
+            self._leave(tok)
+            # merge: parse the head, classify the round's rows, list
+            # the live ones — up to where the detail pass starts
+            tok = self._enter("t_merge_ms", "raft-colocated-merge")
             if rnd == 0 and self._pipeline_depth > 1:
                 # host-side work done between the D2H request
                 # (dispatch) and this collect ran concurrently with
@@ -2671,13 +2737,6 @@ class ColocatedVectorEngine(VectorStepEngine):
                 if self._sync_floor_s > 0:
                     overlap = min(overlap, self._sync_floor_s)
                 self.stats["pipeline_overlap_s"] += overlap
-                _metrics.counter(
-                    "pipeline_overlap_seconds_total"
-                ).add(overlap)
-            self.stats["t_dev_blob_ms"] = self.stats.get(
-                "t_dev_blob_ms", 0
-            ) + (_time.perf_counter() - _t0) * 1000.0
-            self.stats["t_device_ms"] += (_time.perf_counter() - _t0) * 1000.0
             (flags, delivered_bits, rstats, sel_counts, sel_rows,
              sel_vals) = self._parse_head(head, caps, G, nw)
             (sel_rows_buf, sel_rows_slot, sel_rows_need,
@@ -2755,6 +2814,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                     sel_counts, sel_rows, sel_vals, needs_max, touched,
                     esc_seen,
                 )
+                self._leave(tok)
                 continue
 
             # ================= FINAL round ===========================
@@ -2798,7 +2858,8 @@ class ColocatedVectorEngine(VectorStepEngine):
         n_buf_d, n_slot_d, n_need_d, n_append_d, n_sum_d = (
             int(x) for x in sel_counts
         )
-        _t0 = _time.perf_counter()
+        self._leave(tok)
+        tok = self._enter("t_detail_ms", "raft-colocated-detail")
         # device-selected detail (the split-blob fast path): the head
         # already carries counts/row-ids/vals for the rows the DEVICE
         # selected with the same flag logic; verify the host's sets are
@@ -2932,7 +2993,6 @@ class ColocatedVectorEngine(VectorStepEngine):
                 self._sel_fit_streak = 0
         else:
             self._sel_fit_streak = 0
-        self.stats["t_detail_ms"] += (_time.perf_counter() - _t0) * 1000.0
         # device-plane lease evidence (ROADMAP 4b): advance each batch
         # row's CheckQuorum window mirror and anchor the scalar voting
         # remotes when the quorum-active flag holds — BEFORE the bulk
@@ -2951,13 +3011,14 @@ class ColocatedVectorEngine(VectorStepEngine):
                     live, flags, pos_sum, pos_buf, pos_slot, pos_need,
                     vals_np, early_done,
                 )
+        self._leave(tok)
+        tok = self._enter("t_updates_ms", "raft-colocated-updates")
         # one C-level conversion for the merge loop's 10-ints-per-row
         # reads (numpy scalar -> int costs ~100 ns each)
         vals_l = vals_np.tolist() if vals_np is not None else None
 
         from .engine import SLOT_DROPPED
 
-        _t0 = _time.perf_counter()
         # ---- per-row effect merge, batch-indexed ---------------------
         # Everything the loop used to look up per row (gather positions
         # via the *_at dicts, flag probes, bases, delivered-bit unpack,
@@ -3135,7 +3196,7 @@ class ColocatedVectorEngine(VectorStepEngine):
             node.dispatch_dropped(u)
             updates.append((node, u))
             node._check_leader_change()
-        self.stats["t_updates_ms"] += (_time.perf_counter() - _t0) * 1000.0
+        self._leave(tok)
 
         lanes = [t for t in snapshot_sends if t[2] is not None]
         if lanes:
@@ -3163,11 +3224,16 @@ class ColocatedVectorEngine(VectorStepEngine):
             # node's engine so some worker launches again and the
             # messages are consumed (lane scan — the notify itself is
             # per-node, but dirty rows no longer pay a Python probe)
+            tok = self._enter("t_wake_ms", "raft-colocated-wake")
             for g in np.nonzero(self._lanes.alive_mask())[0].tolist():
                 meta = self._meta.get(g)
                 if meta is not None and meta.node.notify_work is not None:
                     meta.node.notify_work()
+            self._leave(tok)
         return updates
+
+
+_NO_APPLY = (0, 0, 0.0, 0.0)  # ExecEngine.apply_totals() of no work
 
 
 class _ColocatedFacade(IStepEngine):
@@ -3175,8 +3241,12 @@ class _ColocatedFacade(IStepEngine):
     ExecEngine drives).  Tracks shard -> replica so ``detach(shard_id)``
     — the IStepEngine contract — releases only THIS host's replica."""
 
-    def __init__(self, core: ColocatedVectorEngine):
+    def __init__(self, core: ColocatedVectorEngine, nodehost=None):
         self.core = core
+        self._nodehost = nodehost
+        # its apply totals as of the core's last _fold_apply (written
+        # there, under the core lock)
+        self.apply_folded = _NO_APPLY
         self._replica_of: Dict[int, int] = {}
 
     @property
@@ -3187,6 +3257,20 @@ class _ColocatedFacade(IStepEngine):
         for n in nodes:
             self._replica_of[n.shard_id] = n.replica_id
         self.core.step_shards(nodes, worker_id)
+
+    def apply_totals(self) -> tuple:
+        """This member's ``ExecEngine.apply_totals()``; zeros until the
+        NodeHost has built its engine (the factory runs before that)."""
+        engine = getattr(self._nodehost, "engine", None)
+        return engine.apply_totals() if engine is not None else _NO_APPLY
+
+    def stop(self) -> None:
+        # the member is going away: take its last totals, then forget it
+        core = self.core
+        with core._lock:
+            core._fold_apply()
+            if self in core._members:
+                core._members.remove(self)
 
     def device_coordinate(self, shard_id: int):
         return self.core.device_coordinate(
@@ -3231,4 +3315,9 @@ class ColocatedEngineGroup:
         with self._lock:
             if self._core is None:
                 self._core = ColocatedVectorEngine(**self._kw)
-            return _ColocatedFacade(self._core)
+            core = self._core
+        facade = _ColocatedFacade(core, nodehost)
+        if nodehost is not None:
+            with core._lock:
+                core._members.append(facade)
+        return facade

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -505,7 +506,8 @@ def _plan_lane_words(  # hostplane-hot
     return uplan
 
 
-def _apply_lane_commit(node, ce, notify: bool = True) -> None:
+def _apply_lane_commit(node, ce, now: float,
+                       notify: bool = True) -> None:
     """The lane rows' post-save apply handoff — one definition for the
     slot-batched and list-fallback persist paths (both MUST run it
     only after the row's save landed: persist-before-apply,
@@ -517,10 +519,15 @@ def _apply_lane_commit(node, ce, notify: bool = True) -> None:
     linger), 32x fewer slices on the commit-wave path.
 
     ``notify=False`` defers the apply-worker wakeup to the caller —
-    the batched per-SM-worker handoff (:func:`_apply_lane_commits`)."""
+    the batched per-SM-worker handoff (:func:`_apply_lane_commits`).
+    ``now`` is the batch's hand-off stamp (``t_apply_wait_ms`` runs
+    from it to the apply worker's start)."""
     if node._trace_spans:
         node._trace_committed(ce)
-    node.sm.task_queue.add(Task(type=TaskType.ENTRIES, entries=ce))
+    node.sm.task_queue.add(Task(
+        type=TaskType.ENTRIES, entries=ce,
+        t_handoff=now,
+    ))
     log = node.peer.raft.log
     log.processed = ce[-1].index
     im = log.inmem
@@ -549,8 +556,9 @@ def _apply_lane_commits(handoffs) -> None:
     batched save ALREADY landed — the persist-before-apply order is
     the caller's contract, unchanged."""
     by_wr: Dict[int, Tuple] = {}
+    now = time.perf_counter()  # one stamp for the whole batch
     for node, ce in handoffs:
-        _apply_lane_commit(node, ce, notify=False)
+        _apply_lane_commit(node, ce, now, notify=False)
         # getattr: bespoke node doubles (bench twins, direct-drive
         # tests) predate the hook and keep the per-row path
         wr = getattr(node, "apply_work_ready", None)
@@ -1511,6 +1519,12 @@ class VectorStepEngine(IStepEngine):
         meta.dirty = True
         meta.set_escalation_hold(node.config)
 
+    def _db_save(self, db, save, *args) -> None:
+        """Every save of the three persist paths below enters the log
+        database here, so an engine that accounts for its WAL time
+        (the colocated core) overrides one method."""
+        save(*args)
+
     def _persist_and_process(self, updates, worker_id: int) -> None:
         """save -> send/apply with per-LogDB fault isolation.  A failed
         batched save loses nothing: peer.commit(u) never ran for those
@@ -1524,7 +1538,8 @@ class VectorStepEngine(IStepEngine):
             )
         for db, pairs in by_db.values():
             try:
-                db.save_raft_state([u for _, u in pairs], worker_id)
+                self._db_save(db, db.save_raft_state,
+                              [u for _, u in pairs], worker_id)
             except Exception:  # noqa: BLE001
                 self.stats["save_failures"] += 1
                 _log.exception(
@@ -1534,8 +1549,9 @@ class VectorStepEngine(IStepEngine):
                 self._on_save_failure(pairs)
                 continue
             self._on_save_ok(pairs)
+            now = time.perf_counter()  # the batch's apply hand-off stamp
             for node, u in pairs:
-                if node.process_update(u):
+                if node.process_update(u, now):
                     node.engine_apply_ready(node.shard_id)
 
     def _persist_lane_batches(self, batches, worker_id: int) -> None:
@@ -1561,8 +1577,8 @@ class VectorStepEngine(IStepEngine):
                 in batches:
             n += len(slots)
             try:
-                db.save_state_slots(slots, terms, votes, commits,
-                                    worker_id)
+                self._db_save(db, db.save_state_slots, slots, terms,
+                              votes, commits, worker_id)
             except Exception:  # noqa: BLE001
                 self.stats["save_failures"] += 1
                 _log.exception(
@@ -1643,7 +1659,8 @@ class VectorStepEngine(IStepEngine):
                             s = get_slot(node.shard_id, node.replica_id)
                             node.hs_lane_slot = s
                         slots.append(s)
-                    save_slots(
+                    self._db_save(
+                        db, save_slots,
                         slots,
                         [t[1] for t in rs],
                         [t[2] for t in rs],
@@ -1651,7 +1668,8 @@ class VectorStepEngine(IStepEngine):
                         worker_id,
                     )
                 else:
-                    db.save_state_lanes(
+                    self._db_save(
+                        db, db.save_state_lanes,
                         [t[0].shard_id for t in rs],
                         [t[0].replica_id for t in rs],
                         [t[1] for t in rs],

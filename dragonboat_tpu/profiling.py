@@ -1,33 +1,40 @@
 """Kernel tracing/profiling hooks (SURVEY §5.1).
 
-The reference's observability for hot loops is Go pprof; the TPU-native
-equivalent is the JAX/XLA device profiler (xplane traces viewable in
-TensorBoard/xprof).  This module is a thin, dependency-light wrapper so
-the engine and the bench can be traced without importing jax at module
-scope anywhere in the host runtime.
+The reference's observability is metrics + pprof; the TPU engine's
+equivalent is the JAX profiler: device kernel timelines (XLA ops of the
+vectorized step / routed round) land in a TensorBoard-loadable trace.
 
-Usage:
+Usage::
+
     from dragonboat_tpu.profiling import trace, annotate
 
-    with trace("/tmp/raft-xplane"):
-        ... run a workload ...            # device trace captured
+    with trace("/tmp/raft-trace"):        # whole-cluster run
+        ... drive a NodeHost with a vector step engine ...
 
     with annotate("device-step"):         # named region in the trace
-        ... kernel launch ...
+        state, out = kernel.step(state, inbox)
 
-``BENCH_PROFILE=<dir> python bench.py`` captures the timed window.
+Open the trace dir with TensorBoard's profile plugin (or xprof).
 """
 from __future__ import annotations
 
 import contextlib
+import sys
+
+_NO_REGION = contextlib.nullcontext()
 
 
 @contextlib.contextmanager
-def trace(log_dir: str):
-    """Capture a JAX profiler trace (xplane) into ``log_dir``."""
+def trace(logdir: str):
+    """Capture a JAX profiler trace (device + host) into ``logdir``.
+    Without the Python call tracer: it slows the traced program several
+    times over and buries the trace; the host's share is told by the
+    :func:`annotate` regions."""
     import jax
 
-    jax.profiler.start_trace(log_dir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=options)
     try:
         yield
     finally:
@@ -35,7 +42,12 @@ def trace(log_dir: str):
 
 
 def annotate(name: str):
-    """Named region for the device trace (no-op cost off-profile)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
+    """Named host region on the profiler's own clock, so a device idle
+    gap can be put down to what the host was doing in it.  Never imports
+    jax: a process that has not loaded it (host-only engines, the
+    gateway of a remote fleet) cannot be tracing, and gets a no-op.  With
+    jax loaded and no trace running the region costs ~0.5 us."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return _NO_REGION
+    return prof.TraceAnnotation(name)

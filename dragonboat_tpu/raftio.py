@@ -54,6 +54,15 @@ class ILogDB(abc.ABC):
     @abc.abstractmethod
     def save_raft_state(self, updates: List[Update], worker_id: int) -> None: ...
 
+    def wal_counts(self) -> tuple:
+        """``(appends, bytes, records)`` written to the write-ahead log
+        so far: one append is one write (and one fsync, unless the
+        record was advisory), bytes are as framed on disk.  What the
+        engines difference around their saves (``wal_appends`` /
+        ``wal_bytes`` / ``wal_records``, docs/OBSERVABILITY.md).  A
+        store that keeps no such log reports zeros."""
+        return 0, 0, 0
+
     def save_state_lanes(
         self,
         shard_ids: List[int],

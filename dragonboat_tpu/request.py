@@ -11,6 +11,7 @@ from __future__ import annotations
 import enum
 import random
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -80,7 +81,7 @@ class RequestState:
     timed-out, terminated and sealed futures alike."""
 
     __slots__ = ("key", "deadline", "_event", "code", "result", "_committed",
-                 "span")
+                 "span", "t_notified")
 
     def __init__(self, key: int, deadline: int):
         self.key = key
@@ -90,6 +91,9 @@ class RequestState:
         self.result: Result = Result()
         self._committed = False
         self.span = None
+        # time.monotonic() at notify: a poller's lag behind the
+        # completion (the gateway's t_ack_lag_ms) runs from it
+        self.t_notified = 0.0
 
     # -- completion (engine side) ---------------------------------------
     def notify(self, code: RequestResultCode, result: Optional[Result] = None):
@@ -99,6 +103,7 @@ class RequestState:
         s = self.span
         if s is not None:
             s.end(status=code.name if code is not None else "unknown")
+        self.t_notified = time.monotonic()
         self._event.set()
 
     def notify_committed(self):

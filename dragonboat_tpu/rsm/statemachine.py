@@ -73,6 +73,9 @@ class Task:
     entries: List[Entry] = field(default_factory=list)
     snapshot: Snapshot = None  # type: ignore[assignment]
     ctx: object = None  # snapshot request context (export path, sink, ...)
+    # perf_counter() stamp of the hand-off to the apply queue (ENTRIES
+    # tasks): the apply worker's t_apply_wait_ms runs from it
+    t_handoff: float = 0.0
 
 
 class TaskQueue:

@@ -177,6 +177,12 @@ class TanLogDB(ILogDB):
         self._inflight = 0  # native appends running outside the lock
         self._idle = threading.Condition(self._lock)  # inflight == 0
         self._rotate_pending = False  # gate: new appends wait, inflight drains
+        # what the WAL cost so far: appends (one write each, one fsync
+        # unless advisory), framed bytes, records.  Written where the
+        # lock is already held after an append; see wal_counts
+        self._wal_appends = 0
+        self._wal_bytes = 0
+        self._wal_records = 0
         # test-only fault injection (reference: vfs error-injection hooks
         # [U]): called with the framed bytes before every write+fsync on
         # BOTH writer paths (python and native group-commit); raising
@@ -334,6 +340,9 @@ class TanLogDB(ILogDB):
             if sync:
                 self._fh.sync()
         self._active_bytes += len(raw)
+        self._wal_appends += 1
+        self._wal_bytes += len(raw)
+        self._wal_records += len(recs)
 
     def _maybe_rotate(self) -> None:
         """Rotate once the active segment is full.  Only call with the
@@ -397,6 +406,11 @@ class TanLogDB(ILogDB):
     def name(self) -> str:
         return "tan"
 
+    def wal_counts(self) -> tuple:
+        # three GIL-atomic loads, no lock: a reader between two of them
+        # is off by one append at most, and the next read catches up
+        return self._wal_appends, self._wal_bytes, self._wal_records
+
     def close(self) -> None:
         with self._lock:
             self._quiesce_appends_locked()
@@ -458,6 +472,9 @@ class TanLogDB(ILogDB):
                 if ok:
                     # publish to readers only AFTER the bytes are durable
                     self._active_bytes += len(raw)
+                    self._wal_appends += 1
+                    self._wal_bytes += len(raw)
+                    self._wal_records += len(recs)
                     self._mirror.save_raft_state(updates, worker_id)
                     if (
                         self._active_bytes >= self.max_segment_bytes

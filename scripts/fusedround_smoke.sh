@@ -29,7 +29,6 @@ from dragonboat_tpu import (
     NodeHost,
     NodeHostConfig,
 )
-from dragonboat_tpu.metrics import global_registry
 from dragonboat_tpu.ops import hostplane
 from dragonboat_tpu.ops.colocated import ColocatedEngineGroup
 from dragonboat_tpu.transport.inproc import reset_inproc_network
@@ -92,7 +91,6 @@ try:
         inflight = len(core._inflight)
     assert st["fused_waves"] > 0, st                       # (1)
     assert st["fused_rounds_stepped"] >= 3 * st["fused_waves"], st
-    assert global_registry.counter("fused_waves_total").value > 0
     assert st["readback_windows"] + inflight == (          # (2)
         st["launches"] + st.get("sel_fallbacks", 0)
     ), (st, inflight)

@@ -28,7 +28,6 @@ from dragonboat_tpu import (
     NodeHost,
     NodeHostConfig,
 )
-from dragonboat_tpu.metrics import global_registry
 from dragonboat_tpu.ops import hostplane
 from dragonboat_tpu.ops.colocated import ColocatedEngineGroup
 from dragonboat_tpu.transport.inproc import reset_inproc_network
@@ -88,9 +87,8 @@ try:
     core = group.core
     st = core.stats
     overlap = st.get("pipeline_overlap_s", 0.0)
-    ctr = global_registry.counter("pipeline_overlap_seconds_total").value
-    assert overlap > 0 and ctr > 0, (                      # (2)
-        f"no pipeline overlap recorded: stats={overlap} counter={ctr}"
+    assert overlap > 0, (                                  # (2)
+        f"no pipeline overlap recorded: stats={overlap}"
     )
     assert st["launches"] > 5, st
     assert hostplane.PARITY_FAILURE_COUNT == 0, hostplane.PARITY_FAILURES

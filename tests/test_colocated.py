@@ -564,9 +564,8 @@ class TestChipSmoke:
         assert rep["jitcheck_retraces"] == []
         assert all(rep["checks"].values()), rep["checks"]
         for k in ("launches", "pipeline_resets", "t_plan_ms",
-                  "t_upload_ms", "t_dev_step_ms", "t_dev_route_ms",
-                  "t_dev_sel_ms", "t_dev_blob_ms", "t_detail_ms",
-                  "t_updates_ms", "t_persist_ms"):
+                  "t_upload_ms", "t_dispatch_ms", "t_dev_blob_ms",
+                  "t_detail_ms", "t_updates_ms", "t_persist_ms"):
             assert k in rep["engine"], k
 
     def test_refuses_to_run_without_an_accelerator(self):

@@ -253,7 +253,7 @@ def run_scale(shards: int, artifact_path: str = "",
             tbreak = "/".join(
                 str(st.get(k, 0) // 1000)
                 for k in ("t_coalesce_ms", "t_plan_ms", "t_upload_ms",
-                          "t_device_ms", "t_detail_ms", "t_updates_ms",
+                          "t_dispatch_ms", "t_detail_ms", "t_updates_ms",
                           "t_persist_ms")
             )
             print(f"leader coverage {covered}/{shards} "
