@@ -171,6 +171,17 @@ _LIM_SOFT = 2**31 - 2**24
 _C_ALIVE, _C_BATCH, _C_PROP, _C_TICKS = range(4)
 
 
+def _combo_np(tick_counts, alive_np, batch_gs, prop_gs) -> np.ndarray:
+    """The [G, 4] per-launch host inputs (alive, batch membership,
+    proposal rows, fused tick counts), one column each."""
+    combo = np.zeros((len(tick_counts), 4), np.int32)
+    combo[:, _C_TICKS] = tick_counts
+    combo[:, _C_ALIVE] = alive_np
+    combo[batch_gs, _C_BATCH] = 1
+    combo[prop_gs, _C_PROP] = 1
+    return combo
+
+
 @jax.jit
 def _assemble_inbox(host: Inbox, pending: Inbox, alive: jnp.ndarray) -> Inbox:
     """Concatenate the ROUTED regions first, then the host-encoded
@@ -592,6 +603,9 @@ class ColocatedVectorEngine(VectorStepEngine):
         # pipeline is drained to depth 0 — never from inside a merge.
         self._deferred: List[Tuple] = []
         self._running_deferred = False
+        # nodes that registered a device read since the last plan loop
+        # (_attach_messages): a launch takes them off its tick lane
+        self._read_ctx_new: List = []
         # True while a generation's merge tail is executing: membership
         # mutators called from inside it (demote, save-failure evict)
         # must defer instead of fencing — a fence mid-merge would
@@ -649,6 +663,10 @@ class ColocatedVectorEngine(VectorStepEngine):
             # (proposals, host-carried messages, reads), as opposed to
             # device_rows_stepped, which counts tick-only rows too
             device_rows_active=0,
+            # batch rows encoded through the tick lane (two arrays, no
+            # Message objects: hostplane.encode_tick_lane); the rest of
+            # device_rows_stepped went through _encode_rows one by one
+            tick_lane_rows=0,
             # the apply workers' totals over every member NodeHost,
             # folded in once a step call (_fold_apply)
             apply_batches=0, apply_entries=0, t_apply_ms=0.0,
@@ -1636,6 +1654,15 @@ class ColocatedVectorEngine(VectorStepEngine):
         updates: List[Tuple] = []
         host_rows: List[Tuple] = []
         batch: List[Tuple] = []
+        # the batch, split by what the plan loop saw of each row: the
+        # tick lane (row id and fused count of every row whose whole
+        # input is hint-free ticks — carried as two parallel lists,
+        # never as Message objects) and the active rows, the only ones
+        # the encode phase walks in Python (see _encode_generation)
+        batch_gs: List[int] = []
+        tick_gs: List[int] = []
+        tick_n: List[int] = []
+        active: List[Tuple] = []
         tok = self._enter("t_coalesce_ms", "raft-colocated-coalesce")
         nodes = self._coalesce(nodes)
         self._maybe_rebase_shards(nodes)
@@ -1668,6 +1695,8 @@ class ColocatedVectorEngine(VectorStepEngine):
         # launch's alive mask (their detach may still be queued behind
         # the core lock)
         self._gen_stopping = []
+        # what registered before this loop, the loop sees for itself
+        self._read_ctx_new.clear()
         for i, node in enumerate(nodes):
             if node.stopped or node.stopping:
                 if gs_list[i] >= 0:
@@ -1725,9 +1754,16 @@ class ColocatedVectorEngine(VectorStepEngine):
                     n_fast += 1
                     if ticks_dev:
                         si = StepInputs(ticks=ticks, gc_ticks=gc_t)
-                        batch.append(
-                            (node, g, si, [("tick", ticks_dev)])
-                        )
+                        row = (node, g, si, [("tick", ticks_dev)])
+                        batch.append(row)
+                        batch_gs.append(g)
+                        if node.device_reads.queue:
+                            # a pending device read rides the tick's
+                            # hint lanes: a sparse row, not a lone tick
+                            active.append(row)
+                        else:
+                            tick_gs.append(g)
+                            tick_n.append(ticks_dev)
                     else:
                         _tick_bookkeeping(node, ticks + gc_t)
                     continue
@@ -1753,7 +1789,10 @@ class ColocatedVectorEngine(VectorStepEngine):
             if not plan and not self._meta[g].dirty:
                 _tick_bookkeeping(node, si.ticks + si.gc_ticks)
                 continue
-            batch.append((node, g, si, plan))
+            row = (node, g, si, plan)
+            batch.append(row)
+            batch_gs.append(g)
+            active.append(row)
 
         self._evict_rows_to_host([
             g
@@ -1791,7 +1830,9 @@ class ColocatedVectorEngine(VectorStepEngine):
                     ]
                 )
                 self._leave(tok)
-                self._launch_generation(batch)
+                self._launch_generation(
+                    batch, batch_gs, active, tick_gs, tick_n
+                )
                 launched = True
             else:
                 # pure preload: nothing to step and no routed traffic in
@@ -2095,12 +2136,71 @@ class ColocatedVectorEngine(VectorStepEngine):
         if fulls:
             self._persist_and_process(fulls, self._last_worker_id)
 
-    def _launch_generation(self, batch) -> None:  # sync-hot
+    def _attach_messages(self, r, node, *args, **kwargs) -> None:
+        # the one place a device read REGISTERS (handle_device_read_resp
+        # off a merged outbox row).  From then on the row's tick carries
+        # the read's ctx in its hint lanes, so it is no tick-lane row:
+        # remember the node for a launch whose plan loop classified it
+        # BEFORE this merge ran (the room check and the eviction fence
+        # complete generations between the plan loop and the encode).
+        reads = node.device_reads
+        had = bool(reads.queue)
+        super()._attach_messages(r, node, *args, **kwargs)
+        if not had and reads.queue:
+            self._read_ctx_new.append(node)
+
+    def _retake_lane_rows(self, batch, batch_gs, active, tick_gs,
+                          tick_n) -> None:
+        """Move rows whose node registered a device read since the plan
+        loop from the tick lane to the active rows (see
+        _attach_messages): O(such rows), usually none."""
+        for node in self._read_ctx_new:
+            g = self._row_of.get(self._row_key(node))
+            if g is None or not node.device_reads.queue or g not in tick_gs:
+                continue
+            row = batch[batch_gs.index(g)]
+            if row[0] is node:  # not a stale pre-restart binding
+                i = tick_gs.index(g)
+                del tick_gs[i], tick_n[i]
+                active.append(row)
+        self._read_ctx_new.clear()
+
+    def _encode_generation(self, active, tick_gs,
+                           tick_n) -> hostplane.LaunchEncode:
+        """The encode phase's host half.  ``active`` rows go through
+        ``_encode_rows`` into ``Message`` lists, staging and proposal
+        rows, and split into lone hint-free ticks (a count in the [G]
+        vector) and dense inbox rows; the tick lane's rows go into the
+        same vector as two arrays (hostplane.encode_tick_lane) and are
+        never walked.  With an empty lane and the whole batch as
+        ``active`` this is the pre-lane encode: the parity oracle."""
+        # staging keys in ASSEMBLED coordinates: the routed regions
+        # (width P*B) come first, host slots after (see _assemble_inbox)
+        row_msgs_of, staging, prop_rows, fed = self._encode_rows(
+            active, slot_offset=self.P * self.budget
+        )
+        # compact host-inbox upload: tick-only rows (the overwhelming
+        # majority at scale) ride a [G] count vector built into an inbox
+        # ON DEVICE; only rows with real host slots upload dense rows
+        tick_counts, tick_fed = hostplane.encode_tick_lane(
+            self.capacity, tick_gs, tick_n
+        )
+        tick_fed.update(fed)
+        sparse = hostplane.split_lone_ticks(tick_counts, active, row_msgs_of)
+        return hostplane.LaunchEncode(
+            tick_counts, sparse, staging, prop_rows, tick_fed
+        )
+
+    def _launch_generation(  # sync-hot
+        self, batch, batch_gs, active, tick_gs, tick_n
+    ) -> None:
         """Assemble, upload and dispatch one generation, request its
         (head, detail) readback, and push the in-flight record — the
         merge tail runs later in _complete_generation (behind the
-        device by up to pipeline_depth generations).  Caller holds the
-        core lock."""
+        device by up to pipeline_depth generations).  ``batch_gs`` is
+        the batch's row ids in batch order; ``tick_gs``/``tick_n`` (the
+        tick lane) and ``active`` split the batch between them (see
+        _step_colocated).  Caller holds the core lock."""
         # room check: the pipe holds up to depth dispatched-unmerged
         # generations; complete the oldest BEFORE adding a new one so
         # each readback stays in flight across a full pipeline's worth
@@ -2123,37 +2223,19 @@ class ColocatedVectorEngine(VectorStepEngine):
         # row upload and the first program of the wave
         tok = self._enter("t_encode_ms", "raft-colocated-encode")
         G, M, E, P, B = self.capacity, self.M, self.E, self.P, self.budget
-        # staging keys in ASSEMBLED coordinates: the routed regions
-        # (width P*B) come first, host slots after (see _assemble_inbox)
-        msg_rows, staging, prop_rows, tick_fed = self._encode_batch(
-            batch, slot_offset=P * B
-        )
-        # compact host-inbox upload: tick-only rows (the overwhelming
-        # majority at scale) ride a [G] count vector built into an inbox
-        # ON DEVICE; only rows with real host slots upload dense rows
-        tick_counts = np.zeros((G,), np.int32)
-        sparse: List[Tuple[int, List]] = []
-        for node, g, si, plan in batch:
-            msgs = msg_rows[g]
-            if not msgs:
-                continue
-            m0 = msgs[0]
-            if (
-                len(msgs) == 1
-                and int(m0.type) == MT_TICK
-                and m0.hint == 0
-                and m0.hint_high == 0
-            ):
-                tick_counts[g] = m0.log_index
-            else:
-                sparse.append((g, msgs))
+        if self._read_ctx_new:
+            self._retake_lane_rows(batch, batch_gs, active, tick_gs, tick_n)
+        enc = self._encode_generation(active, tick_gs, tick_n)
+        # raftlint: ignore[sync-budget] host-built index arrays, not device readbacks
+        batch_gs = np.asarray(batch_gs, np.int64)
+        if hostplane.PARITY:
+            n_reads = self.stats["device_reads"]
+            whole = self._encode_generation(batch, (), ())
+            self.stats["device_reads"] = n_reads
+            hostplane.check_encode_parity(batch, batch_gs, enc, whole)
+        tick_counts, sparse, staging, prop_rows, tick_fed = enc
         if self._tables_dirty:
             self._rebuild_tables()
-        # ONE fused [G, 4] host upload for every per-launch [G] input
-        # (alive, batch membership, proposal rows, fused tick counts):
-        # each separate device_put pays ~10-20 ms of link latency
-        combo_np = np.zeros((G, 4), np.int32)
-        combo_np[:, _C_TICKS] = tick_counts
         # alive straight off the SoA lanes (attached & clean) — the old
         # per-launch Python scan over the whole meta table cost
         # ~0.5 µs/row (~125 ms/launch at 250k rows).  Stopping rows
@@ -2175,10 +2257,6 @@ class ColocatedVectorEngine(VectorStepEngine):
         gen_stopping = getattr(self, "_gen_stopping", None)
         if gen_stopping:
             alive_np[gen_stopping] = False
-        # raftlint: ignore[sync-budget] host-built index arrays, not device readbacks
-        batch_gs = np.asarray(
-            [g for _, g, _, _ in batch], np.int64
-        )
         # raftlint: ignore[sync-budget] host-built index array, not a device readback
         prop_gs = np.asarray(prop_rows, np.int64)
         # ---- fused commit wave decision (ISSUE 15) ------------------
@@ -2212,10 +2290,12 @@ class ColocatedVectorEngine(VectorStepEngine):
                 self.stats["fused_rounds_stepped"] += rounds
             else:
                 self.stats["fused_fences"] += 1
-        combo_np[:, _C_ALIVE] = alive_np
-        combo_np[batch_gs, _C_BATCH] = 1
-        combo_np[prop_gs, _C_PROP] = 1
-        combo = self._put_rows(jnp.asarray(combo_np))
+        # ONE fused [G, 4] host upload for every per-launch [G] input
+        # (alive, batch membership, proposal rows, fused tick counts):
+        # each separate device_put pays ~10-20 ms of link latency
+        combo = self._put_rows(jnp.asarray(
+            _combo_np(tick_counts, alive_np, batch_gs, prop_gs)
+        ))
         host_inbox = _host_inbox_from_ticks(combo, M=M, E=E)
         if sparse:
             nsb = _bucket(len(sparse))
@@ -2351,6 +2431,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         self.stats["launches"] += 1
         self.stats["device_steps"] += rounds
         self.stats["device_rows_stepped"] += len(batch)
+        self.stats["tick_lane_rows"] += len(tick_gs)
         self.stats["device_rows_active"] += len(sparse)
         self._inflight.append(_InFlightGen(
             batch=batch, staging=staging, alive_np=alive_np,

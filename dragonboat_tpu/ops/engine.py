@@ -1716,8 +1716,9 @@ class VectorStepEngine(IStepEngine):
             for node, _u in pairs:
                 self._save_quarantine.discard(node)
 
-    def _encode_batch(self, batch, slot_offset: int = 0):
-        """Plans -> (per-row Message lists, staging, proposal rows).
+    def _encode_rows(self, batch, slot_offset: int = 0):
+        """Plans -> (one Message list per batch row, in batch order,
+        staging, proposal rows, tick_fed).
 
         Shared by the base and colocated device steps: slot order mirrors
         the scalar replay order; staged payload entries are keyed by slot
@@ -1733,13 +1734,17 @@ class VectorStepEngine(IStepEngine):
 
         ``tick_fed`` (4th return, row -> fused tick count) is the
         device-window mirror input for the lease evidence lanes
-        (hostplane.LeaseLanes.row_step)."""
-        msg_rows: List[List[Message]] = [[] for _ in range(self.capacity)]
+        (hostplane.LeaseLanes.row_step).
+
+        Allocates per row GIVEN, never per row of the engine: the
+        colocated launch hands over its ~40 active rows of 4,096."""
+        row_msgs_of: List[List[Message]] = []
         staging: Dict[int, Dict[int, List[Entry]]] = {}
         prop_rows: List[int] = []
         tick_fed: Dict[int, int] = {}
         for node, g, si, plan in batch:
-            row_msgs = msg_rows[g]
+            row_msgs: List[Message] = []
+            row_msgs_of.append(row_msgs)
             stage: Dict[int, List[Entry]] = {}
             base = int(self._base[g])
             for plan_slot, (kind, payload) in enumerate(plan):
@@ -1785,11 +1790,16 @@ class VectorStepEngine(IStepEngine):
                 for k, p in plan
             ):
                 prop_rows.append(g)
-        return msg_rows, staging, prop_rows, tick_fed
+        return row_msgs_of, staging, prop_rows, tick_fed
 
     def _device_step(self, batch) -> List[Tuple]:
         G, M, E = self.capacity, self.M, self.E
-        msg_rows, staging, prop_rows, tick_fed = self._encode_batch(batch)
+        row_msgs_of, staging, prop_rows, tick_fed = self._encode_rows(batch)
+        # a whole inbox: every row of the engine, empty where the batch
+        # has none
+        msg_rows: List[List[Message]] = [[] for _ in range(G)]
+        for (_node, g, _si, _plan), msgs in zip(batch, row_msgs_of):
+            msg_rows[g] = msgs
         inbox, overflow = S.encode_inbox(msg_rows, M, E)
         assert not overflow, f"planner let oversized rows through: {overflow}"
         inbox = self._put_rows(inbox)

@@ -18,7 +18,9 @@ Three layers:
   syntax while the vectorized passes read whole lanes at once.
 
 * vectorized passes — ``classify_static`` (the batched plan
-  classifier's static-eligibility prefilter), ``build_merge_sets``
+  classifier's static-eligibility prefilter), ``encode_tick_lane``
+  (the launch's tick-only rows, encoded from two index/count lists
+  and never as ``Message`` objects), ``build_merge_sets``
   (the post-launch row sets: escalations, live rows, buf/append/
   need/slot/sum), ``pos_of``/``covered`` (index-array replacements
   for the old per-row ``*_at`` dict builds and ``all(g in …)``
@@ -44,7 +46,7 @@ device plane's job, audited separately by analysis/jaxcheck).
 from __future__ import annotations
 
 import os
-from typing import List, NamedTuple, Sequence
+from typing import Dict, List, NamedTuple, Sequence, Tuple
 
 import numpy as np
 
@@ -55,6 +57,7 @@ from .types import (
     F_ESC,
     F_NEED_SS,
     F_QUORUM_ACTIVE,
+    MT_TICK,
     R_COMMIT,
     R_LAST,
     R_LEADER,
@@ -444,6 +447,110 @@ def classify_static_scalar(lanes: RowLanes, gs: Sequence[int]) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
+# the tick lane (the launch's encode phase)
+# ---------------------------------------------------------------------------
+class LaunchEncode(NamedTuple):
+    """One generation's host-side encode, as ``_launch_generation``
+    consumes it: the ``[G]`` fused tick count of every row whose whole
+    host inbox is one hint-free tick, the rows that upload dense inbox
+    rows (``(g, Message list)``), the staged payload entries by row and
+    assembled slot, the proposal-slot rows, and the row -> fused tick
+    count map the completion's lease pass reads."""
+
+    tick_counts: np.ndarray
+    sparse: List[Tuple[int, list]]
+    staging: Dict[int, Dict[int, list]]
+    prop_rows: List[int]
+    tick_fed: Dict[int, int]
+
+
+def encode_tick_lane(  # hostplane-hot
+    G: int, tick_gs: Sequence[int], tick_n: Sequence[int]
+) -> Tuple[np.ndarray, Dict[int, int]]:
+    """The tick lane's whole encode.  ``tick_gs``/``tick_n`` are the
+    row ids and fused tick counts of the batch rows the plan loop's
+    fast lane appended with the sole plan ``[("tick", n)]`` and no
+    pending device-read ctx (so the tick would carry no hint): ~98 % of
+    a launch at 1,000 groups x 3.  One numpy store puts them into the
+    ``[G]`` count vector ``_host_inbox_from_ticks`` expands on the
+    device; the dict is ``_encode_rows``' ``tick_fed`` for the same
+    rows.  No ``Message``, no per-row Python: the per-row twin
+    (:func:`split_lone_ticks` over ``_encode_rows``' output) is what
+    every batch row took before, still takes when it carries anything
+    else, and is the parity oracle for these."""
+    tick_counts = np.zeros((G,), np.int32)
+    if len(tick_gs):
+        tick_counts[np.asarray(tick_gs, np.int64)] = np.asarray(
+            tick_n, np.int32
+        )
+    return tick_counts, dict(zip(tick_gs, tick_n))
+
+
+def split_lone_ticks(
+    tick_counts: np.ndarray, rows, row_msgs_of
+) -> List[Tuple[int, list]]:
+    """Per-row twin of :func:`encode_tick_lane`: of ``rows`` (batch
+    tuples) and their ``Message`` lists (``_encode_rows``' first
+    return, parallel to ``rows``), a row whose inbox is one hint-free
+    tick lands in ``tick_counts`` (written in place); every other row
+    with input is returned as ``(g, msgs)`` for the dense upload.  Runs
+    over the launch's ACTIVE rows, and over the whole batch as the
+    parity oracle."""
+    sparse: List[Tuple[int, list]] = []
+    for (_node, g, _si, _plan), msgs in zip(rows, row_msgs_of):
+        if not msgs:
+            continue
+        m0 = msgs[0]
+        if (
+            len(msgs) == 1
+            and int(m0.type) == MT_TICK
+            and m0.hint == 0
+            and m0.hint_high == 0
+        ):
+            tick_counts[g] = m0.log_index
+        else:
+            sparse.append((g, msgs))
+    return sparse
+
+
+def assert_encode_parity(
+    batch, batch_gs: np.ndarray, lane: LaunchEncode, ref: LaunchEncode
+) -> None:
+    """``lane`` (tick lane + active rows) against ``ref`` (the whole
+    batch through ``_encode_rows`` + :func:`split_lone_ticks`): same
+    count vector, same dense rows with equal ``Message`` lists, same
+    staging, proposal rows and lease-pass tick map; ``batch_gs`` is the
+    batch's row ids in batch order."""
+    want_gs = [g for _, g, _, _ in batch]
+    if np.asarray(batch_gs).tolist() != want_gs:
+        raise HostPlaneParityError(_diff("batch_gs", batch_gs, want_gs))
+    if not np.array_equal(lane.tick_counts, ref.tick_counts):
+        raise HostPlaneParityError(
+            _diff("tick_counts", lane.tick_counts, ref.tick_counts)
+        )
+    got, want = dict(lane.sparse), dict(ref.sparse)
+    if len(got) != len(lane.sparse) or got != want:
+        raise HostPlaneParityError(
+            f"sparse rows: lane {sorted(got)} != whole batch "
+            f"{sorted(want)} (or a row's Message list differs: "
+            f"{[g for g in got if g in want and got[g] != want[g]][:8]})"
+        )
+    if lane.staging != ref.staging:
+        raise HostPlaneParityError(
+            _diff("staging rows", sorted(lane.staging), sorted(ref.staging))
+        )
+    if sorted(lane.prop_rows) != sorted(ref.prop_rows):
+        raise HostPlaneParityError(
+            _diff("prop_rows", sorted(lane.prop_rows), sorted(ref.prop_rows))
+        )
+    if lane.tick_fed != ref.tick_fed:
+        raise HostPlaneParityError(
+            _diff("tick_fed", sorted(lane.tick_fed.items()),
+                  sorted(ref.tick_fed.items()))
+        )
+
+
+# ---------------------------------------------------------------------------
 # merge row sets (the post-launch tail's classification)
 # ---------------------------------------------------------------------------
 class MergeSets(NamedTuple):
@@ -659,6 +766,13 @@ def _record_failure(e: Exception) -> None:  # pragma: no cover - bug path
 def check_classify_parity(lanes: RowLanes, gs, vec) -> None:
     try:
         assert_classify_parity(lanes, gs, vec)
+    except HostPlaneParityError as e:  # pragma: no cover - bug path
+        _record_failure(e)
+
+
+def check_encode_parity(batch, batch_gs, lane, ref) -> None:
+    try:
+        assert_encode_parity(batch, batch_gs, lane, ref)
     except HostPlaneParityError as e:  # pragma: no cover - bug path
         _record_failure(e)
 

@@ -590,6 +590,26 @@ def test_host_loop_real_tree_lane_plan_annotation_is_live():
     assert any(f.rule == "host-loop" for f in fs)
 
 
+def test_host_loop_real_tree_tick_lane_annotation_is_live():
+    """encode_tick_lane (PR 27: a launch's tick-only rows as two arrays)
+    carries the # hostplane-hot marker; a per-row loop seeded into its
+    body must surface, so the ~2,000 rows a launch it was written to
+    keep out of Python cannot grow back in."""
+    path = os.path.join(REPO, "dragonboat_tpu/ops/hostplane.py")
+    src = open(path).read()
+    assert "def encode_tick_lane(  # hostplane-hot" in src
+    assert lint_source(src, "dragonboat_tpu/ops/hostplane.py") == []
+    needle = "    tick_counts = np.zeros((G,), np.int32)\n    if len(tick_gs):"
+    assert needle in src
+    seeded = src.replace(
+        needle,
+        "    for g, n in zip(tick_gs, tick_n):\n        pass\n" + needle,
+        1,
+    )
+    fs = lint_source(seeded, "dragonboat_tpu/ops/hostplane.py")
+    assert [f.rule for f in fs] == ["host-loop"], fs
+
+
 def test_host_loop_lane_scalar_oracle_ignore_is_live():
     """plan_update_sync_scalar (the documented per-row parity oracle)
     is exempted by a def-line-adjacent ignore; stripping the ignore
