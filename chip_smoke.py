@@ -23,6 +23,13 @@ the run with neither line.
 The numbers in the report are one unrepeated run's observations for the
 next issue to plan from.  None of them is a benchmark metric.
 
+This script builds the three-replica geometry only.  The five-replica
+smoke (BASELINE config 3 cut to 1,000 groups x 5 on an 8,192-row P=5 state,
+on-disk state machines) is the benchmark's own cell, which builds the
+deployment from its configuration's file and holds it to the same checks:
+
+    python benchmark/run.py --workload ycsb-a-10k5.mixed-sat --seed 1 --seconds 20 --trace 0
+
 One process, no children: a chip belongs to one process at a time.
 """
 from __future__ import annotations

@@ -33,6 +33,9 @@ tests/test_bigstate.py.
 
 Command codec (struct-framed, not pickle — commands travel the wire and
 the library-wide no-pickle guard applies): ``put_cmd``/``del_cmd``.
+:class:`TextOnDiskKV` is the same state machine behind the text
+commands the served path's clients send (``b"key=value"``, ``lookup(key)
+-> value``, as ``examples/kv_gateway.KV``).
 """
 from __future__ import annotations
 
@@ -139,6 +142,10 @@ class OnDiskKV(IOnDiskStateMachine):
         self.applied = 0  # highest index applied to the in-core state
         self._wal = None  # open append handle
         self._wal_bytes = 0  # bytes in the current WAL (incl. unsynced)
+        # frames and bytes update() has appended since construction
+        # (wal_counts; docs/OBSERVABILITY.md "Counters")
+        self._wal_appends = 0
+        self._wal_appended = 0
         self._bytes = 0  # sum of key+value bytes (the "state size" probe)
         # serializes checkpoint rewrites against close(); update/sync
         # run on the one apply worker and need no lock among themselves
@@ -254,18 +261,21 @@ class OnDiskKV(IOnDiskStateMachine):
             # never interleaves fresh frames with garbage
             self.fs.truncate(self._wal_path, good)
 
+    def _put(self, k: bytes, v: bytes) -> Result:
+        old = self._data.get(k)
+        if old is not None:
+            self._bytes -= len(k) + len(old)
+        self._data[k] = v
+        self._bytes += len(k) + len(v)
+        return Result(value=1)
+
     def _apply_cmd(self, cmd: bytes) -> Result:
         try:
             op, k, v = decode_cmd(cmd)
         except ValueError:
             return Result(value=0)
         if op == OP_PUT:
-            old = self._data.get(k)
-            if old is not None:
-                self._bytes -= len(k) + len(old)
-            self._data[k] = v
-            self._bytes += len(k) + len(v)
-            return Result(value=1)
+            return self._put(k, v)
         old = self._data.pop(k, None)
         if old is not None:
             self._bytes -= len(k) + len(old)
@@ -280,8 +290,10 @@ class OnDiskKV(IOnDiskStateMachine):
             frame = _frame_hdr.pack(len(body), zlib.crc32(body)) + body
             self._wal.write(frame)
             self._wal_bytes += len(frame)
+            self._wal_appended += len(frame)
             e.result = self._apply_cmd(e.cmd)
             self.applied = e.index
+        self._wal_appends += len(entries)
         if self._wal_bytes >= self.compact_wal_bytes:
             # fold the WAL into a fresh checkpoint HERE, on the apply
             # path that generated the bytes (amortized, LSM-style), NOT
@@ -296,6 +308,12 @@ class OnDiskKV(IOnDiskStateMachine):
                 self._write_checkpoint(self.applied, self._data.items())
                 self._reset_wal()
         return entries
+
+    def wal_counts(self) -> Tuple[int, int]:
+        """``(frames, bytes)`` that ``update()`` has appended to this
+        state machine's own log so far, as ``ILogDB.wal_counts()`` is
+        for the Raft log: cumulative, readers take deltas."""
+        return self._wal_appends, self._wal_appended
 
     def lookup(self, query):
         # tuple OR list: RPC queries ride the JSON value lane, which
@@ -423,16 +441,38 @@ class OnDiskKV(IOnDiskStateMachine):
                 self._wal = None
 
 
+class TextOnDiskKV(OnDiskKV):
+    """:class:`OnDiskKV` behind the text commands of the served path
+    (``examples/kv_gateway.KV``): a command is ``b"key=value"`` and
+    ``lookup(key)`` takes the key and gives the value, both ``str``.
+    Only the codec differs: the WAL frames hold the command as it was
+    proposed, so checkpoint, replay, ``sync`` and the streamed
+    snapshots are the parent's, byte for byte."""
+
+    def _apply_cmd(self, cmd: bytes) -> Result:
+        k, sep, v = cmd.partition(b"=")
+        if not sep:
+            return Result(value=0)
+        return self._put(k, v)
+
+    def lookup(self, query):
+        if isinstance(query, str):
+            v = self._data.get(query.encode())
+            return None if v is None else v.decode()
+        return super().lookup(query)
+
+
 def ondisk_kv_factory(
     root: str,
     fs: Optional[vfs_mod.IVFS] = None,
     compact_wal_bytes: int = DEFAULT_COMPACT_WAL_BYTES,
+    cls=OnDiskKV,
 ):
     """``sm_factory`` for NodeHost.start_replica: each replica gets its
     own subdirectory of ``root`` (replicas NEVER share state dirs)."""
 
     def factory(shard_id: int, replica_id: int) -> OnDiskKV:
-        return OnDiskKV(
+        return cls(
             shard_id,
             replica_id,
             base_dir=os.path.join(root, f"{shard_id}-{replica_id}"),
@@ -441,3 +481,8 @@ def ondisk_kv_factory(
         )
 
     return factory
+
+
+def text_kv_factory(root: str, **kw):
+    """:func:`ondisk_kv_factory` of :class:`TextOnDiskKV`."""
+    return ondisk_kv_factory(root, cls=TextOnDiskKV, **kw)

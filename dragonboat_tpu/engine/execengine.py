@@ -32,6 +32,17 @@ if TYPE_CHECKING:
 
 _log = get_logger("engine")
 
+# what ExecEngine.apply_totals() counts, by name: seconds inside
+# node.apply(), and what Node.apply() reports under these same names:
+# ENTRIES tasks applied and their entries, seconds the batches waited
+# between hand-off and apply, seconds inside the user state machine's
+# update, and the appends and bytes an on-disk state machine wrote to
+# its own log (docs/OBSERVABILITY.md "Counters")
+APPLY_TOTALS = (
+    "t_apply_s", "apply_batches", "apply_entries", "t_apply_wait_s",
+    "t_sm_update_s", "sm_wal_appends", "sm_wal_bytes",
+)
+
 
 class WorkReady:
     """Per-partition ready-shard set with wakeup (reference: workReady [U])."""
@@ -165,11 +176,12 @@ class ExecEngine:
             bounds=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024),
         )
         self._apply_hist = self.metrics.histogram("raft_engine_apply_seconds")
-        # what the apply workers did, always on: per worker slot
-        # [batches, entries, seconds inside node.apply(), seconds the
-        # batches waited between hand-off and apply].  One writer a
-        # slot; apply_totals() sums them for a reader on any thread
-        self._apply_acc = [[0, 0, 0.0, 0.0] for _ in range(apply_workers)]
+        # what the apply workers did, always on: APPLY_TOTALS per
+        # worker slot.  One writer a slot; apply_totals() sums them for
+        # a reader on any thread
+        self._apply_acc = [
+            dict.fromkeys(APPLY_TOTALS, 0) for _ in range(apply_workers)
+        ]
         self.step_ready = WorkReady(step_workers)
         self.apply_ready = WorkReady(apply_workers)
         self.step_engine = step_engine or HostStepEngine(logdb)
@@ -254,10 +266,9 @@ class ExecEngine:
     def notify_many(self, shard_ids) -> None:
         self.step_ready.notify_all(shard_ids)
 
-    def apply_totals(self) -> tuple:
-        """``(apply_batches, apply_entries, t_apply_s, t_apply_wait_s)``
-        over all apply workers since start."""
-        return tuple(sum(a[i] for a in self._apply_acc) for i in range(4))
+    def apply_totals(self) -> dict:
+        """``APPLY_TOTALS`` by name, over all apply workers since start."""
+        return {k: sum(a[k] for a in self._apply_acc) for k in APPLY_TOTALS}
 
     # -- workers ----------------------------------------------------------
     def _step_worker_main(self, worker_id: int) -> None:
@@ -295,13 +306,12 @@ class ExecEngine:
                 try:
                     t0 = time.perf_counter()
                     with annotate("raft-apply"):
-                        batches, entries, wait_s = node.apply()
+                        applied = node.apply()
                     dt = time.perf_counter() - t0
                     self._apply_hist.observe(dt)
-                    acc[0] += batches
-                    acc[1] += entries
-                    acc[2] += dt
-                    acc[3] += wait_s
+                    acc["t_apply_s"] += dt
+                    for k, v in applied.items():
+                        acc[k] += v
                 except Exception:  # noqa: BLE001
                     _log.exception(
                         "apply worker %d shard %d failed", worker_id, node.shard_id

@@ -945,7 +945,15 @@ class Gateway:
                 time.sleep(0.02)
                 continue
             try:
-                val = nh.sync_read(shard_id, query, timeout=remaining)
+                # one attempt waits the budget's per-try timeout, not
+                # the whole deadline: a ReadIndex request lost to a
+                # change of leader is never answered (found on the
+                # chip: nine reads of a 20 s run waited 300 s), and a
+                # read is idempotent, so it goes round again
+                val = nh.sync_read(
+                    shard_id, query,
+                    timeout=min(remaining, self.budget.per_try_timeout()),
+                )
                 self._count_read(PATH_READ_INDEX)
                 return ReadResult(val, PATH_READ_INDEX)
             except Exception as e:  # noqa: BLE001 — reads are

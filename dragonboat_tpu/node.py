@@ -1080,19 +1080,24 @@ class Node:
     # ------------------------------------------------------------------
     # apply path (owning apply worker only)
     # ------------------------------------------------------------------
-    def apply(self) -> tuple:
+    def apply(self) -> dict:
         """Drain the task queue through the RSM (reference:
-        engine applyWorkerMain -> rsm Handle [U]).  Returns ``(batches,
-        entries, wait_s)``: ENTRIES tasks applied, their entries, and
-        the seconds they sat between hand-off and this drain."""
+        engine applyWorkerMain -> rsm Handle [U]).  Returns what it did
+        under the names ``ExecEngine.APPLY_TOTALS`` counts them by:
+        ENTRIES tasks applied, their entries, the seconds they sat
+        between hand-off and this drain, the seconds inside the user
+        state machine's ``update``, and what an on-disk state machine
+        appended to its own log meanwhile; nothing for a stopped node."""
         with self._apply_lock:
             if self.stopped:
-                return 0, 0, 0.0
+                return {}
             return self._apply_locked()
 
-    def _apply_locked(self) -> tuple:
+    def _apply_locked(self) -> dict:
         batches = entries = 0
         wait_s = 0.0
+        managed = self.sm.managed
+        update_s, wal = managed.update_s, managed.wal_counts()
         t_start = time.perf_counter()
         for task in self.sm.task_queue.get_all():
             if task.type == TaskType.ENTRIES:
@@ -1113,7 +1118,14 @@ class Node:
             self._applied_since_snapshot = 0
             with self._qlock:
                 self._snapshot_reqs.append((0, self.config.compaction_overhead))
-        return batches, entries, wait_s
+        wal_now = managed.wal_counts()
+        return {
+            "apply_batches": batches, "apply_entries": entries,
+            "t_apply_wait_s": wait_s,
+            "t_sm_update_s": managed.update_s - update_s,
+            "sm_wal_appends": wal_now[0] - wal[0],
+            "sm_wal_bytes": wal_now[1] - wal[1],
+        }
 
     def _complete_applied(self, results: List[ApplyResult]) -> None:
         for r in results:

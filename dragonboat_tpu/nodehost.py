@@ -15,7 +15,7 @@ from typing import Callable, Dict, Optional
 
 from .client import Session
 from .config import Config, ConfigError, NodeHostConfig
-from .engine.execengine import ExecEngine
+from .engine.execengine import APPLY_TOTALS, ExecEngine
 from .events import EventFanout
 from .logger import get_logger
 from .metrics import MetricsRegistry
@@ -106,6 +106,14 @@ class NodeHost:
         self._global_ticks = 0
         self._nodes_lock = threading.RLock()
         self._closed = False
+        # leaders a replica here met after its shard's first: a new
+        # (term, leader) pair, so an election it saw, never the loss of
+        # a leader nor the same one met again (always on;
+        # docs/OBSERVABILITY.md "Counters")
+        self.leader_changes = 0
+        # shard id -> (term, leader) last met; guarded-by: _leader_lock
+        self._leader_seen: Dict[int, tuple] = {}
+        self._leader_lock = threading.Lock()
 
         # exclusive dir lock + deployment-id check (reference:
         # internal/server environment [U])
@@ -322,14 +330,14 @@ class NodeHost:
                     self.metrics.gauge(
                         "raft_engine_" + key, lambda k=key: stats[k]
                     )
-            for i, key in enumerate((
-                "apply_batches", "apply_entries", "t_apply_s",
-                "t_apply_wait_s",
-            )):
+            for key in APPLY_TOTALS:
                 self.metrics.gauge(
                     "raft_nodehost_" + key,
-                    lambda i=i: self.engine.apply_totals()[i],
+                    lambda k=key: self.engine.apply_totals()[k],
                 )
+            self.metrics.gauge(
+                "raft_nodehost_leader_changes", lambda: self.leader_changes
+            )
 
             self._ticks_paused = False
             self._ticker_stop = threading.Event()
@@ -528,6 +536,8 @@ class NodeHost:
             raise ShardNotFound(f"shard {shard_id}")
         self.engine.unregister(shard_id)
         node.stop()
+        with self._leader_lock:
+            self._leader_seen.pop(shard_id, None)
 
     def stop_replica(self, shard_id: int, replica_id: int) -> None:
         self.stop_shard(shard_id)
@@ -662,6 +672,12 @@ class NodeHost:
     def _on_leader_updated(
         self, shard_id: int, replica_id: int, term: int, leader_id: int
     ) -> None:
+        if leader_id:
+            with self._leader_lock:
+                seen = self._leader_seen.get(shard_id)
+                if seen != (term, leader_id):
+                    self._leader_seen[shard_id] = (term, leader_id)
+                    self.leader_changes += seen is not None
         rec = self.recorder
         if rec is not None:
             rec.record(

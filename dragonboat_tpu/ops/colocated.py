@@ -57,7 +57,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from ..analysis import jitcheck
-from ..engine.execengine import IStepEngine
+from ..engine.execengine import APPLY_TOTALS, IStepEngine
 from . import hostplane
 from ..logger import get_logger
 from ..node import StepInputs
@@ -668,9 +668,14 @@ class ColocatedVectorEngine(VectorStepEngine):
             # device_rows_stepped went through _encode_rows one by one
             tick_lane_rows=0,
             # the apply workers' totals over every member NodeHost,
-            # folded in once a step call (_fold_apply)
+            # folded in once a step call (_fold_apply): batches and
+            # entries applied, time inside node.apply(), from hand-off
+            # to apply and inside the user state machine's update, what
+            # on-disk state machines appended to their own logs; and the
+            # members' leader changes after each group's first leader
             apply_batches=0, apply_entries=0, t_apply_ms=0.0,
-            t_apply_wait_ms=0.0,
+            t_apply_wait_ms=0.0, t_sm_update_ms=0.0, sm_wal_appends=0,
+            sm_wal_bytes=0, leader_changes=0,
             # pipeline observability: host work overlapped with an
             # in-flight readback request (the double-buffering win),
             # fences (drains to depth 0 forced by membership mutation),
@@ -729,14 +734,13 @@ class ColocatedVectorEngine(VectorStepEngine):
         ``stats`` keeps one writer and lags by one step call at most."""
         st = self.stats
         for member in self._members:
-            tot = member.apply_totals()
-            seen = member.apply_folded
+            tot = member.totals()
+            seen = member.folded
             if tot != seen:
-                st["apply_batches"] += tot[0] - seen[0]
-                st["apply_entries"] += tot[1] - seen[1]
-                st["t_apply_ms"] += (tot[2] - seen[2]) * 1000.0
-                st["t_apply_wait_ms"] += (tot[3] - seen[3]) * 1000.0
-                member.apply_folded = tot
+                for name, now in tot.items():
+                    key, scale = _MEMBER_TOTALS[name]
+                    st[key] += (now - seen.get(name, 0)) * scale
+                member.folded = tot
 
     # -- persist: one phase over every call, the WAL's share inside -----
     def _persisting(self, persist, work, worker_id: int) -> None:
@@ -1272,20 +1276,30 @@ class ColocatedVectorEngine(VectorStepEngine):
             return
         if self._inflight:
             self.stats["pipeline_fences"] += 1
-        updates = self._drain_pipeline()
+        self._drain_pipeline()
+
+    def _persist_completed(self, updates: List[Tuple]) -> None:
+        """Persist what one completion (or the deferred actions)
+        emitted BEFORE the next generation completes.  A completion's
+        early pass (_lane_commit_pass) persists its rows and hands
+        their committed entries to apply at once; a classic update of
+        the SAME node from the generation before, still waiting in a
+        list, would then be saved and processed after it: an older hard
+        state written over a newer one, and ``invalid processed`` out
+        of ``log.commit_update`` (the cursor had moved past it).  So no
+        update outlives the completion that emitted it."""
         if updates:
             self._drain_update_retries(updates)
             self._persist_and_process(updates, self._last_worker_id)
 
-    def _drain_pipeline(self) -> List[Tuple]:
-        """Complete every in-flight generation in dispatch order, then
-        run the deferred actions; returns the updates to persist."""
-        updates: List[Tuple] = []
+    def _drain_pipeline(self) -> None:
+        """Complete every in-flight generation in dispatch order,
+        persisting each one's updates as it completes, then run and
+        persist the deferred actions."""
         while self._inflight:
-            updates.extend(self._complete_oldest())
-        updates.extend(self._run_deferred())
+            self._persist_completed(self._complete_oldest())
+        self._persist_completed(self._run_deferred())
         self._flush_free_pending()
-        return updates
 
     def _complete_oldest(self) -> List[Tuple]:
         rec = self._inflight.popleft()
@@ -1626,7 +1640,6 @@ class ColocatedVectorEngine(VectorStepEngine):
         # from the pipe-full room check several generations later.
         # Runs before planning, so the plan also sees the freshest
         # merged scalars the link can provide.
-        ripe: List[Tuple] = []
         while self._inflight:
             rec = self._inflight[0]
             if self._sync_floor_s > 0:
@@ -1641,10 +1654,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                 for dev in (*rec.head_dev, *rec.detail_dev)
             ):
                 break
-            ripe.extend(self._complete_oldest())
-        if ripe:
-            self._drain_update_retries(ripe)
-            self._persist_and_process(ripe, worker_id)
+            self._persist_completed(self._complete_oldest())
         if self._deferred:
             # deferred membership actions (recorded mid-merge, e.g. a
             # save-failure eviction during the driver's persist or an
@@ -1863,7 +1873,9 @@ class ColocatedVectorEngine(VectorStepEngine):
         # before the next dispatch.
         if (not launched) or self._pipeline_depth == 1 or self._deferred:
             while self._inflight:
-                updates.extend(self._complete_oldest())
+                # in emission order: this call's own updates first
+                self._persist_completed(updates)
+                updates = self._complete_oldest()
         if self._deferred and not self._inflight:
             updates.extend(self._run_deferred())
         self._flush_free_pending()
@@ -2212,12 +2224,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         # TWO generations, the second still mid-floor — a systematic
         # in-lock stall that measured worse than the wait it removed.)
         while len(self._inflight) >= self._pipeline_depth:
-            room_updates = self._complete_oldest()
-            if room_updates:
-                self._drain_update_retries(room_updates)
-                self._persist_and_process(
-                    room_updates, self._last_worker_id
-                )
+            self._persist_completed(self._complete_oldest())
         # encode: the batch into inbox rows, the per-launch [G] inputs
         # and the host inbox onto the device — everything between the
         # row upload and the first program of the wave
@@ -3314,7 +3321,13 @@ class ColocatedVectorEngine(VectorStepEngine):
         return updates
 
 
-_NO_APPLY = (0, 0, 0.0, 0.0)  # ExecEngine.apply_totals() of no work
+# what _ColocatedFacade.totals() counts, name -> (stats key, scale):
+# ExecEngine.APPLY_TOTALS, seconds folded in as ms, and the NodeHost's
+# leader_changes
+_MEMBER_TOTALS = {
+    name: (name[:-1] + "ms", 1000.0) if name.startswith("t_") else (name, 1)
+    for name in (*APPLY_TOTALS, "leader_changes")
+}
 
 
 class _ColocatedFacade(IStepEngine):
@@ -3325,9 +3338,9 @@ class _ColocatedFacade(IStepEngine):
     def __init__(self, core: ColocatedVectorEngine, nodehost=None):
         self.core = core
         self._nodehost = nodehost
-        # its apply totals as of the core's last _fold_apply (written
-        # there, under the core lock)
-        self.apply_folded = _NO_APPLY
+        # its totals as of the core's last _fold_apply (written there,
+        # under the core lock)
+        self.folded: Dict[str, float] = {}
         self._replica_of: Dict[int, int] = {}
 
     @property
@@ -3339,11 +3352,16 @@ class _ColocatedFacade(IStepEngine):
             self._replica_of[n.shard_id] = n.replica_id
         self.core.step_shards(nodes, worker_id)
 
-    def apply_totals(self) -> tuple:
-        """This member's ``ExecEngine.apply_totals()``; zeros until the
-        NodeHost has built its engine (the factory runs before that)."""
-        engine = getattr(self._nodehost, "engine", None)
-        return engine.apply_totals() if engine is not None else _NO_APPLY
+    def totals(self) -> dict:
+        """This member's ``ExecEngine.apply_totals()`` and its
+        NodeHost's ``leader_changes``, by the names of
+        ``_MEMBER_TOTALS``; nothing until the NodeHost has built its
+        engine (the factory runs before that)."""
+        nh = self._nodehost
+        engine = getattr(nh, "engine", None)
+        if engine is None:
+            return {}
+        return {**engine.apply_totals(), "leader_changes": nh.leader_changes}
 
     def stop(self) -> None:
         # the member is going away: take its last totals, then forget it

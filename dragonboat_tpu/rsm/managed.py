@@ -9,8 +9,10 @@ from __future__ import annotations
 
 import enum
 import threading
+import time
 from typing import BinaryIO, List, Optional
 
+from ..profiling import annotate
 from ..statemachine import (
     IConcurrentStateMachine,
     IOnDiskStateMachine,
@@ -42,6 +44,9 @@ class ManagedStateMachine:
         self.sm = sm
         self.type = sm_type
         self._mu = threading.RLock()  # regular SM: excludes update vs snapshot
+        # seconds inside the user's update(), cumulative; its one writer
+        # is the apply worker holding the node's apply lock
+        self.update_s = 0.0
 
     @property
     def on_disk(self) -> bool:
@@ -57,12 +62,22 @@ class ManagedStateMachine:
         return self.sm.open(stopc)
 
     def batched_update(self, entries: List[SMEntry]) -> List[SMEntry]:
-        if self.type == SMType.REGULAR:
-            with self._mu:
-                for e in entries:
-                    e.result = self.sm.update(e)
-                return entries
-        return self.sm.update(entries)
+        t0 = time.perf_counter()
+        try:
+            with annotate("raft-sm-update"):
+                if self.type == SMType.REGULAR:
+                    with self._mu:
+                        for e in entries:
+                            e.result = self.sm.update(e)
+                        return entries
+                return self.sm.update(entries)
+        finally:
+            self.update_s += time.perf_counter() - t0
+
+    def wal_counts(self) -> tuple:
+        """``(appends, bytes)`` the user state machine wrote to its own
+        log: an on-disk tier's ``wal_counts()``, zeros for the others."""
+        return self.sm.wal_counts() if self.type == SMType.ON_DISK else (0, 0)
 
     def lookup(self, query):
         if self.type == SMType.REGULAR:

@@ -122,6 +122,13 @@ class IOnDiskStateMachine(abc.ABC):
     @abc.abstractmethod
     def recover_from_snapshot(self, r: BinaryIO, done) -> None: ...
 
+    def wal_counts(self) -> tuple:
+        """``(appends, bytes)`` that ``update()`` has written to this
+        state machine's own log so far, cumulative (what the engines
+        report as ``sm_wal_appends`` / ``sm_wal_bytes``,
+        docs/OBSERVABILITY.md).  One that keeps no log reports zeros."""
+        return 0, 0
+
     def close(self) -> None:
         pass
 

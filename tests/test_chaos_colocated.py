@@ -26,6 +26,7 @@ and campaigning, exactly a network partition.
 """
 import os
 import random
+import shutil
 import threading
 import time
 
@@ -74,7 +75,14 @@ class ColocatedCluster(Cluster):
         super().__init__(seed=seed)
 
     def _dir(self, rid):
-        return f"/tmp/nh-cchaos-{rid}"
+        # a directory a process: test_updatelanes.py builds this cluster
+        # too, and under xdist the two files can run at the same time
+        return f"/tmp/nh-cchaos-{os.getpid()}-{rid}"
+
+    def close(self):
+        super().close()
+        for rid in self.ADDRS:
+            shutil.rmtree(self._dir(rid), ignore_errors=True)
 
     def config(self, rid):
         return colo_chaos_config(rid)

@@ -639,6 +639,76 @@ class TestOverload:
 
 
 # ---------------------------------------------------------------------------
+# the ReadIndex fallback: one attempt is not the whole deadline
+# ---------------------------------------------------------------------------
+class _LostReadHost:
+    """A NodeHost stand-in for the read path alone: no lease, and a
+    ``sync_read`` whose first ``lose`` requests are never answered (a
+    ReadIndex request lost to a change of leader), so each of them
+    waits its timeout out and fails, as ``NodeHost.sync_read`` does."""
+
+    def __init__(self, lose: int):
+        self.lose = lose
+        self.timeouts = []
+
+    def is_leader_of(self, shard_id):
+        return True
+
+    def _get_node(self, shard_id):
+        return object()
+
+    def lease_read(self, shard_id, query, margin_ticks=2):
+        from dragonboat_tpu.node import LEASE_MISS_UNREPORTED
+
+        return LEASE_MISS_UNREPORTED, None
+
+    def sync_read(self, shard_id, query, timeout=5.0):
+        from dragonboat_tpu.nodehost import TimeoutError_
+
+        self.timeouts.append(timeout)
+        if len(self.timeouts) <= self.lose:
+            time.sleep(timeout)
+            raise TimeoutError_("TIMEOUT")
+        return f"value-of-{query}"
+
+
+class TestReadIndexFallbackPerTry:
+    def test_a_lost_read_index_request_costs_one_try_not_the_deadline(self):
+        budget = LatencyBudget(bootstrap=0.05, floor=0.05, election_window=0.1)
+        per_try = budget.per_try_timeout()
+        assert per_try == pytest.approx(0.2)
+        host = _LostReadHost(lose=2)
+        gw = Gateway({"h1": host}, GatewayConfig(budget=budget))
+        try:
+            t0 = time.monotonic()
+            assert gw.read(7, "k", timeout=30.0) == "value-of-k"
+            took = time.monotonic() - t0
+        finally:
+            gw.close()
+        # two tries lost, the third answered: two per-try waits, not 30 s
+        assert host.timeouts == [pytest.approx(per_try)] * 3
+        assert 2 * per_try <= took < 5.0
+        assert gw.stats()["read_fallbacks"] == 1
+
+    def test_the_deadline_still_bounds_the_last_try_and_the_read(self):
+        from dragonboat_tpu.nodehost import TimeoutError_
+
+        budget = LatencyBudget(bootstrap=0.05, floor=0.05, election_window=0.1)
+        host = _LostReadHost(lose=10**6)
+        gw = Gateway({"h1": host}, GatewayConfig(budget=budget))
+        try:
+            t0 = time.monotonic()
+            with pytest.raises(TimeoutError_):
+                gw.read(7, "k", timeout=0.5)
+            took = time.monotonic() - t0
+        finally:
+            gw.close()
+        assert 0.5 <= took < 2.0
+        assert max(host.timeouts) <= 0.2 + 1e-6   # never over one try
+        assert min(host.timeouts) > 0.0           # the last one: what was left
+
+
+# ---------------------------------------------------------------------------
 # snapshot-cap feedback auto-wiring (ROADMAP 5a)
 # ---------------------------------------------------------------------------
 class _CapFakeHost:
