@@ -62,9 +62,13 @@ class WorkReady:
             self._conds[p].notify()
 
     def notify_all(self, shard_ids) -> None:
-        by_p: Dict[int, List[int]] = {}
-        for s in shard_ids:
-            by_p.setdefault(self.partition(s), []).append(s)
+        if self.partitions == 1:
+            # nothing to group: one set update under one lock round
+            by_p = {0: shard_ids} if shard_ids else {}
+        else:
+            by_p: Dict[int, List[int]] = {}
+            for s in shard_ids:
+                by_p.setdefault(self.partition(s), []).append(s)
         for p, ids in by_p.items():
             with self._conds[p]:
                 self._sets[p].update(ids)
@@ -230,6 +234,7 @@ class ExecEngine:
         # handoff (ops/engine._apply_lane_commits): one notify_all per
         # partition per generation instead of one lock take per row
         node.apply_work_ready = self.apply_ready
+        node.step_work_ready = self.step_ready
         with self._nodes_lock:
             self._nodes[node.shard_id] = node
         self.step_ready.notify(node.shard_id)
@@ -289,10 +294,12 @@ class ExecEngine:
                 self.step_worker_failures += 1
                 self._step_failures.add()
                 _log.exception("step worker %d failed", worker_id)
-            # shards with remaining work re-arm immediately
-            for n in nodes:
-                if n.has_work():
-                    self.step_ready.notify(n.shard_id)
+            # shards with remaining work re-arm immediately: one lock
+            # round for all of them, not one a shard (~1,000 rounds on
+            # the lock the colocated engine's wake takes next)
+            again = [n.shard_id for n in nodes if n.has_work()]
+            if again:
+                self.step_ready.notify_all(again)
 
     def _apply_worker_main(self, worker_id: int) -> None:
         while not self._stop.is_set():

@@ -138,7 +138,7 @@ class Node:
         "_snapshotting",
         "_applied_since_snapshot", "_retired_snapshots", "_apply_lock",
         "_sm_close_lock", "notify_work", "engine_apply_ready",
-        "apply_work_ready",
+        "apply_work_ready", "step_work_ready",
         "log_reader", "sm", "_stop_event", "peer", "quiesce",
         "wake", "parked_at_tick", "tracer", "_trace_spans",
     )
@@ -299,6 +299,10 @@ class Node:
         # partition through it (engine._apply_lane_commits) instead of
         # taking the partition lock once per row
         self.apply_work_ready = None
+        # the step workers' WorkReady, set at registration too: the
+        # colocated engine wakes every alive row's worker through it,
+        # one notify_all a member NodeHost (colocated._wake_alive)
+        self.step_work_ready = None
 
         # --- storage views ----------------------------------------------
         bootstrap = logdb.get_bootstrap_info(config.shard_id, config.replica_id)

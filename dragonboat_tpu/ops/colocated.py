@@ -44,6 +44,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
+import operator
 import os as _os
 import threading
 import time as _time
@@ -124,6 +126,7 @@ from .types import (
 
 _log = get_logger("engine")
 _perf = _time.perf_counter
+_STOPPED = operator.attrgetter("stopped")
 
 # -- double-buffered generations (the launch pipeline) -----------------
 # DRAGONBOAT_TPU_PIPELINE_DEPTH: how many generations may be in flight
@@ -459,6 +462,22 @@ def _scatter_inbox_rows(host: Inbox, pos, sub: Inbox) -> Inbox:
     ))
 
 
+def _lane_inputs(lane, i: int) -> StepInputs:
+    """The ``StepInputs`` of position ``i`` of a tick lane, for the
+    slow paths that need ONE row's inputs back (and the oracle)."""
+    return StepInputs(ticks=lane.ticks[i],
+                      gc_ticks=lane.gc.get(lane.gs[i], 0))
+
+
+def _emitting(live, pos_sum, touched) -> frozenset:
+    """Of ``live``, the rows a completion can emit anything for: those
+    with values this round, and those an earlier round of the wave
+    touched.  Every other live row only ticked.  (Parity oracle.)"""
+    return frozenset(
+        g for _node, g, _si in live if pos_sum[g] >= 0 or g in touched
+    )
+
+
 class _InFlightGen:
     """One dispatched-but-unmerged generation of the launch pipeline.
 
@@ -477,18 +496,27 @@ class _InFlightGen:
     round."""
 
     __slots__ = (
-        "batch", "staging", "alive_np", "batch_gs", "prop_gs", "caps",
-        "merged", "out", "head_dev", "detail_dev", "t_req", "tick_fed",
-        "rounds",
+        "batch", "lane", "staging", "alive_np", "batch_gs", "fed",
+        "prop_gs", "caps", "merged", "out", "head_dev", "detail_dev",
+        "t_req", "rounds", "clocked",
     )
 
-    def __init__(self, *, batch, staging, alive_np, batch_gs, prop_gs,
-                 caps, merged, out, head_dev, detail_dev, t_req,
-                 tick_fed=None, rounds=1):
+    def __init__(self, *, batch, lane, staging, alive_np, batch_gs, fed,
+                 prop_gs, caps, merged, out, head_dev, detail_dev, t_req,
+                 rounds=1):
+        # the stepped rows, split in two: ``batch`` holds the ACTIVE
+        # rows as (node, g, si, plan) tuples, ``lane`` the tick-only
+        # rows as columns (hostplane.TickLane).  ``batch_gs`` is the
+        # whole stepped set's row ids, active rows first and the lane's
+        # after, as the device's _C_BATCH column and build_merge_sets
+        # see it: a position past len(batch) names a lane row.  ``fed``
+        # is parallel to it: the ticks each row was fed.
         self.batch = batch
+        self.lane = lane
         self.staging = staging
         self.alive_np = alive_np
         self.batch_gs = batch_gs
+        self.fed = fed
         self.prop_gs = prop_gs
         self.caps = caps
         self.merged = merged          # per-round list of state handles
@@ -496,8 +524,20 @@ class _InFlightGen:
         self.head_dev = head_dev      # per-round list of head blobs
         self.detail_dev = detail_dev  # per-round list of detail blobs
         self.t_req = t_req
-        self.tick_fed = tick_fed or {}
         self.rounds = rounds
+        # both clocks of the generation's rows advanced (the
+        # completion's bookkeeping ran, or a pipeline reset did it)
+        self.clocked = False
+
+    def stepped_row(self, i: int) -> Tuple:
+        """``(node, g, si)`` of position ``i`` of ``batch_gs``; a lane
+        row's ``StepInputs`` is made here, for the one row asked for."""
+        n = len(self.batch)
+        if i < n:
+            node, g, si, _plan = self.batch[i]
+            return node, g, si
+        lane = self.lane
+        return lane.nodes[i - n], lane.gs[i - n], _lane_inputs(lane, i - n)
 
 
 class ColocatedVectorEngine(VectorStepEngine):
@@ -624,6 +664,11 @@ class ColocatedVectorEngine(VectorStepEngine):
         # the member NodeHosts' facades, whose apply workers' totals
         # _fold_apply folds into stats
         self._members: List["_ColocatedFacade"] = []
+        # the wake lane: per row, which member's step-worker WorkReady
+        # its node registered with (-1: none), so that _wake_alive
+        # reaches every alive row's worker without a call a row
+        self._wake_slot = np.full((capacity,), -1, np.int64)
+        self._wake_ready: List = []
         super().__init__(None, capacity=capacity, P=P, W=W, M=M, E=E, O=O,
                          device=device, mesh=mesh)
         # nemesis escalations are consumed at plan time here: routed
@@ -667,6 +712,13 @@ class ColocatedVectorEngine(VectorStepEngine):
             # Message objects: hostplane.encode_tick_lane); the rest of
             # device_rows_stepped went through _encode_rows one by one
             tick_lane_rows=0,
+            # rows the completion tail touched in Python: the live list
+            # of every round (active rows, lane rows the round's flags
+            # mark, resident rows with effects), plus the rows the
+            # lease pass armed, disarmed, started a window of or
+            # anchored.  The tick lane's other rows are clocked in one
+            # two-column loop and not counted
+            completion_rows_walked=0,
             # the apply workers' totals over every member NodeHost,
             # folded in once a step call (_fold_apply): batches and
             # entries applied, time inside node.apply(), from hand-off
@@ -774,18 +826,73 @@ class ColocatedVectorEngine(VectorStepEngine):
             st["wal_bytes"] += after[1] - before[1]
             st["wal_records"] += after[2] - before[2]
 
-    def _lease_pass(self, live, flags, vals_np, pos_sum,
-                    tick_fed) -> None:
+    def _lease_pass(  # hostplane-hot
+        self, nodes, gs, fed, skip, flags, vals_np, pos_sum
+    ) -> Tuple[int, np.ndarray]:
         """Per-generation device-lease evidence pass (ROADMAP 4b): see
         hostplane.LeaseLanes.  Runs before the bulk mirror write (role
-        transitions read the OLD mirror) and before per-row tick
-        bookkeeping (window starts stamp the pre-launch clock — the
-        conservative side)."""
+        transitions read the OLD mirror) and before tick bookkeeping
+        (window starts stamp the pre-launch clock — the conservative
+        side).
+
+        An array pass over the completion's rows: ``gs`` (the stepped
+        rows, then the other live ones), ``fed`` the ticks each was
+        fed, ``nodes`` their nodes and ``skip`` the rows to leave alone
+        (escalated, stopped, detached; None = none).  Python touches a
+        row only where its role changed (arm / disarm), its window
+        crossed (the start is the row's own clock) or its anchor moved
+        since it was last applied (hostplane.LeaseLanes.lanes_step);
+        returns how many rows that was, and the rows that hold an
+        anchor this launch.  ``_lease_pass_rows`` is the per-row twin
+        the parity oracle runs beside it."""
+        lease = self._lease
+        if skip is not None:
+            keep = ~skip
+            gs, fed = gs[keep], fed[keep]
+            nodes = list(itertools.compress(nodes, keep.tolist()))
+        walked = 0
+        if vals_np is not None and len(vals_np):
+            k = pos_sum[gs]
+            has = np.nonzero(k >= 0)[0]
+            g_has = gs[has]
+            roles = vals_np[k[has], _R_ROLE]
+            chg = np.nonzero(roles != self._mirror[_R_ROLE, g_has])[0]
+            walked += len(chg)
+            # raftlint: ignore[host-loop] residue: the rows whose role changed this launch
+            for c in chg.tolist():
+                r = nodes[has[c]].peer.raft
+                if int(roles[c]) == _ROLE_LEADER_I and r.check_quorum:
+                    lease.arm(int(g_has[c]), r.election_timeout, 0)
+                else:
+                    lease.disarm(int(g_has[c]))
+        crossed, held, moved = lease.lanes_step(gs, fed, flags)
+        walked += len(crossed) + len(moved)
+        # raftlint: ignore[host-loop] residue: the rows whose window crossed (one in election_timeout launches a leader)
+        for i in crossed.tolist():
+            # the device's CheckQuorum sweep ran this launch: a fresh
+            # window starts on this row's clock NOW
+            lease.window_start[gs[i]] = nodes[i].tick_count
+        # raftlint: ignore[host-loop] residue: the rows whose anchor moved (once a window a leader)
+        for i in moved.tolist():
+            nodes[i].peer.raft.anchor_quorum_evidence(
+                int(lease.window_start[gs[i]])
+            )
+        return walked, gs[held]
+
+    # raftlint: ignore[host-loop] parity oracle: the per-row pass the array one replaced
+    def _lease_pass_rows(self, live, flags, vals_np, pos_sum, tick_fed,
+                         lease) -> Dict[int, int]:
+        """Per-row twin of :meth:`_lease_pass`, as every completion ran
+        it before PR 29: over ``live`` tuples, one ``row_step`` a row.
+        Steps ``lease`` (the oracle hands it a copy of the lanes) and
+        returns row -> anchor for the rows that hold one; the caller
+        compares, nothing is applied."""
+        anchors: Dict[int, int] = {}
         for node, g, si in live:
             if node.stopped or self._meta.get(g) is None:
                 continue
             r = node.peer.raft
-            if vals_np is not None:
+            if vals_np is not None and len(vals_np):
                 k = int(pos_sum[g])
                 if k >= 0:
                     role = int(vals_np[k, _R_ROLE])
@@ -794,14 +901,15 @@ class ColocatedVectorEngine(VectorStepEngine):
                             role == int(RaftRole.LEADER)
                             and r.check_quorum
                         ):
-                            self._lease.arm(g, r.election_timeout, 0)
+                            lease.arm(g, r.election_timeout, 0)
                         else:
-                            self._lease.disarm(g)
-            a = self._lease.row_step(
+                            lease.disarm(g)
+            a = lease.row_step(
                 g, tick_fed.get(g, 0), node.tick_count, int(flags[g])
             )
             if a >= 0:
-                r.anchor_quorum_evidence(a)
+                anchors[g] = a
+        return anchors
 
     def device_coordinate(self, shard_id: int, replica_id=None):
         if self._mesh is None:
@@ -888,7 +996,23 @@ class ColocatedVectorEngine(VectorStepEngine):
             self._host_replica[g] = node.replica_id
             self._host_peers[g, :] = 0
             self._tables_dirty = True
+            self._wake_slot[g] = self._wake_slot_of(node)
         return g
+
+    def _wake_slot_of(self, node) -> int:
+        ready = getattr(node, "step_work_ready", None)
+        if ready is None:
+            return -1
+        for i, known in enumerate(self._wake_ready):
+            if known is ready:
+                return i
+        for i in range(len(self._wake_ready)):
+            if not (self._wake_slot == i).any():
+                # a member that left with all its rows: take its place
+                self._wake_ready[i] = ready
+                return i
+        self._wake_ready.append(ready)
+        return len(self._wake_ready) - 1
 
     def _release_row(self, g: int, shard_id: int) -> None:
         """Clear the route-table claim of a freed row (caller holds the
@@ -899,6 +1023,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         self._host_shard[g] = 0
         self._host_replica[g] = 0
         self._host_peers[g, :] = 0
+        self._wake_slot[g] = -1
         self._lanes.reset_row(g, attached=False)
         self._tables_dirty = True
         if not any(
@@ -1310,6 +1435,8 @@ class ColocatedVectorEngine(VectorStepEngine):
             # the generation chain is poisoned (its outputs feed every
             # later in-flight handle): roll the resident set back to
             # the last merged generation
+            if not rec.clocked:
+                self._clock_unmerged(rec.batch, rec.lane)
             self._reset_after_pipeline_failure()
             raise
         finally:
@@ -1426,6 +1553,12 @@ class ColocatedVectorEngine(VectorStepEngine):
         # will never be collected, so account them here
         self.stats["pipeline_resets"] += 1
         self.stats["readback_windows"] += len(self._inflight)
+        # the discarded generations' ticks left their nodes' tick lanes
+        # at the plan loop; their completions' bookkeeping will never
+        # run, so both clocks advance here: no tick is lost to a reset
+        for rec in self._inflight:
+            if not rec.clocked:
+                self._clock_unmerged(rec.batch, rec.lane)
         self._inflight.clear()
         self._pending_live = False
         self._flush_free_pending()
@@ -1663,16 +1796,19 @@ class ColocatedVectorEngine(VectorStepEngine):
             self._fence()
         updates: List[Tuple] = []
         host_rows: List[Tuple] = []
+        # the stepped rows, split by what the plan loop saw of each:
+        # the tick lane (every row whose whole input is hint-free
+        # ticks — carried as parallel columns from here to the end of
+        # the completion, never as a tuple, a StepInputs, a plan list
+        # or a Message: hostplane.TickLane) and ``batch``, the active
+        # rows, the only ones the launch and its completion walk in
+        # Python
         batch: List[Tuple] = []
-        # the batch, split by what the plan loop saw of each row: the
-        # tick lane (row id and fused count of every row whose whole
-        # input is hint-free ticks — carried as two parallel lists,
-        # never as Message objects) and the active rows, the only ones
-        # the encode phase walks in Python (see _encode_generation)
-        batch_gs: List[int] = []
-        tick_gs: List[int] = []
-        tick_n: List[int] = []
-        active: List[Tuple] = []
+        lane = hostplane.TickLane()
+        lane_g = lane.gs.append
+        lane_fed = lane.fed.append
+        lane_ticks = lane.ticks.append
+        lane_node = lane.nodes.append
         tok = self._enter("t_coalesce_ms", "raft-colocated-coalesce")
         nodes = self._coalesce(nodes)
         self._maybe_rebase_shards(nodes)
@@ -1696,6 +1832,10 @@ class ColocatedVectorEngine(VectorStepEngine):
         static_arr = hostplane.classify_static(
             self._lanes, np.asarray(gs_list, np.int64)
         )
+        # completions so far: one that runs between here and the upload
+        # (a fence) is the only thing that can dirty a row classified
+        # clean just now
+        merged_before = self.stats["readback_windows"]
         if hostplane.PARITY:
             hostplane.check_classify_parity(
                 self._lanes, gs_list, static_arr
@@ -1763,17 +1903,21 @@ class ColocatedVectorEngine(VectorStepEngine):
                         ticks_dev = ticks
                     n_fast += 1
                     if ticks_dev:
-                        si = StepInputs(ticks=ticks, gc_ticks=gc_t)
-                        row = (node, g, si, [("tick", ticks_dev)])
-                        batch.append(row)
-                        batch_gs.append(g)
                         if node.device_reads.queue:
                             # a pending device read rides the tick's
                             # hint lanes: a sparse row, not a lone tick
-                            active.append(row)
+                            batch.append((
+                                node, g,
+                                StepInputs(ticks=ticks, gc_ticks=gc_t),
+                                [("tick", ticks_dev)],
+                            ))
                         else:
-                            tick_gs.append(g)
-                            tick_n.append(ticks_dev)
+                            lane_g(g)
+                            lane_fed(ticks_dev)
+                            lane_ticks(ticks)
+                            lane_node(node)
+                            if gc_t:
+                                lane.gc[g] = gc_t
                     else:
                         _tick_bookkeeping(node, ticks + gc_t)
                     continue
@@ -1799,10 +1943,7 @@ class ColocatedVectorEngine(VectorStepEngine):
             if not plan and not self._meta[g].dirty:
                 _tick_bookkeeping(node, si.ticks + si.gc_ticks)
                 continue
-            row = (node, g, si, plan)
-            batch.append(row)
-            batch_gs.append(g)
-            active.append(row)
+            batch.append((node, g, si, plan))
 
         self._evict_rows_to_host([
             g
@@ -1828,21 +1969,38 @@ class ColocatedVectorEngine(VectorStepEngine):
             ) + n_fast
         self._leave(tok)
         launched = False
-        if batch or self._pending_live:
-            if self._pending_live or any(plan for _, _, _, plan in batch):
+        if batch or lane.gs or self._pending_live:
+            # (a lane row always has a plan: its one tick)
+            if (
+                self._pending_live
+                or lane.gs
+                or any(plan for _, _, _, plan in batch)
+            ):
                 tok = self._enter("t_upload_ms", "raft-colocated-upload")
                 dirty_lane = self._lanes.dirty  # one load; np bool [G]
-                self._upload_rows(
-                    [
-                        (g, node.peer.raft)
-                        for node, g, si, plan in batch
-                        if dirty_lane[g]
-                    ]
-                )
+                rows = [
+                    (g, node.peer.raft)
+                    for node, g, si, plan in batch
+                    if dirty_lane[g]
+                ]
+                if lane.gs and (
+                    self.stats["readback_windows"] != merged_before
+                ):
+                    # a lane row is clean by classify_static; one that
+                    # a fence's completion or its deferred actions
+                    # dirtied since (an escalation's eviction, a halt)
+                    # uploads again, as a batch row always did
+                    # raftlint: ignore[sync-budget] host-built index array, not a device readback
+                    stale = np.nonzero(
+                        dirty_lane[np.asarray(lane.gs, np.int64)]
+                    )[0]
+                    for i in stale.tolist():
+                        rows.append(
+                            (lane.gs[i], lane.nodes[i].peer.raft)
+                        )
+                self._upload_rows(rows)
                 self._leave(tok)
-                self._launch_generation(
-                    batch, batch_gs, active, tick_gs, tick_n
-                )
+                self._launch_generation(batch, lane)
                 launched = True
             else:
                 # pure preload: nothing to step and no routed traffic in
@@ -1894,7 +2052,9 @@ class ColocatedVectorEngine(VectorStepEngine):
             # finding), so fall back to any alive resident node.
             tok = self._enter("t_wake_ms", "raft-colocated-wake")
             poked = False
-            for node, _g, _si, _plan in batch:
+            for node in itertools.chain(
+                (row[0] for row in batch), lane.nodes
+            ):
                 if not node.stopped and node.notify_work is not None:
                     node.notify_work()
                     poked = True
@@ -1952,7 +2112,9 @@ class ColocatedVectorEngine(VectorStepEngine):
         launch-rate above the wall-tick cadence makes them the
         majority) skip with two attribute loads; ticked rows advance
         both clocks and take the hint-gated single-lock pending-table
-        sweep inside _tick_bookkeeping."""
+        sweep inside _tick_bookkeeping.  ``live`` rows carry a
+        ``StepInputs`` only if they are ACTIVE batch rows: the tick
+        lane's rows are clocked by :meth:`_bookkeeping_lane`."""
         meta_get = self._meta.get
         for node, g, si in live:
             if si is None:
@@ -1969,6 +2131,218 @@ class ColocatedVectorEngine(VectorStepEngine):
                         node.pending_tables,
                         node.pending_deadline_hint, tc,
                     )
+
+    def _bookkeeping_lane(self, lane, skip) -> None:
+        """:meth:`_bookkeeping_pass` for the tick lane: its node and
+        clock-tick columns side by side, no tuple and no
+        ``StepInputs`` a row (a lane row always ticked).  ``skip``
+        marks the lane rows to leave alone (escalated, stopped,
+        detached), None where there are none.  The clocks are node
+        attributes, so this is still one short loop body a row."""
+        ticks = lane.clock_np.tolist()
+        rows = zip(lane.nodes, ticks)
+        if skip is not None:
+            rows = itertools.compress(rows, (~skip).tolist())
+        for node, t in rows:
+            tc = node.tick_count + t
+            node.tick_count = tc
+            node.peer.raft.tick_count += t
+            if tc >= node.pending_deadline_hint[0]:
+                gc_tables(
+                    node.pending_tables, node.pending_deadline_hint, tc
+                )
+
+    def _clock_unmerged(self, batch, lane) -> None:
+        """Advance both clocks for a generation whose completion will
+        never run (a launch that raised, a pipeline reset): the ticks
+        it drained are gone from the nodes' tick lanes, and a clock
+        that lost them would deliver every pending deadline late."""
+        for node, _g, si, _plan in batch:
+            if si is not None and not node.stopped:
+                _tick_bookkeeping(node, si.ticks + si.gc_ticks)
+        if len(lane):
+            self._bookkeeping_lane(
+                lane, self._skip_mask(lane.nodes, lane.gs_np)
+            )
+
+    def _skip_mask(  # hostplane-hot
+        self, nodes, gs, esc_seen=(), n_stepped: int = 0
+    ) -> Optional[np.ndarray]:
+        """[n] bool over a completion's rows: those it leaves alone, as
+        the per-row passes did — detached since the launch, stopped,
+        or (of the first ``n_stepped``, the stepped rows) escalated in
+        any round of the wave, whose recovery is the deferred evict +
+        replay.  None where no row is."""
+        skip = ~self._lanes.attached[gs]
+        if any(map(_STOPPED, nodes)):
+            skip |= np.fromiter(map(_STOPPED, nodes), bool, len(nodes))
+        if esc_seen:
+            skip[:n_stepped] |= np.isin(gs[:n_stepped], list(esc_seen))
+        return skip if skip.any() else None
+
+    def _clock_and_lease(self, rec, live, flags, vals_np, pos_sum,
+                         esc_seen, touched, whole=None) -> None:
+        """One completion's lease pass, then its tick bookkeeping (in
+        that order: window starts stamp the PRE-launch clock), over
+        the stepped rows as arrays plus the few other live rows.
+        ``whole`` (the parity oracle's: the live list as every
+        completion built it before PR 29, one tuple a stepped row) has
+        the per-row passes worked out beside the array ones and the
+        two compared (hostplane.check_completion_parity)."""
+        batch, lane = rec.batch, rec.lane
+        n_act, n_step = len(batch), len(rec.batch_gs)
+        gs, fed = rec.batch_gs, rec.fed
+        nodes = [row[0] for row in batch]
+        nodes += lane.nodes
+        stepped = np.zeros((self.capacity,), bool)
+        stepped[gs] = True
+        others = [
+            (node, g) for node, g, si in live
+            if si is None and not stepped[g]
+        ]
+        if others:
+            nodes += [node for node, _g in others]
+            gs = np.concatenate([gs, [g for _n, g in others]])
+            fed = np.concatenate([fed, np.zeros((len(others),), np.int64)])
+        skip = self._skip_mask(nodes, gs, esc_seen, n_step)
+        if whole is not None:
+            want = self._completion_reference(
+                rec, whole, flags, vals_np, pos_sum, touched
+            )
+        walked, held = self._lease_pass(
+            nodes, gs, fed, skip, flags, vals_np, pos_sum
+        )
+        self.stats["completion_rows_walked"] += walked
+        self._bookkeeping_pass(live)
+        if len(lane):
+            self._bookkeeping_lane(
+                lane, None if skip is None else skip[n_act:n_step]
+            )
+        rec.clocked = True
+        if whole is not None:
+            lease = self._lease
+            rows = want.rows
+            hostplane.check_completion_parity(
+                hostplane.CompletionTrace(
+                    emitted=_emitting(live, pos_sum, touched),
+                    rows=rows, et=lease.et[rows],
+                    dev_el=lease.dev_el[rows],
+                    window_start=lease.window_start[rows],
+                    anchors=dict(zip(
+                        held.tolist(),
+                        lease.window_start[held].tolist(),
+                    )),
+                    clocks={
+                        g: (node.tick_count, node.peer.raft.tick_count)
+                        for node, g, _si in whole
+                    },
+                ),
+                want,
+            )
+
+    # raftlint: ignore[host-loop] parity oracle: the per-row passes worked out over the whole live list
+    def _completion_reference(self, rec, whole, flags, vals_np, pos_sum,
+                              touched) -> "hostplane.CompletionTrace":
+        """What the per-row lease and bookkeeping passes make of
+        ``whole``: the lease pass run on a COPY of the lease lanes
+        (anchors collected, none applied) and the clocks each row ends
+        on, computed and not written."""
+        lease = self._lease.copy()
+        anchors = self._lease_pass_rows(
+            whole, flags, vals_np, pos_sum,
+            dict(zip(rec.batch_gs.tolist(), rec.fed.tolist())), lease,
+        )
+        clocks = {}
+        for node, g, si in whole:
+            t = 0
+            if (
+                si is not None and not node.stopped
+                and self._meta.get(g) is not None
+            ):
+                t = si.ticks + si.gc_ticks
+            clocks[g] = (node.tick_count + t,
+                         node.peer.raft.tick_count + t)
+        rows = np.asarray([g for _n, g, _si in whole], np.int64)
+        return hostplane.CompletionTrace(
+            emitted=_emitting(whole, pos_sum, touched),
+            rows=rows, et=lease.et[rows], dev_el=lease.dev_el[rows],
+            window_start=lease.window_start[rows], anchors=anchors,
+            clocks=clocks,
+        )
+
+    def _wake_alive(self) -> None:  # hostplane-hot
+        """Wake the step worker of every alive resident row: the same
+        ready sets as one ``notify_work()`` a row, through one
+        ``WorkReady.notify_all`` a member NodeHost (what its
+        ``ExecEngine.notify_many`` is) — one lock round a partition,
+        not one a row."""
+        alive = self._lanes.alive_mask()
+        slot = self._wake_slot
+        # raftlint: ignore[host-loop] one pass a member NodeHost, not a row
+        for i, ready in enumerate(self._wake_ready):
+            ids = self._host_shard[alive & (slot == i)]
+            if len(ids):
+                ready.notify_all(ids.tolist())
+        rest = alive & (slot < 0)
+        if rest.any():
+            # nodes no ExecEngine registered (direct-drive tests,
+            # bespoke engines): the per-row call, where there is one
+            # raftlint: ignore[host-loop] fallback for nodes without a step_work_ready
+            for g in np.nonzero(rest)[0].tolist():
+                meta = self._meta.get(g)
+                if meta is not None and meta.node.notify_work is not None:
+                    meta.node.notify_work()
+
+    def _live_rows(self, rec, flags, sets, esc_seen,
+                   esc_other: bool) -> List[Tuple]:
+        """The rows of one round a completion walks in Python, as
+        ``(node, g, si)``: the ACTIVE batch rows, the tick lane's rows
+        the round's flags mark (``F_ANY_LIVE``: one mask over the
+        lane's row ids — an unmarked lane row only ticked, and the
+        array passes clock and lease it), and the resident rows routed
+        traffic gave effects to (``sets.live_other``); less the rows
+        escalated in any round so far (``esc_other``: the other rows
+        too — the intermediate rounds' rule)."""
+        if esc_seen:
+            live = [
+                (node, g, si) for node, g, si, _plan in rec.batch
+                if g not in esc_seen
+            ]
+        else:
+            live = [(node, g, si) for node, g, si, _plan in rec.batch]
+        lane = rec.lane
+        if len(lane):
+            hit = np.nonzero((flags[lane.gs_np] & _F_ANY_LIVE) != 0)[0]
+            gs, nodes = lane.gs, lane.nodes
+            for i in hit.tolist():
+                if gs[i] not in esc_seen:
+                    live.append((nodes[i], gs[i], None))
+        meta_get = self._meta.get
+        for g in sets.live_other.tolist():
+            if esc_other and g in esc_seen:
+                continue
+            meta = meta_get(g)
+            if meta is not None:
+                live.append((meta.node, g, None))
+        return live
+
+    # raftlint: ignore[host-loop] parity oracle: the whole-batch live list every completion built before PR 29
+    def _live_rows_whole(self, rec, sets, esc_seen,
+                         esc_other: bool) -> List[Tuple]:
+        """Per-row twin of :meth:`_live_rows`: every stepped row as a
+        tuple with its ``StepInputs``, lane rows included."""
+        n = len(rec.batch_gs)
+        live = [
+            row for row in map(rec.stepped_row, range(n))
+            if row[1] not in esc_seen
+        ]
+        for g in sets.live_other.tolist():
+            if esc_other and g in esc_seen:
+                continue
+            meta = self._meta.get(g)
+            if meta is not None:
+                live.append((meta.node, g, None))
+        return live
 
     def _lane_commit_pass(self, live, flags, pos_sum, pos_buf, pos_slot,
                           pos_need, vals_np, early_done) -> None:
@@ -2161,20 +2535,21 @@ class ColocatedVectorEngine(VectorStepEngine):
         if not had and reads.queue:
             self._read_ctx_new.append(node)
 
-    def _retake_lane_rows(self, batch, batch_gs, active, tick_gs,
-                          tick_n) -> None:
+    def _retake_lane_rows(self, batch, lane) -> None:
         """Move rows whose node registered a device read since the plan
         loop from the tick lane to the active rows (see
-        _attach_messages): O(such rows), usually none."""
+        _attach_messages), each with the ``StepInputs`` and the plan a
+        batch row has: O(such rows), usually none."""
         for node in self._read_ctx_new:
             g = self._row_of.get(self._row_key(node))
-            if g is None or not node.device_reads.queue or g not in tick_gs:
+            if g is None or not node.device_reads.queue or g not in lane.gs:
                 continue
-            row = batch[batch_gs.index(g)]
-            if row[0] is node:  # not a stale pre-restart binding
-                i = tick_gs.index(g)
-                del tick_gs[i], tick_n[i]
-                active.append(row)
+            if lane.nodes[lane.gs.index(g)] is node:  # no stale binding
+                _node, fed, ticks, gc_t = lane.take(g)
+                batch.append((
+                    node, g, StepInputs(ticks=ticks, gc_ticks=gc_t),
+                    [("tick", fed)],
+                ))
         self._read_ctx_new.clear()
 
     def _encode_generation(self, active, tick_gs,
@@ -2184,7 +2559,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         rows, and split into lone hint-free ticks (a count in the [G]
         vector) and dense inbox rows; the tick lane's rows go into the
         same vector as two arrays (hostplane.encode_tick_lane) and are
-        never walked.  With an empty lane and the whole batch as
+        never walked.  With an empty lane and the whole stepped set as
         ``active`` this is the pre-lane encode: the parity oracle."""
         # staging keys in ASSEMBLED coordinates: the routed regions
         # (width P*B) come first, host slots after (see _assemble_inbox)
@@ -2194,25 +2569,32 @@ class ColocatedVectorEngine(VectorStepEngine):
         # compact host-inbox upload: tick-only rows (the overwhelming
         # majority at scale) ride a [G] count vector built into an inbox
         # ON DEVICE; only rows with real host slots upload dense rows
-        tick_counts, tick_fed = hostplane.encode_tick_lane(
+        tick_counts = hostplane.encode_tick_lane(
             self.capacity, tick_gs, tick_n
         )
-        tick_fed.update(fed)
         sparse = hostplane.split_lone_ticks(tick_counts, active, row_msgs_of)
         return hostplane.LaunchEncode(
-            tick_counts, sparse, staging, prop_rows, tick_fed
+            tick_counts, sparse, staging, prop_rows, fed
         )
 
-    def _launch_generation(  # sync-hot
-        self, batch, batch_gs, active, tick_gs, tick_n
-    ) -> None:
+    @staticmethod
+    def _lane_as_batch(lane) -> List[Tuple]:
+        """The lane's rows as the batch tuples they were before PR 29:
+        the parity oracle's input, and nothing else's."""
+        return [
+            (node, g, _lane_inputs(lane, i), [("tick", n)])
+            for i, (node, g, n) in enumerate(
+                zip(lane.nodes, lane.gs, lane.fed))
+        ]
+
+    def _launch_generation(self, batch, lane) -> None:  # sync-hot
         """Assemble, upload and dispatch one generation, request its
         (head, detail) readback, and push the in-flight record — the
         merge tail runs later in _complete_generation (behind the
-        device by up to pipeline_depth generations).  ``batch_gs`` is
-        the batch's row ids in batch order; ``tick_gs``/``tick_n`` (the
-        tick lane) and ``active`` split the batch between them (see
-        _step_colocated).  Caller holds the core lock."""
+        device by up to pipeline_depth generations).  ``batch`` (the
+        active rows) and ``lane`` (the tick-only rows, as columns)
+        split the stepped rows between them (see _step_colocated).
+        Caller holds the core lock."""
         # room check: the pipe holds up to depth dispatched-unmerged
         # generations; complete the oldest BEFORE adding a new one so
         # each readback stays in flight across a full pipeline's worth
@@ -2231,16 +2613,27 @@ class ColocatedVectorEngine(VectorStepEngine):
         tok = self._enter("t_encode_ms", "raft-colocated-encode")
         G, M, E, P, B = self.capacity, self.M, self.E, self.P, self.budget
         if self._read_ctx_new:
-            self._retake_lane_rows(batch, batch_gs, active, tick_gs, tick_n)
-        enc = self._encode_generation(active, tick_gs, tick_n)
-        # raftlint: ignore[sync-budget] host-built index arrays, not device readbacks
-        batch_gs = np.asarray(batch_gs, np.int64)
+            self._retake_lane_rows(batch, lane)
+        lane.seal()
+        enc = self._encode_generation(batch, lane.gs_np, lane.fed_np)
+        tick_counts, sparse, staging, prop_rows, tick_fed = enc
+        # the whole stepped set, active rows first and the lane after,
+        # with the ticks each row was fed beside it
+        n_act = len(batch)
+        batch_gs = np.empty((n_act + len(lane),), np.int64)
+        batch_gs[:n_act] = [g for _, g, _, _ in batch]
+        batch_gs[n_act:] = lane.gs_np
+        fed = np.zeros((len(batch_gs),), np.int64)
+        fed[:n_act] = [tick_fed.get(g, 0) for _, g, _, _ in batch]
+        fed[n_act:] = lane.fed_np
         if hostplane.PARITY:
             n_reads = self.stats["device_reads"]
-            whole = self._encode_generation(batch, (), ())
+            whole_batch = batch + self._lane_as_batch(lane)
+            whole = self._encode_generation(whole_batch, (), ())
             self.stats["device_reads"] = n_reads
-            hostplane.check_encode_parity(batch, batch_gs, enc, whole)
-        tick_counts, sparse, staging, prop_rows, tick_fed = enc
+            hostplane.check_encode_parity(
+                whole_batch, batch_gs, enc, whole, lane
+            )
         if self._tables_dirty:
             self._rebuild_tables()
         # alive straight off the SoA lanes (attached & clean) — the old
@@ -2368,6 +2761,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                 self._pending = self._put_rows(make_inbox(G, P * B, E))
             except Exception:  # noqa: BLE001 — next launch rebuilds
                 pass
+            self._clock_unmerged(batch, lane)
             raise
         # from here the generation is the new device truth: the next
         # launch (possibly dispatched before this one merges) chains on
@@ -2432,20 +2826,20 @@ class ColocatedVectorEngine(VectorStepEngine):
                     out_l.append(out_k)
                     _sel(merged_k, out_k, stats_k, packed_k, flags_k)
         except BaseException:
+            self._clock_unmerged(batch, lane)
             self._reset_after_pipeline_failure()
             raise
         self._phase(dispatching)
         self.stats["launches"] += 1
         self.stats["device_steps"] += rounds
-        self.stats["device_rows_stepped"] += len(batch)
-        self.stats["tick_lane_rows"] += len(tick_gs)
+        self.stats["device_rows_stepped"] += len(batch_gs)
+        self.stats["tick_lane_rows"] += len(lane)
         self.stats["device_rows_active"] += len(sparse)
         self._inflight.append(_InFlightGen(
-            batch=batch, staging=staging, alive_np=alive_np,
-            batch_gs=batch_gs, prop_gs=prop_gs, caps=caps,
+            batch=batch, lane=lane, staging=staging, alive_np=alive_np,
+            batch_gs=batch_gs, fed=fed, prop_gs=prop_gs, caps=caps,
             merged=merged_l, out=out_l, head_dev=head_l,
-            detail_dev=detail_l, t_req=_time.monotonic(),
-            tick_fed=tick_fed, rounds=rounds,
+            detail_dev=detail_l, t_req=_time.monotonic(), rounds=rounds,
         ))
 
     def _parse_head(self, head, caps, G: int, nw: int):  # sync-hot
@@ -2705,19 +3099,16 @@ class ColocatedVectorEngine(VectorStepEngine):
         # deferred to escalation recovery are excluded, and rows it
         # syncs update the lanes so the NEXT round's diff composes.
         if vals_np is not None and len(sets.sum_rows):
-            live_k: List[Tuple] = [
-                (node, g, si)
-                for node, g, si, _plan in rec.batch
-                if g not in esc_seen
-            ]
-            live_set = {g for _, g, _ in live_k}
-            meta_get = self._meta.get
-            for g in sets.live_other.tolist():
-                if g in esc_seen or g in live_set:
-                    continue
-                meta = meta_get(g)
-                if meta is not None:
-                    live_k.append((meta.node, g, None))
+            live_k = self._live_rows(rec, flags, sets, esc_seen, True)
+            self.stats["completion_rows_walked"] += len(live_k)
+            if hostplane.PARITY:
+                hostplane.check_completion_parity(
+                    hostplane.CompletionTrace(emitted=_emitting(
+                        live_k, pos_sum, ())),
+                    hostplane.CompletionTrace(emitted=_emitting(
+                        self._live_rows_whole(rec, sets, esc_seen, True),
+                        pos_sum, ())),
+                )
             pos_slot_k = (
                 pos_slot if rnd == 0
                 else hostplane.pos_of(G, sets.slot_rows)
@@ -2880,10 +3271,12 @@ class ColocatedVectorEngine(VectorStepEngine):
             if n_esc:
                 self.stats["escalations"] += n_esc
                 for i in sets.esc_batch_pos.tolist():
-                    node, g, si, _plan = batch[i]
+                    # a position past the active rows names a lane row
+                    g = int(batch_gs[i])
                     if g in esc_seen:
                         continue
                     esc_seen.add(g)
+                    node, _g, si = rec.stepped_row(i)
                     self._deferred.append(
                         ("esc", node, g, si if rnd == 0 else None)
                     )
@@ -2910,26 +3303,14 @@ class ColocatedVectorEngine(VectorStepEngine):
 
         stage_map = staging if K == 1 else {}
         rnd = K - 1
-        # ---- live rows: batch rows + any resident row with effects ----
-        esc_keep = np.ones((len(batch),), bool)
-        # every batch row whose device row escalated in ANY round of
-        # the wave is excluded from the final merge (its recovery is
-        # the deferred evict+replay above)
-        esc_keep[[
-            i for i, (_n, g, _s, _p) in enumerate(batch)
-            if g in esc_seen
-        ]] = False
-        live: List[Tuple] = [
-            (node, g, si)
-            for (node, g, si, plan), k in zip(batch, esc_keep.tolist())
-            if k
-        ]
+        # ---- live rows: the rows this completion walks in Python ------
+        # The active batch rows, the lane rows the final round's flags
+        # mark, and any resident row with effects — every batch row
+        # whose device row escalated in ANY round of the wave excluded
+        # (its recovery is the deferred evict+replay above).  The lane
+        # rows that only ticked are clocked and leased as arrays below.
+        live = self._live_rows(rec, flags, sets, esc_seen, False)
         live_gs = {g for _, g, _ in live}
-        for g in sets.live_other.tolist():
-            meta = self._meta.get(g)
-            if meta is not None:
-                live.append((meta.node, g, None))
-                live_gs.add(g)
         # rows an intermediate round touched that the final round left
         # quiet still owe their get_update (merged appends/messages
         # must persist and dispatch)
@@ -2937,6 +3318,17 @@ class ColocatedVectorEngine(VectorStepEngine):
             if g not in live_gs and g not in esc_seen:
                 live.append((node, g, None))
                 live_gs.add(g)
+        self.stats["completion_rows_walked"] += len(live)
+        whole = None
+        if hostplane.PARITY:
+            # the live list as it was before PR 29, one tuple a stepped
+            # row: what the per-row passes are worked out over
+            whole = self._live_rows_whole(rec, sets, esc_seen, False)
+            whole_gs = {g for _, g, _ in whole}
+            whole += [
+                (node, g, None) for g, node in touched.items()
+                if g not in whole_gs and g not in esc_seen
+            ]
 
         buf_rows = sets.buf_rows
         append_rows = sets.append_rows
@@ -2984,9 +3376,11 @@ class ColocatedVectorEngine(VectorStepEngine):
             # lease pass BEFORE bookkeeping: lease window starts must
             # stamp the PRE-launch clock (see _lease_pass); then ONE
             # batched bookkeeping pass for the whole generation
-            self._lease_pass(live, flags, vals_np, pos_sum, rec.tick_fed)
+            self._clock_and_lease(
+                rec, live, flags, vals_np, pos_sum, esc_seen, touched,
+                whole,
+            )
             lease_done = True
-            self._bookkeeping_pass(live)
             # ---- EARLY completion: the commit-proving prefix --------
             # A live row with values but NO append/outbox/slot/need
             # sections (the common shape: a leader whose routed acks
@@ -3092,8 +3486,10 @@ class ColocatedVectorEngine(VectorStepEngine):
         # here instead (detail and position maps only just landed) —
         # same order as dev_ok: lease, bookkeeping, lane commit.
         if not lease_done:
-            self._lease_pass(live, flags, vals_np, pos_sum, rec.tick_fed)
-            self._bookkeeping_pass(live)
+            self._clock_and_lease(
+                rec, live, flags, vals_np, pos_sum, esc_seen, touched,
+                whole,
+            )
             if vals_np is not None:
                 self._lane_commit_pass(
                     live, flags, pos_sum, pos_buf, pos_slot, pos_need,
@@ -3310,13 +3706,9 @@ class ColocatedVectorEngine(VectorStepEngine):
         if self._pending_live:
             # in-flight routed traffic: wake every ALIVE resident
             # node's engine so some worker launches again and the
-            # messages are consumed (lane scan — the notify itself is
-            # per-node, but dirty rows no longer pay a Python probe)
+            # messages are consumed
             tok = self._enter("t_wake_ms", "raft-colocated-wake")
-            for g in np.nonzero(self._lanes.alive_mask())[0].tolist():
-                meta = self._meta.get(g)
-                if meta is not None and meta.node.notify_work is not None:
-                    meta.node.notify_work()
+            self._wake_alive()
             self._leave(tok)
         return updates
 

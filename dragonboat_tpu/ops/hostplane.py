@@ -168,17 +168,32 @@ class LeaseLanes:
     All writes run under the engine's core lock, like RowLanes.
     """
 
-    __slots__ = ("window_start", "dev_el", "et")
+    __slots__ = ("window_start", "dev_el", "et", "anchored")
 
     def __init__(self, capacity: int):
         self.window_start = np.full((capacity,), -1, np.int64)
         self.dev_el = np.zeros((capacity,), np.int64)
         self.et = np.zeros((capacity,), np.int64)  # 0 = disarmed
+        # the anchor tick last handed to Raft.anchor_quorum_evidence
+        # for the row (-1 = none since it was armed): lanes_step names
+        # a row for anchoring only when its anchor moved past this
+        self.anchored = np.full((capacity,), -1, np.int64)
+
+    def copy(self) -> "LeaseLanes":
+        """Lanes of their own with the same contents (the parity
+        oracle steps a copy beside the real ones)."""
+        other = LeaseLanes(0)
+        other.window_start = self.window_start.copy()
+        other.dev_el = self.dev_el.copy()
+        other.et = self.et.copy()
+        other.anchored = self.anchored.copy()
+        return other
 
     def disarm(self, g: int) -> None:
         self.et[g] = 0
         self.dev_el[g] = 0
         self.window_start[g] = -1
+        self.anchored[g] = -1
 
     def arm(self, g: int, election_timeout: int, election_tick: int) -> None:
         """Arm a row entering device residency (or winning an election
@@ -188,6 +203,7 @@ class LeaseLanes:
         self.et[g] = election_timeout
         self.dev_el[g] = election_tick
         self.window_start[g] = -1  # first window: fabricated actives
+        self.anchored[g] = -1
 
     def row_step(self, g: int, fed_ticks: int, now: int,
                  flags_word: int) -> int:
@@ -212,6 +228,40 @@ class LeaseLanes:
         if ws >= 0 and (flags_word & F_QUORUM_ACTIVE):
             return int(ws)
         return -1
+
+    def lanes_step(  # hostplane-hot
+        self, gs: np.ndarray, fed: np.ndarray, flags: np.ndarray
+    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """:meth:`row_step` over a whole launch at once: ``gs`` are the
+        rows of one completion (distinct), ``fed`` the ticks each was
+        fed, ``flags`` the round's ``[G]`` flags word.  Advances
+        ``dev_el`` for every armed row and returns positions into
+        ``gs``: the rows whose window CROSSED (the caller stamps
+        ``window_start`` from each such row's own pre-launch clock, the
+        one per-row fact the lanes do not hold), the rows that hold an
+        anchor this launch (``row_step`` >= 0), and of those the rows
+        whose anchor MOVED since it was last applied — the only ones
+        ``Raft.anchor_quorum_evidence`` has anything to do for: it is a
+        monotone max over the remotes' ``last_resp_tick``, so applying
+        an anchor again changes nothing, and not applying it can only
+        leave the lease shorter.  ``anchored`` is written here for the
+        moved rows: the caller applies exactly those."""
+        et = self.et[gs]
+        armed = et > 0
+        el = self.dev_el[gs] + fed
+        crossed = armed & (el >= et)
+        self.dev_el[gs] = np.where(
+            armed, np.where(crossed, 0, el), self.dev_el[gs]
+        )
+        ws = self.window_start[gs]
+        held = (
+            armed & ~crossed & (ws >= 0)
+            & ((flags[gs] & F_QUORUM_ACTIVE) != 0)
+        )
+        moved = held & (ws != self.anchored[gs])
+        at = np.nonzero(moved)[0]
+        self.anchored[gs[at]] = ws[at]
+        return np.nonzero(crossed)[0], np.nonzero(held)[0], at
 
 
 class UpdateLanes:
@@ -449,13 +499,77 @@ def classify_static_scalar(lanes: RowLanes, gs: Sequence[int]) -> np.ndarray:
 # ---------------------------------------------------------------------------
 # the tick lane (the launch's encode phase)
 # ---------------------------------------------------------------------------
+_NO_ROWS = np.zeros((0,), np.int64)
+
+
+class TickLane:
+    """One launch's tick-only rows as parallel columns, from the plan
+    loop to the end of the completion: a row on the lane is never a
+    Python tuple, a ``StepInputs`` or a plan list.
+
+    The plan loop appends to the four lists (``gs`` row id, ``fed`` the
+    fused tick count the device is fed, ``ticks`` the ticks drained —
+    more than ``fed`` where quiesce swallowed some — and ``nodes``);
+    ``gc`` holds, by row id, the ticks the backlog cap dropped, for the
+    few rows that have any.  :meth:`seal` (at the launch, after
+    ``_retake_lane_rows`` gave rows back) freezes the columns the
+    completion's array passes read: ``gs_np``, ``fed_np`` and
+    ``clock_np`` = ticks + dropped ticks, what both clocks advance by.
+    A slow path that needs one row's inputs back (an escalation's
+    replay) builds a ``StepInputs(ticks, gc_ticks)`` for that row from
+    ``ticks``/``gc`` (docs/PARITY.md "The tick lane through
+    completion")."""
+
+    __slots__ = ("gs", "fed", "ticks", "nodes", "gc", "gs_np", "fed_np",
+                 "clock_np")
+
+    def __init__(self):
+        self.gs: List[int] = []
+        self.fed: List[int] = []
+        self.ticks: List[int] = []
+        self.nodes: list = []
+        self.gc: Dict[int, int] = {}
+        self.gs_np = self.fed_np = self.clock_np = _NO_ROWS
+
+    def __len__(self) -> int:
+        return len(self.gs)
+
+    def add(self, node, g: int, fed: int, ticks: int, gc: int = 0) -> None:
+        """One row (the plan loop open-codes this with bound appends)."""
+        self.gs.append(g)
+        self.fed.append(fed)
+        self.ticks.append(ticks)
+        self.nodes.append(node)
+        if gc:
+            self.gc[g] = gc
+
+    def take(self, g: int) -> Tuple:
+        """Remove row ``g`` and return ``(node, fed, ticks, gc)``: the
+        row turned out to carry more than a hint-free tick."""
+        i = self.gs.index(g)
+        del self.gs[i]
+        return (self.nodes.pop(i), self.fed.pop(i), self.ticks.pop(i),
+                self.gc.pop(g, 0))
+
+    def seal(self) -> "TickLane":  # hostplane-hot
+        self.gs_np = np.asarray(self.gs, np.int64)
+        self.fed_np = np.asarray(self.fed, np.int64)
+        clock = np.asarray(self.ticks, np.int64)
+        if self.gc:
+            # raftlint: ignore[host-loop] the rows the backlog cap dropped ticks of: none in a healthy launch
+            for g, n in self.gc.items():
+                clock[self.gs.index(g)] += n
+        self.clock_np = clock
+        return self
+
+
 class LaunchEncode(NamedTuple):
     """One generation's host-side encode, as ``_launch_generation``
     consumes it: the ``[G]`` fused tick count of every row whose whole
     host inbox is one hint-free tick, the rows that upload dense inbox
     rows (``(g, Message list)``), the staged payload entries by row and
-    assembled slot, the proposal-slot rows, and the row -> fused tick
-    count map the completion's lease pass reads."""
+    assembled slot, the proposal-slot rows, and the ACTIVE rows' row ->
+    fused tick count map (the lane's counts stay in ``TickLane.fed``)."""
 
     tick_counts: np.ndarray
     sparse: List[Tuple[int, list]]
@@ -466,24 +580,23 @@ class LaunchEncode(NamedTuple):
 
 def encode_tick_lane(  # hostplane-hot
     G: int, tick_gs: Sequence[int], tick_n: Sequence[int]
-) -> Tuple[np.ndarray, Dict[int, int]]:
+) -> np.ndarray:
     """The tick lane's whole encode.  ``tick_gs``/``tick_n`` are the
-    row ids and fused tick counts of the batch rows the plan loop's
-    fast lane appended with the sole plan ``[("tick", n)]`` and no
-    pending device-read ctx (so the tick would carry no hint): ~98 % of
+    row ids and fused tick counts of the rows the plan loop's fast lane
+    put on the :class:`TickLane` (sole plan ``[("tick", n)]``, no
+    pending device-read ctx, so the tick would carry no hint): ~98 % of
     a launch at 1,000 groups x 3.  One numpy store puts them into the
     ``[G]`` count vector ``_host_inbox_from_ticks`` expands on the
-    device; the dict is ``_encode_rows``' ``tick_fed`` for the same
-    rows.  No ``Message``, no per-row Python: the per-row twin
-    (:func:`split_lone_ticks` over ``_encode_rows``' output) is what
-    every batch row took before, still takes when it carries anything
-    else, and is the parity oracle for these."""
+    device.  No ``Message``, no dict, no per-row Python: the per-row
+    twin (:func:`split_lone_ticks` over ``_encode_rows``' output) is
+    what every batch row took before, still takes when it carries
+    anything else, and is the parity oracle for these."""
     tick_counts = np.zeros((G,), np.int32)
     if len(tick_gs):
         tick_counts[np.asarray(tick_gs, np.int64)] = np.asarray(
             tick_n, np.int32
         )
-    return tick_counts, dict(zip(tick_gs, tick_n))
+    return tick_counts
 
 
 def split_lone_ticks(
@@ -514,13 +627,16 @@ def split_lone_ticks(
 
 
 def assert_encode_parity(
-    batch, batch_gs: np.ndarray, lane: LaunchEncode, ref: LaunchEncode
+    batch, batch_gs: np.ndarray, lane: LaunchEncode, ref: LaunchEncode,
+    tick_lane: "TickLane" = None,
 ) -> None:
     """``lane`` (tick lane + active rows) against ``ref`` (the whole
     batch through ``_encode_rows`` + :func:`split_lone_ticks`): same
     count vector, same dense rows with equal ``Message`` lists, same
-    staging, proposal rows and lease-pass tick map; ``batch_gs`` is the
-    batch's row ids in batch order."""
+    staging, proposal rows and lease-pass tick map (the active rows'
+    ``tick_fed`` and ``tick_lane``'s counts together); ``batch`` is the
+    whole stepped set as tuples, active rows first, and ``batch_gs``
+    its row ids in that order."""
     want_gs = [g for _, g, _, _ in batch]
     if np.asarray(batch_gs).tolist() != want_gs:
         raise HostPlaneParityError(_diff("batch_gs", batch_gs, want_gs))
@@ -543,9 +659,12 @@ def assert_encode_parity(
         raise HostPlaneParityError(
             _diff("prop_rows", sorted(lane.prop_rows), sorted(ref.prop_rows))
         )
-    if lane.tick_fed != ref.tick_fed:
+    fed = dict(lane.tick_fed)
+    if tick_lane is not None:
+        fed.update(zip(tick_lane.gs, tick_lane.fed))
+    if fed != ref.tick_fed:
         raise HostPlaneParityError(
-            _diff("tick_fed", sorted(lane.tick_fed.items()),
+            _diff("tick_fed", sorted(fed.items()),
                   sorted(ref.tick_fed.items()))
         )
 
@@ -770,9 +889,73 @@ def check_classify_parity(lanes: RowLanes, gs, vec) -> None:
         _record_failure(e)
 
 
-def check_encode_parity(batch, batch_gs, lane, ref) -> None:
+def check_encode_parity(batch, batch_gs, lane, ref, tick_lane=None) -> None:
     try:
-        assert_encode_parity(batch, batch_gs, lane, ref)
+        assert_encode_parity(batch, batch_gs, lane, ref, tick_lane)
+    except HostPlaneParityError as e:  # pragma: no cover - bug path
+        _record_failure(e)
+
+
+class CompletionTrace(NamedTuple):
+    """What one completion's live build, lease pass and tick
+    bookkeeping came to, in a form both ways of running them can fill
+    in: the array passes over the stepped rows (PR 29) and the per-row
+    passes over one tuple a stepped row (the parity oracle).
+
+    ``emitted`` is the set of rows the completion can emit anything
+    for (a live row with values, or one an earlier round touched: the
+    array side walks no others); ``rows`` the rows the three lease
+    lanes are compared over, ``anchors`` row -> anchor tick for every
+    row that HOLDS an anchor this launch (the array side applies only
+    the ones that moved; the value a row is anchored at is what is
+    compared), ``clocks`` row -> (node clock, raft clock) after the
+    bookkeeping."""
+
+    emitted: frozenset = frozenset()
+    rows: np.ndarray = _NO_ROWS
+    et: np.ndarray = _NO_ROWS
+    dev_el: np.ndarray = _NO_ROWS
+    window_start: np.ndarray = _NO_ROWS
+    anchors: Dict[int, int] = {}
+    clocks: Dict[int, Tuple[int, int]] = {}
+
+
+def assert_completion_parity(got: CompletionTrace,
+                             want: CompletionTrace) -> None:
+    """The array passes' trace against the per-row passes': the same
+    rows emitted, the same lease lanes, anchors and clocks.  Raises
+    :class:`HostPlaneParityError` naming the first that differs."""
+    if got.emitted != want.emitted:
+        raise HostPlaneParityError(
+            _diff("rows emitted", sorted(got.emitted), sorted(want.emitted))
+        )
+    if not np.array_equal(got.rows, want.rows):
+        raise HostPlaneParityError(_diff("lease rows", got.rows, want.rows))
+    for name in ("et", "dev_el", "window_start"):
+        a, b = getattr(got, name), getattr(want, name)
+        if not np.array_equal(a, b):
+            bad = np.nonzero(np.asarray(a) != np.asarray(b))[0][:8]
+            raise HostPlaneParityError(
+                f"lease {name}: rows {got.rows[bad].tolist()} array "
+                f"{np.asarray(a)[bad].tolist()} != per-row "
+                f"{np.asarray(b)[bad].tolist()}"
+            )
+    for name in ("anchors", "clocks"):
+        a, b = getattr(got, name), getattr(want, name)
+        if a != b:
+            bad = sorted(
+                g for g in set(a) | set(b) if a.get(g) != b.get(g)
+            )[:8]
+            raise HostPlaneParityError(
+                f"{name}: rows {bad} array {[a.get(g) for g in bad]} "
+                f"!= per-row {[b.get(g) for g in bad]}"
+            )
+
+
+def check_completion_parity(got: CompletionTrace,
+                            want: CompletionTrace) -> None:
+    try:
+        assert_completion_parity(got, want)
     except HostPlaneParityError as e:  # pragma: no cover - bug path
         _record_failure(e)
 

@@ -9,6 +9,8 @@ else runs on device 0.  The full NodeHost stack on a mesh is covered by
 import os
 import sys
 
+import pytest
+
 # run the whole suite with internal invariant assertions ON (reference:
 # build-tag-gated internal/invariants checks enabled in CI builds [U])
 os.environ.setdefault("DRAGONBOAT_TPU_INVARIANTS", "1")
@@ -165,3 +167,21 @@ def pytest_runtest_teardown(item, nextitem):
                 + jitcheck.format_retraces(rows),
                 pytrace=False,
             )
+
+
+@pytest.fixture(autouse=True)
+def _hostplane_parity_gate():
+    """Under ``DRAGONBOAT_TPU_HOSTPLANE_PARITY=1`` (the oracle run of
+    the colocated tests: docs/PARITY.md) a test fails if the array
+    passes and their per-row twins disagreed anywhere while it ran; the
+    engine itself only records a difference and goes on."""
+    if os.environ.get("DRAGONBOAT_TPU_HOSTPLANE_PARITY", "") != "1":
+        yield
+        return
+    from dragonboat_tpu.ops import hostplane
+
+    before = hostplane.PARITY_FAILURE_COUNT
+    yield
+    assert hostplane.PARITY_FAILURE_COUNT == before, (
+        hostplane.PARITY_FAILURES[-3:]
+    )

@@ -610,6 +610,50 @@ def test_host_loop_real_tree_tick_lane_annotation_is_live():
     assert [f.rule for f in fs] == ["host-loop"], fs
 
 
+def test_host_loop_real_tree_completion_lane_annotations_are_live():
+    """The array passes PR 29 put under the completion (the lease step
+    over a whole launch, the lane's columns, the rows a completion
+    leaves alone, the wake) carry the # hostplane-hot marker; a per-row
+    loop seeded into each must surface, so the ~2,000-3,300 tick-only
+    rows a launch they keep out of Python cannot grow back in."""
+    seeds = {
+        "dragonboat_tpu/ops/hostplane.py": [
+            ("    def lanes_step(  # hostplane-hot",
+             "        et = self.et[gs]\n",
+             "        for g in gs:\n            pass\n"),
+            ("    def seal(self) -> \"TickLane\":  # hostplane-hot",
+             "        self.gs_np = np.asarray(self.gs, np.int64)\n",
+             "        junk = [int(g) for g in self.gs]\n"),
+        ],
+        "dragonboat_tpu/ops/colocated.py": [
+            ("    def _lease_pass(  # hostplane-hot",
+             "        lease = self._lease\n        if skip is not None:\n",
+             "        for node in nodes:\n            pass\n"),
+            ("    def _skip_mask(  # hostplane-hot",
+             "        skip = ~self._lanes.attached[gs]\n",
+             "        junk = [n.stopped for n in nodes]\n"),
+            ("    def _wake_alive(self) -> None:  # hostplane-hot",
+             "        alive = self._lanes.alive_mask()\n        slot = ",
+             "        for meta in self._meta.values():\n            pass\n"),
+        ],
+    }
+    for rel, cases in seeds.items():
+        src = open(os.path.join(REPO, rel)).read()
+        assert lint_source(src, rel) == []
+        for def_line, needle, junk in cases:
+            assert def_line in src, def_line
+            assert src.count(needle) == 1, needle
+            fs = lint_source(src.replace(needle, junk + needle, 1), rel)
+            assert [f.rule for f in fs] == ["host-loop"], (def_line, fs)
+        # the documented residue loops are exempt by their point
+        # ignores, and only by them
+        stripped = src.replace(
+            "# raftlint: ignore[host-loop] residue:", "# stripped:")
+        if stripped != src:
+            fs = lint_source(stripped, rel)
+            assert len(fs) == 3 and {f.rule for f in fs} == {"host-loop"}
+
+
 def test_host_loop_lane_scalar_oracle_ignore_is_live():
     """plan_update_sync_scalar (the documented per-row parity oracle)
     is exempted by a def-line-adjacent ignore; stripping the ignore

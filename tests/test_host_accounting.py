@@ -51,7 +51,7 @@ ENGINE_KEYS = PHASES + (
     "t_launch_ms", "t_between_ms", "t_misc_ms", "t_lock_wait_ms",
     "t_wal_ms", "wal_appends", "wal_bytes", "wal_records",
     "device_rows_active", "apply_batches", "apply_entries", "t_apply_ms",
-    "t_apply_wait_ms",
+    "t_apply_wait_ms", "tick_lane_rows", "completion_rows_walked",
 )
 GATEWAY_KEYS = (
     "proposed", "t_queue_wait_ms", "t_ack_lag_ms", "poll_checks",
@@ -174,6 +174,33 @@ def test_phases_add_up_to_the_launch_and_launch_plus_between_to_the_wall(
         elapsed_ms, rel=0.10)
     assert d["t_wal_ms"] <= d["t_persist_ms"]
     assert 0 < d["device_rows_active"] <= d["device_rows_stepped"]
+
+
+def test_the_account_closes_with_the_lane_carried_through_the_completion(
+        cluster):
+    """PR 29: the tick lane's rows stay columns through the completion
+    and the wake is one call a member NodeHost; the same clock still
+    charges every millisecond once, and the completion counts the rows
+    it walked in Python."""
+    st0, _ = cluster.engine()
+    time.sleep(0.3)  # launches that carry nothing but ticks
+    for i in range(16):
+        cluster.write(SHARDS[i % len(SHARDS)], f"lane{i}", b"v" * 16)
+    st1, _ = cluster.engine()
+    d = delta(st1, st0)
+    assert d["launches"] > 0 and d["tick_lane_rows"] > 0
+    named = sum(d[k] for k in PHASES)
+    assert named + d["t_misc_ms"] == pytest.approx(d["t_launch_ms"],
+                                                   rel=1e-6, abs=0.01)
+    for k in ("t_plan_ms", "t_merge_ms", "t_detail_ms", "t_updates_ms",
+              "t_wake_ms"):
+        assert d[k] > 0, k
+    # every write's rows are walked (a leader and two followers, over
+    # the rounds of its wave), a row that only ticked is not: 24 rows
+    # resident, so a launch that walked them all every round would
+    # count three times its stepped rows
+    assert 16 * 3 <= d["completion_rows_walked"]
+    assert d["completion_rows_walked"] < d["device_rows_stepped"]
 
 
 # -- the WAL and the apply workers --------------------------------------
