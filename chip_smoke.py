@@ -177,8 +177,8 @@ def main(argv=None) -> int:
 
     jitcheck.enable(True)  # _warm() marks; any later compile is a retrace
     reset_inproc_network()
-    # bench.phase_c's geometry; pipeline depth and fused rounds at their
-    # shipped defaults, the link-latency simulator off
+    # BASELINE config 2's geometry; pipeline depth and fused rounds at
+    # their shipped defaults, the link-latency simulator off
     group = ColocatedEngineGroup(
         capacity=capacity, P=3, W=16, M=8, E=4, O=32, budget=4,
         sync_floor_ms=0.0, mesh=mesh,

@@ -150,7 +150,7 @@ class OnDiskKV(IOnDiskStateMachine):
         # serializes checkpoint rewrites against close(); update/sync
         # run on the one apply worker and need no lock among themselves
         self._io_lock = threading.Lock()
-        # observability for tests/bench
+        # observability for tests and the benchmark
         self.stats = {
             "opens": 0, "replayed": 0, "skipped": 0, "torn": 0,
             "checkpoints": 0, "syncs": 0,

@@ -104,7 +104,7 @@ class CapFeedback:
     it is healthy — the ``LatencyBudget`` discipline applied to
     background bandwidth (docs/BIGSTATE.md "cap feedback").
 
-    The loop owner (bench harness, an operator thread, a future engine
+    The loop owner (an operator thread, a future engine
     hook) feeds commit latencies via ``observe`` — typically by sharing
     the same ``client.LatencyBudget`` the proposers already feed — and
     calls ``tick()`` periodically:

@@ -120,22 +120,7 @@ class EngineConfig:
 
 @dataclass
 class NodeHostConfig:
-    """Per-process configuration (reference: config.NodeHostConfig [U]).
-
-    ``tick_sweep_batch`` coarsens the host ticker: the per-node sweep
-    runs only every Nth ``rtt_millisecond`` period, crediting N logical
-    ticks at once — the same logical tick RATE at 1/N the per-node host
-    cost (the mass-start tooling knob, formerly the undocumented
-    ``TICK_SWEEP_BATCH`` env var, which remains honoured when this field
-    is 0).  Timing-granularity implication: election/heartbeat/quiesce
-    deadlines are still crossed at the right tick COUNT, but the
-    crossing is only observed at sweep boundaries, so any raft timer can
-    fire up to ``(N-1) * rtt_millisecond`` wall-clock late and N ticks
-    land in one step with no wall time between them for responses.
-    Keep ``N * heartbeat_rtt`` well under ``election_rtt`` or healthy
-    leaders will flap; intended for experiments and mass-start tooling,
-    not steady-state deployments.  0 = use the env var, else 1.
-    """
+    """Per-process configuration (reference: config.NodeHostConfig [U])."""
 
     deployment_id: int = 0
     nodehost_dir: str = ""
@@ -161,7 +146,6 @@ class NodeHostConfig:
     enable_tracing: bool = False
     trace_sample_rate: float = 1.0
     enable_flight_recorder: bool = False
-    tick_sweep_batch: int = 0  # 0 = TICK_SWEEP_BATCH env var, else 1
     gossip: GossipConfig = field(default_factory=GossipConfig)
     expert: ExpertConfig = field(default_factory=ExpertConfig)
     raft_event_listener: Optional[object] = None
@@ -172,8 +156,6 @@ class NodeHostConfig:
             raise ConfigError("nodehost_dir not set")
         if self.rtt_millisecond <= 0:
             raise ConfigError("rtt_millisecond must be > 0")
-        if self.tick_sweep_batch < 0:
-            raise ConfigError("tick_sweep_batch must be >= 0")
         if not 0.0 <= self.trace_sample_rate <= 1.0:
             raise ConfigError("trace_sample_rate must be in [0, 1]")
         if not self.raft_address:

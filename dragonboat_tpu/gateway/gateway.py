@@ -300,7 +300,7 @@ class Gateway:
             metrics=self.metrics,
         )
         # completion counters mutate under _done_lock: tests and the
-        # bench read them as exact deltas, and Counter.add is a GIL-
+        # benchmark read them as exact deltas, and Counter.add is a GIL-
         # racy read-modify-write when several workers complete
         # concurrently (review finding).  The read-path counters
         # (lease/fallback/route) keep the project-wide lock-free-ish

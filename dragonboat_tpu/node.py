@@ -602,7 +602,7 @@ class Node:
         # under _qlock is the linearization point) — the colocated
         # coalesce scan calls this once per resident node per launch
         # generation, and the lock acquisition alone was ~60% of a
-        # 294 s coalesce bill at 50k rows (SCALE_r05)
+        # 294 s coalesce bill at 50k rows (round 5)
         # raftlint: ignore[guarded-by] lock-free hint; drain under _qlock linearizes
         if (
             self._received

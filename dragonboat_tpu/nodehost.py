@@ -395,21 +395,11 @@ class NodeHost:
         self._env.close()
 
     def _ticker_main(self) -> None:
-        import os as _os
-
-        # sweep the per-node loop only every Nth period, crediting N
-        # ticks at once (same logical tick rate, 1/N the per-node host
-        # cost); see NodeHostConfig.tick_sweep_batch for the timing-
-        # granularity caveats.  The env var remains the fallback for
-        # deployments that predate the config field.
-        batch = self.config.tick_sweep_batch or max(
-            1, int(_os.environ.get("TICK_SWEEP_BATCH", "1"))
-        )
-        period = self.config.rtt_millisecond / 1000.0 * batch
+        period = self.config.rtt_millisecond / 1000.0
         while not self._ticker_stop.wait(period):
             if self._ticks_paused:
                 continue
-            self._global_ticks += batch
+            self._global_ticks += 1
             with self._nodes_lock:
                 nodes = [
                     n for sid, n in self._nodes.items()
@@ -437,8 +427,7 @@ class NodeHost:
                                     f"tick={self._global_ticks}",
                                 )
                             continue
-                for _ in range(batch):
-                    n.add_tick()
+                n.add_tick()
                 ready.append(n.shard_id)
             if ready:
                 self.engine.notify_many(ready)

@@ -16,13 +16,13 @@ span stitches into the SAME cross-host trace as the leader's proposal.
 
 Cost contract: a disabled tracer is ``None`` on every hot object — the
 hot paths pay one attribute load and a falsy test, nothing else
-(verified by scripts/obs_smoke.sh's bench guard).  An enabled tracer
-records into a bounded ring (old traces fall off; a tracer can run
+(tests/test_obs.py ``TestConfigGates`` pins the ``None``).  An enabled
+tracer records into a bounded ring (old traces fall off; a tracer can run
 forever without growing) and sampling (``trace_sample_rate``) bounds
 the per-request cost at high rates.
 
 Timebase: ``time.monotonic()`` — one clock per process.  All-in-one-
-process clusters (the test/bench topology) merge exactly; cross-process
+process clusters (the test topology) merge exactly; cross-process
 merges are subject to clock skew between processes (noted in
 docs/OBSERVABILITY.md).
 """

@@ -6,7 +6,7 @@ transport even when the peer replica lives in the same process
 ``VectorStepEngine`` inherits that shape: each message round-trips
 device -> host decode -> transport -> host encode -> device.  When a
 whole cluster is colocated on one chip (multiple NodeHosts in one
-process — the standard test/bench topology, and the production topology
+process — the standard test topology, and the production topology
 for BASELINE configs 2-4), that detour is the scaling bottleneck.
 
 ``ColocatedEngineGroup`` is the product configuration that removes it:
@@ -46,7 +46,6 @@ import dataclasses
 import functools
 import itertools
 import operator
-import os as _os
 import threading
 import time as _time
 from collections import OrderedDict
@@ -129,38 +128,26 @@ _perf = _time.perf_counter
 _STOPPED = operator.attrgetter("stopped")
 
 # -- double-buffered generations (the launch pipeline) -----------------
-# DRAGONBOAT_TPU_PIPELINE_DEPTH: how many generations may be in flight
-# at once.  2 (the default) double-buffers: while generation N's blob
-# readback is in flight, generation N+1 assembles, uploads and
+# Generations in flight at once.  2 double-buffers: while generation
+# N's blob readback is in flight, generation N+1 assembles, uploads and
 # dispatches — the donated-buffer program chain permits it, and where
 # a device->host sync has a latency floor the readback overlaps the
-# next launch's host work, so sync count stops being the unit of
-# product-path latency.  1 = the serial loop (dispatch, sync, merge,
-# repeat).
-_PIPE_DEPTH_DEFAULT = int(
-    _os.environ.get("DRAGONBOAT_TPU_PIPELINE_DEPTH", "2") or 2
-)
-# DRAGONBOAT_TPU_SYNC_FLOOR_MS: a simulated link latency for tests — a
-# readback's data is not considered landed until <floor> ms after the
-# D2H copy was REQUESTED (copy_to_host_async).  Models a remote device:
-# the floor is round-trip latency, paid from request to data regardless
-# of size, and requests issued early (at dispatch) collect late for
-# free — which is what the pipeline exploits and what `bench.py
-# phase_pipeline` sweeps.  0 (the default) is the real machine: on a
-# local v5e a small readback lands in under a millisecond (PERF.md).
-_SYNC_FLOOR_MS_DEFAULT = float(
-    _os.environ.get("DRAGONBOAT_TPU_SYNC_FLOOR_MS", "0") or 0
-)
-# DRAGONBOAT_TPU_FUSED_ROUNDS: how many consecutive consensus rounds a
-# routable generation chains device-side before its ONE readback (the
-# fused commit wave, ISSUE 15).  3 (the default) is one full
+# next launch's host work.  1 is the serial loop (dispatch, sync,
+# merge, repeat), the reference the pipelined path is tested against.
+_PIPE_DEPTH_DEFAULT = 2
+# Simulated link latency, ms: a readback's data is not considered
+# landed until this long after the D2H copy was REQUESTED
+# (copy_to_host_async), as on a remote device.  0.0 is the real
+# machine: on a local v5e a small readback lands in under a millisecond
+# (PERF.md).
+_SYNC_FLOOR_MS_DEFAULT = 0.0
+# Consecutive consensus rounds a routable generation chains device-side
+# before its ONE readback (the fused commit wave).  3 is one full
 # propose -> replicate/ack -> commit/deliver sequence: a quiet-path
 # proposal commits in one launch + one readback instead of three of
-# each.  1 disables fusing (the PR 11 single-round launch loop, bit for
-# bit).
-_FUSED_ROUNDS_DEFAULT = int(
-    _os.environ.get("DRAGONBOAT_TPU_FUSED_ROUNDS", "3") or 3
-)
+# each.  1 is the single-round launch loop, the reference the fused
+# path is tested against.
+_FUSED_ROUNDS_DEFAULT = 3
 
 # fast-lane invalidation margin: re-validate a row's int32 headroom via
 # the full plan well before the hard 2^31 ceiling (margin >> M*E and
@@ -2432,7 +2419,7 @@ class ColocatedVectorEngine(VectorStepEngine):
             log = r.log
             im = log.inmem
             # NOTE: open-coded in lockstep with the engine lane branch
-            # and the bench twin — see the note in engine._device_step
+            # — see the note in engine._device_step
             if (
                 r.msgs or r.ready_to_reads or r.dropped_entries
                 or r.dropped_read_indexes or im.snapshot.index

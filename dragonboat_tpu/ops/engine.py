@@ -559,8 +559,8 @@ def _apply_lane_commits(handoffs) -> None:
     now = time.perf_counter()  # one stamp for the whole batch
     for node, ce in handoffs:
         _apply_lane_commit(node, ce, now, notify=False)
-        # getattr: bespoke node doubles (bench twins, direct-drive
-        # tests) predate the hook and keep the per-row path
+        # getattr: bespoke node doubles (direct-drive tests) predate
+        # the hook and keep the per-row path
         wr = getattr(node, "apply_work_ready", None)
         if wr is not None:
             by_wr.setdefault(id(wr), (wr, []))[1].append(node.shard_id)
@@ -670,8 +670,7 @@ class VectorStepEngine(IStepEngine):
             # The kernel is row-local so the step compiles with zero
             # collectives; upload/readback gathers and (in the colocated
             # subclass) cross-shard routing legitimately induce XLA
-            # collective permutes — correctness first, the bench path
-            # stays single-device.
+            # collective permutes — correctness first.
             from jax.sharding import NamedSharding, PartitionSpec
 
             if capacity % mesh.size:
@@ -2005,14 +2004,11 @@ class VectorStepEngine(IStepEngine):
             ):
                 # ---- LANE row: no heavy sections ---------------------
                 # NOTE: this residue-probe + U_*-application block is
-                # intentionally OPEN-CODED in three places — here,
-                # colocated._lane_commit_pass and the bench's
-                # _lane_stage twin — because a shared per-row helper
-                # (call/closure per row) costs exactly the altitude
-                # this loop exists to remove.  Any semantic change
-                # MUST land in all three; the bench's twin-population
-                # raft-word + persisted-state equality is the
-                # application-level drift detector.
+                # intentionally OPEN-CODED in two places — here and
+                # colocated._lane_commit_pass — because a shared
+                # per-row helper (call/closure per row) costs exactly
+                # the altitude this loop exists to remove.  Any
+                # semantic change MUST land in both.
                 im = log.inmem
                 if (
                     r.msgs or r.ready_to_reads or r.dropped_entries

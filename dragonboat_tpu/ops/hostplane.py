@@ -33,8 +33,7 @@ Three layers:
   the PARITY ORACLE: with ``PARITY`` enabled (env
   ``DRAGONBOAT_TPU_HOSTPLANE_PARITY=1``, or set directly by tests) the
   colocated engine runs both implementations on every generation and
-  fail-stops on any divergence.  They also let ``bench.py
-  phase_hostplane`` measure the stage cost the vectorization removed.
+  fail-stops on any divergence.
 
 The scalar ``_plan_device`` classifier in ops/engine.py remains the
 slow-path fallback for rows that fail the static prefilter — exactly

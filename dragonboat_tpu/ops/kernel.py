@@ -1578,8 +1578,8 @@ def _step_impl(
     arrays [P, G]/[W, G], inbox [M, G]/[M, E, G].  Returns internal
     layout.  ``step`` wraps this with the boundary transposes;
     ``step_internal`` exposes it directly so device-resident loops
-    (bench phase A, future engine paths) never pay the padded-layout
-    boundary traffic (~12 ms/launch at 300k rows, measured r5)."""
+    never pay the padded-layout boundary traffic (~12 ms/launch at
+    300k rows, round 5, remote link)."""
     G = state.G
     P = _P(state)
     M = cin.mtype.shape[0]
@@ -1682,7 +1682,7 @@ def step_internal(
     The padded-layout boundary traffic of ``step`` costs ~12 ms/launch
     at 300k rows (measured r5, real barrier) — more than the slot pass
     itself.  Device-resident loops that keep state in the internal
-    layout across launches (bench phase A) skip it entirely; hosts can
+    layout across launches skip it entirely; hosts can
     build internal-layout operands directly in numpy (a host-side
     transpose is a cheap packed copy) via ``state_to_internal``.
     """
@@ -1697,17 +1697,7 @@ def state_to_internal(st: DeviceState) -> DeviceState:
     return _state_to_internal(st)
 
 
-def inbox_to_internal(ib: Inbox) -> Inbox:
-    """Public [G, M]/[G, M, E] -> internal (G-last) inbox layout — the
-    companion of :func:`state_to_internal` for callers (bench phase A
-    sharded, tests) that build internal-layout launches host-side."""
-    return _inbox_to_internal(ib)
-
-
-def make_step_sharded(  # mesh-hot
-    mesh, state: DeviceState, inbox: Inbox, *, out_capacity: int,
-    internal: bool = False,
-):
+def make_step_sharded(mesh, *, out_capacity: int):  # mesh-hot
     """Build the shard_map'd step over a 1-D groups mesh (ROADMAP 3).
 
     Returns a jitted ``(state, inbox) -> (state', out)`` whose program
@@ -1719,12 +1709,6 @@ def make_step_sharded(  # mesh-hot
     shard-local quantity is the slot-compaction trip count ``n_occ``
     (a per-shard max): a shard with emptier inboxes runs fewer slot
     passes, which is exactly the empty-slot no-op contract.
-
-    ``state``/``inbox`` are EXAMPLE operands (shape/ndim only) used to
-    derive per-leaf partition specs; ``internal=True`` expects the
-    G-last layout (``state_to_internal``/``inbox_to_internal``) and
-    shards the TRAILING axis of every leaf, so phase-A-style loops keep
-    the packed-lane layout across launches with no boundary transposes.
     """
     import jax as _jax
 
@@ -1734,29 +1718,15 @@ def make_step_sharded(  # mesh-hot
     if len(mesh.axis_names) != 1:
         raise ValueError("groups mesh must be one-dimensional")
     axis = mesh.axis_names[0]
-    fn = step_internal if internal else step
 
     def _local(st, ib):
-        return fn(st, ib, out_capacity=out_capacity)
+        return step(st, ib, out_capacity=out_capacity)
 
-    if internal:
-        # G trails every leaf: build per-leaf specs by ndim
-        def spec_of(a):
-            return _PS(*([None] * (a.ndim - 1) + [axis]))
-
-        out_shapes = _jax.eval_shape(_local, state, inbox)
-        in_specs = (
-            _jax.tree.map(spec_of, state),
-            _jax.tree.map(spec_of, inbox),
-        )
-        out_specs = _jax.tree.map(spec_of, out_shapes)
-    else:
-        # G leads every leaf: a single prefix spec covers each pytree
-        in_specs = (_PS(axis), _PS(axis))
-        out_specs = (_PS(axis), _PS(axis))
+    # G leads every leaf: a single prefix spec covers each pytree
     return _jax.jit(
         _shard_map(
-            _local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            _local, mesh=mesh, in_specs=(_PS(axis), _PS(axis)),
+            out_specs=(_PS(axis), _PS(axis)),
             # every spec here is sharded, so the varying-axes check
             # has nothing to verify; skip its trace-time cost
             check_vma=False,

@@ -1,7 +1,7 @@
 """Device placement for the ops plane: one mesh-aware selection helper.
 
 Before this module every launch site hardcoded ``jax.devices()[0]``
-(``ops/engine.py``, both bench phases), which is exactly the
+(``ops/engine.py``), which is exactly the
 single-chip assumption ROADMAP item 3 calls the missing multiplier.
 All device/mesh selection now routes through here:
 
@@ -19,7 +19,8 @@ All device/mesh selection now routes through here:
   owns the contiguous row block ``[d*Gl, (d+1)*Gl)``.
 
 * :func:`configure_compile_cache` — the one compile-cache rule every
-  entry point (``chip_smoke.py``, ``bench.py``, the tests) follows.
+  entry point (``chip_smoke.py``, ``benchmark/run.py``, the tests)
+  follows.
 
 Keeping the block contract in ONE module matters: the shard_map'd
 launch slices state by block, the route tables classify device
@@ -74,7 +75,7 @@ def configure_compile_cache(jax_module=None) -> str:
 
 
 def default_device(jax_module=None):
-    """The engine/bench home device.  ``DRAGONBOAT_TPU_DEVICE=<i>``
+    """The engine's home device.  ``DRAGONBOAT_TPU_DEVICE=<i>``
     overrides the index; the default (0) is byte-for-byte the old
     hardcoded ``jax.devices()[0]`` behavior."""
     if jax_module is None:

@@ -199,9 +199,9 @@ def _b_scatter_inbox_rows():
     return (_inbox(CANON["M"]), pos, _inbox(CANON["M"], 4)), {}
 
 
-# audit-only jit of the bench/consensus round: route() itself is a pure
-# function callers jit (bench.py compiles its own); this wrapper puts
-# its program under the same dtype/transfer audit as everything else
+# audit-only jit of the consensus round: route() itself is a pure
+# function callers jit; this wrapper puts its program under the same
+# dtype/transfer audit as everything else
 _routed_round_audit = functools.partial(
     jax.jit,
     static_argnames=(
@@ -304,7 +304,7 @@ ENTRY_POINTS: Tuple[EntryPoint, ...] = (
         C._scatter_inbox_rows,
         _b_scatter_inbox_rows,
     ),
-    # route (audit-only jit wrappers; bench jits its own copies)
+    # route (audit-only jit wrappers)
     EntryPoint(
         "route.routed_round", _routed_round_audit, _b_routed_round,
         runtime=False,
@@ -326,18 +326,15 @@ def mesh_entry_points(mesh) -> Tuple[EntryPoint, ...]:
     jaxcheck transfer/dtype rules extended to the multi-chip programs
     (docs/MULTICHIP.md; the ISSUE-12 "zero cross-device host hops"
     gate).  Not part of the static ENTRY_POINTS tuple because a mesh
-    needs visible devices: bench.phase_multichip, the multichip smoke
-    and tests/test_multichip.py audit these explicitly under forced
-    host devices.  CANON['G'] must divide the mesh (64 covers 1-8)."""
+    needs visible devices: tests/test_multichip.py audits these
+    explicitly under forced host devices.  CANON['G'] must divide the mesh (64 covers 1-8)."""
     import numpy as np
 
     G = CANON["G"]
     if G % mesh.size:
         raise ValueError(f"CANON G={G} must divide mesh size {mesh.size}")
 
-    step_sharded = K.make_step_sharded(
-        mesh, _state(), _inbox(CANON["M"]), out_capacity=CANON["O"]
-    )
+    step_sharded = K.make_step_sharded(mesh, out_capacity=CANON["O"])
     round_sharded = R.make_sharded_round(
         mesh, M=_M_ROUTE, E=CANON["E"], out_capacity=CANON["O"],
         budget=CANON["budget"], xbudget=4, base=_BASE_ROUTE,

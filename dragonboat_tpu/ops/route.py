@@ -11,8 +11,7 @@ would round-trip device->host->device each step.
 ``DeviceOut`` buffer whose destination replica is resident on the same
 chip are scattered straight into the next step's ``Inbox``.  Combined
 with ``ops/kernel.step`` this closes the loop — elections, replication
-and commit advance run entirely device-side, which is what the
-consensus benchmark (bench.py) measures.
+and commit advance run entirely device-side.
 
 Routing is **best-effort**: anything the router cannot deliver (peer
 off-device, per-sender slot budget exhausted, REPLICATE entries no
@@ -402,8 +401,8 @@ def make_prefill(
     propose_n: int = 1,
 ) -> Inbox:
     """Injected inbox prefix: slot 0 = LOCAL_TICK for every row, slot 1 =
-    a ``propose_n``-entry PROPOSE on rows currently leading (the bench's
-    load generator; empty slots stay NO_OP and cost nothing)."""
+    a ``propose_n``-entry PROPOSE on rows currently leading (a device-
+    side load generator; empty slots stay NO_OP and cost nothing)."""
     G = state.G
 
     def zm():
@@ -447,7 +446,7 @@ def merge_and_route(
     the replay; dropping the inputs is raft-safe message loss), then
     route the outboxes into the next round's inbox on top of a fresh
     tick/proposal prefill.  Shared by ``routed_round`` and callers that
-    jit step/route as SEPARATE programs for compile time (bench.py).
+    jit step/route as SEPARATE programs for compile time.
 
     Returns (state', inbox', stats, escalated_row_count).
     """
@@ -524,15 +523,14 @@ def fused_rounds(
     per-round stats fall out of the unrolled loop for free, and the
     compile cost is ``rounds`` copies of one round's program — NOT the
     pathological step+route mega-fusion the r5 compile-time finding
-    rules out (bench.py keeps step and route as separate jit units at
-    scale geometry for exactly that reason; a K-chain of the SAME
-    round program reuses its fusion decisions and stays linear).
+    rules out (step and route stay separate jit units at scale
+    geometry for exactly that reason; a K-chain of the SAME round
+    program reuses its fusion decisions and stays linear).
 
     Bit-exactness contract: ``fused_rounds(..., rounds=K)`` must equal
     K sequential ``routed_round`` calls, state and inbox, bit for bit
-    — the serial-K parity oracle (tests/test_hostplane.py, armed live
-    under ``DRAGONBOAT_TPU_HOSTPLANE_PARITY`` in the bench's fused
-    split).
+    — the serial-K parity oracle (tests/test_hostplane.py
+    ``TestFusedRoundOracle``).
 
     Returns ``(state', inbox', stats [rounds, 6], n_esc [rounds])`` —
     per-round RouteStats rows and escalation counts (an escalated
@@ -883,9 +881,8 @@ def make_sharded_round(  # mesh-hot
     per-device stats lanes are: RouteStats order for the local router,
     then [sent, delivered, dropped_budget, dropped_xlane, dropped_ring,
     escalated, rows_live] for the lane/step, one row per (device,
-    round) — the per-device split ``bench.py phase_multichip``
-    balances and records (``rounds=1``, the default, keeps the
-    historical [D, 6]/[D, 7] shape).
+    round) — the per-device split (``rounds=1``, the default, keeps
+    the historical [D, 6]/[D, 7] shape).
     """
     import jax as _jax
 

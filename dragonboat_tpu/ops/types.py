@@ -7,8 +7,8 @@ step advances every row at once (SURVEY.md §7 "Architecture stance").
 
 A **row** is one (shard, replica) pair — exactly what one scalar ``Raft``
 object models.  All protocol scalars are ``int32`` (TPUs have no native
-int64; indexes/terms stay < 2^31 which is ample for any deployment the
-bench exercises — the host WAL uses 64-bit indexes and escalates rows on
+int64; indexes/terms stay < 2^31 which is ample for any deployment
+measured — the host WAL uses 64-bit indexes and escalates rows on
 overflow long before that).
 
 Shape legend:

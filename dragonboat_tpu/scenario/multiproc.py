@@ -12,8 +12,9 @@ go over the RPC fault op to the victim's own FaultController.
 
 Two entry points ride it:
 
-* :func:`run_rpc_smoke` — the ~5s CI gate (scripts/rpc_smoke.sh): a
-  2-process fleet commits over the wire, the leader's process is
+* :func:`run_rpc_smoke` — the ~5s gate of tests/test_rpc.py
+  (``test_rpc_smoke_two_process_fleet``): a 2-process fleet commits
+  over the wire, the leader's process is
   SIGKILLed mid-service, a restart over the same dirs recovers within
   ``assert_recovery_sla``, and post-recovery commits + reroutes pass.
 * :func:`run_mini_multiproc_day` — the 3-process mini production day
@@ -65,13 +66,10 @@ class ProcFleet:
     """N procworker children + the client-side planes over them."""
 
     def __init__(self, n: int = 3, *, workdir: str = "/tmp/mpday",
-                 base_port: int = 29650, fresh: bool = True,
-                 shards: int = 1, rpc_inflight: int = 64):
+                 base_port: int = 29650, fresh: bool = True):
         self.n = n
         self.workdir = workdir
         self.base_port = base_port
-        self.shards = shards
-        self.rpc_inflight = rpc_inflight
         self.procs: Dict[int, subprocess.Popen] = {}
         self.handles: Dict[str, RemoteHostHandle] = {}
         self.ready: Dict[int, dict] = {}
@@ -95,8 +93,6 @@ class ProcFleet:
         pkg_root = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
-        env["DRAGONBOAT_PROC_SHARDS"] = str(self.shards)
-        env["DRAGONBOAT_PROC_RPC_INFLIGHT"] = str(self.rpc_inflight)
         return subprocess.Popen(
             [sys.executable, "-m", "dragonboat_tpu.scenario.procworker",
              str(idx), str(self.n), self.workdir, str(self.base_port)],
@@ -269,7 +265,7 @@ def _sla_hosts(fleet: ProcFleet) -> Dict[str, RemoteHostHandle]:
 
 
 # ---------------------------------------------------------------------------
-# the ~5s CI gate (scripts/rpc_smoke.sh)
+# the ~5s gate (tests/test_rpc.py::test_rpc_smoke_two_process_fleet)
 # ---------------------------------------------------------------------------
 def run_rpc_smoke(n: int = 2, *, workdir: str = "/tmp/rpc-smoke",
                   base_port: int = 29750) -> dict:
@@ -529,7 +525,8 @@ def run_mini_multiproc_day(n: int = 3, *, workdir: str = "/tmp/mpday",
 
 
 # ---------------------------------------------------------------------------
-# the ~5s telemetry CI gate (scripts/fleetobs_smoke.sh)
+# the ~5s telemetry gate
+# (tests/test_fleetobs.py::test_fleetobs_smoke_two_process_fleet)
 # ---------------------------------------------------------------------------
 def run_fleetobs_smoke(n: int = 2, *, workdir: str = "/tmp/fleetobs-smoke",
                        base_port: int = 29850) -> dict:

@@ -110,29 +110,19 @@ def main() -> None:
         if time.time() > deadline:
             raise TimeoutError(f"worker {idx}: member map incomplete")
         time.sleep(0.1)
-    # DRAGONBOAT_PROC_SHARDS grows the worker to a multi-shard host
-    # (shards 1..S, all AuditKV, replica ids == slot numbers) — the
-    # read-plane bench spreads its 100k-session plane across them
-    n_shards = int(os.environ.get("DRAGONBOAT_PROC_SHARDS", "1"))
-    for sid in range(1, max(1, n_shards) + 1):
-        nh.start_replica(
-            members, False, AuditKV,
-            Config(replica_id=idx, shard_id=sid, election_rtt=20,
-                   heartbeat_rtt=2, pre_vote=True, check_quorum=True),
-        )
+    nh.start_replica(
+        members, False, AuditKV,
+        Config(replica_id=idx, shard_id=1, election_rtt=20,
+               heartbeat_rtt=2, pre_vote=True, check_quorum=True),
+    )
 
     # the nemesis plane, remotely drivable: the parent injects
     # asym_drop/asym_delay/partition windows on THIS host's transport
     # through the same RPC ingress clients use
     ctl = FaultController(seed=1000 + idx)
     ctl.install_nodehost(f"w{idx}", nh)
-    # DRAGONBOAT_PROC_RPC_INFLIGHT narrows the per-host admission door
-    # (RpcServer sheds RPC_ERR_BUSY beyond it) — the read-plane bench
-    # uses it to make per-replica serving capacity the explicit
-    # bottleneck being scaled
-    inflight = int(os.environ.get("DRAGONBOAT_PROC_RPC_INFLIGHT", "64"))
     srv = RpcServer(nh, rpc_addr, fault_controller=ctl,
-                    allow_fault_ops=True, max_inflight=inflight)
+                    allow_fault_ops=True)
     srv.start()
     _write_atomic(
         f"{workdir}/ready-{idx}.json",

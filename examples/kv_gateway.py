@@ -12,14 +12,11 @@ the per-read ReadIndex quorum round trip (docs/GATEWAY.md).  Run:
     python examples/kv_gateway.py
 
 When the backing NodeHosts run the colocated device engine, client
-latency also rides the launch pipeline: generations double-buffer by
-default (``DRAGONBOAT_TPU_PIPELINE_DEPTH``, default 2);
-``DRAGONBOAT_TPU_SYNC_FLOOR_MS`` simulates a link latency for tests
-(e.g. ``=100`` models a remote device; 0, the default, is the real
-machine).  Fused commit waves (``DRAGONBOAT_TPU_FUSED_ROUNDS``,
-default 3) then collapse a proposal's propose→commit rounds into one
-launch + one readback window.  ``chip_smoke.py`` at the repo root runs
-this served path on the device engine, on the chip.
+latency also rides the launch pipeline: generations double-buffer
+(engine keyword ``pipeline_depth``, 2), and fused commit waves (engine
+keyword ``fused_rounds``, 3) collapse a proposal's propose→commit
+rounds into one launch + one readback window.  ``chip_smoke.py`` at
+the repo root runs this served path on the device engine, on the chip.
 """
 from __future__ import annotations
 

@@ -15,16 +15,13 @@ examples/kv_gateway.py and docs/GATEWAY.md.
 
 NOTE on the device launch pipeline: when these NodeHosts share a
 ``ColocatedEngineGroup`` (the product device path), generations are
-double-buffered by default — the merge tail runs one generation behind
-the device so a readback's latency overlaps the next launch.  Two
-knobs: ``DRAGONBOAT_TPU_PIPELINE_DEPTH`` (2 = double-buffered, 1 = the
-old serial loop) and ``DRAGONBOAT_TPU_SYNC_FLOOR_MS`` (a simulated
-link latency for tests, e.g. 100 to model a remote device; 0, the
-default, is the real machine) — see ``bench.py phase_pipeline``.
-Routable generations additionally fuse ``DRAGONBOAT_TPU_FUSED_ROUNDS``
-consecutive consensus rounds device-side (default 3: a quiet-path
-proposal commits in ONE launch + ONE readback window; 1 restores the
-single-round loop).
+double-buffered — the merge tail runs one generation behind the
+device so a readback's latency overlaps the next launch — and routable
+generations fuse three consecutive consensus rounds device-side: a
+quiet-path proposal commits in ONE launch + ONE readback window.  The
+engine keywords ``pipeline_depth=1`` and ``fused_rounds=1`` are the
+serial single-round loop tests compare against; ``sync_floor_ms`` is a
+simulated link latency for tests (0, the default, is the real machine).
 """
 from __future__ import annotations
 

@@ -1,6 +1,7 @@
 #!/usr/bin/env bash
-# Static-analysis gates + analyzer self-tests (docs/ANALYSIS.md), wired
-# into tier-1 as a cheap post-step: raftlint (AST rules, <60s),
+# Static-analysis gates + analyzer self-tests (docs/ANALYSIS.md), a
+# convenience over gates that are tier-1 tests already (tests/
+# test_analysis.py, test_jaxcheck.py): raftlint (AST rules, <60s),
 # jaxcheck (the device-plane program auditor: dtype/transfer/donation/
 # G-last over every ops/ jit entry point, <60s on CPU) and wirecheck
 # (the wire-compat auditor: golden corpus, skew matrix, 500-mutation
@@ -13,7 +14,7 @@ cd "$(dirname "$0")/.." || exit 1
 set -o pipefail
 rc=0
 timeout -k 5 60 env JAX_PLATFORMS=cpu python -m dragonboat_tpu.analysis \
-    --baseline dragonboat_tpu/analysis/baseline.txt dragonboat_tpu bench.py \
+    --baseline dragonboat_tpu/analysis/baseline.txt dragonboat_tpu \
     || rc=1
 timeout -k 5 60 env JAX_PLATFORMS=cpu python -m dragonboat_tpu.analysis \
     --jax --baseline dragonboat_tpu/analysis/jax_baseline.txt \

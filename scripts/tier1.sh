@@ -1,68 +1,8 @@
 #!/usr/bin/env bash
-# Tier-1 verify: the ROADMAP.md command (plus --durations=15 so the
-# budget hogs are named in every run), runnable from any cwd, with four
-# cheap post-steps: the observability smoke (scripts/obs_smoke.sh, ~5s),
-# the serving-front-plane smoke (scripts/gateway_smoke.sh, ~10s: batched
-# session proposals, lease reads, routing convergence, overload
-# shedding), the big-state smoke (scripts/bigstate_smoke.sh, ~5s:
-# capped resumable snapshot stream, cap respected, commit p50 held,
-# mid-transfer kill resumes), the launch-pipeline smoke
-# (scripts/pipeline_smoke.sh, ~5s: depth-2 double buffering at a 10ms
-# simulated sync floor, overlap counter > 0, all futures complete,
-# parity green), the fused-round smoke (scripts/fusedround_smoke.sh,
-# ~5s: K=3 fused commit waves fire, one readback window per
-# generation, parity green, clean drain), the update-lane smoke
-# (scripts/updatelanes_smoke.sh,
-# ~5s: live cluster generations with the array-side pb.Update lanes
-# carrying rows, parity green, zero divergence halts), the multi-chip
-# smoke (scripts/multichip_smoke.sh,
-# ~60s warm: sharded kernel/round parity at 2/4/8 forced host
-# devices + the transfer-free jaxcheck gate over the sharded entry
-# points), the production-day scenario smoke (scripts/scenario_smoke.sh,
-# ~10-15s: tiny seeded mini-day over the mixed on-disk/in-memory/witness
-# fleet — every disturbance class fired, audit green, zero SLA misses),
-# the cross-process RPC smoke (scripts/rpc_smoke.sh, ~5-8s: a real
-# two-OS-process fleet over RPC/TCP + gossip, leader SIGKILLed and
-# recovered under SLA, routing reconverged with zero shared memory)
-# the read-plane smoke (scripts/readplane_smoke.sh, ~3s: 3-replica
-# shard behind the gateway, one read per consistency level with the
-# follower path actually taken, full audit incl. the bounded-read
-# containment pass green),
-# the fleet-scope telemetry smoke (scripts/fleetobs_smoke.sh, ~5s:
-# 2-process fleet under traced gateway proposals, >=1 trace stitched
-# across the RPC boundary, bounded obs tails polled from every
-# process, JSON SLO burn-rate ledger with the full objective catalog),
-# the wire-compat smoke (scripts/wirecheck_smoke.sh, ~3s: the full
-# wirecheck gate — goldens/skew/fuzz/rot-guards — plus a live
-# mutated-golden true positive)
-# and the static-analysis gates + analyzer
-# self-tests (scripts/lint.sh: raftlint + jaxcheck + wirecheck +
-# fixtures, <3m).
-# Prints
-# DOTS_PASSED=<n> and a TIER1_BUDGET runtime line against the 870s
-# ROADMAP budget, and exits non-zero if any step fails.
+# Tier-1: the one test command, as the driver runs it (six xdist
+# workers, one file a worker at a time, a 1,470 s limit).  Prints
+# DOTS_PASSED=<n> and exits with pytest's code.  The driver also sets
+# ALLOW_MULTIPLE_LIBTPU_LOAD=1 for its own runs; no test here loads
+# the TPU's library, so the repository does not.
 cd "$(dirname "$0")/.." || exit 1
-t0=$(date +%s)
-set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --durations=15 --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)
-t1=$(date +%s)
-total=$((t1 - t0))
-headroom=$((870 - total))
-warn=""
-if [ "$headroom" -lt 60 ]; then
-    warn=" — UNDER 60s HEADROOM: gate new suite time behind env vars (ROADMAP budget note)"
-fi
-echo "TIER1_BUDGET: pytest ${total}s of 870s (headroom ${headroom}s)${warn}"
-timeout -k 10 120 bash scripts/obs_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 120 bash scripts/gateway_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 120 bash scripts/bigstate_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 120 bash scripts/pipeline_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 120 bash scripts/fusedround_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 120 bash scripts/updatelanes_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 240 bash scripts/multichip_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 120 bash scripts/scenario_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 120 bash scripts/rpc_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 120 bash scripts/readplane_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 120 bash scripts/fleetobs_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 120 bash scripts/wirecheck_smoke.sh || rc=$((rc == 0 ? 1 : rc))
-timeout -k 10 300 bash scripts/lint.sh || rc=$((rc == 0 ? 1 : rc))
-exit $rc
+set -o pipefail; rm -rf /tmp/_t1.log /tmp/_t1.xml; timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 --dist loadfile --junitxml=/tmp/_t1.xml -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; said=$(sed -n 's/.*<testsuite [^>]*errors="\([0-9]*\)" failures="\([0-9]*\)" skipped="\([0-9]*\)" tests="\([0-9]*\)".*/\4 \1 \2 \3/p' /tmp/_t1.xml 2>/dev/null | head -n 1 | awk '{n=$1-$2-$3-$4; print (n<0 ? 0 : n)}'); echo DOTS_PASSED=${said:-$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)}; echo WORKERS_DOWN=$(grep -acE '\[gw[0-9]+\] node down' /tmp/_t1.log 2>/dev/null); exit $rc

@@ -20,8 +20,7 @@ os.environ.setdefault("DRAGONBOAT_TPU_INVARIANTS", "1")
 # schedule merely GRAZES — even if this run got lucky with timing —
 # fails the test with both witness stacks.  Same env-gate pattern as
 # invariants; set =0 to opt out.  Scoped to the modules that churn
-# clusters hardest rather than suite-wide to bound the tier-1 budget
-# (overhead tracked by bench.phase_lockcheck).
+# clusters hardest rather than suite-wide to bound the tier-1 budget.
 os.environ.setdefault("DRAGONBOAT_TPU_LOCKCHECK", "1")
 
 # eight forced host devices for the mesh-capable paths; must be in the

@@ -904,11 +904,11 @@ def test_baseline_rejects_malformed_lines(tmp_path):
 
 def test_tree_is_lint_clean_with_checked_in_baseline():
     """THE gate, same invocation as scripts/lint.sh: zero unbaselined
-    findings over the package (+ bench.py)."""
+    findings over the package."""
     old = os.getcwd()
     os.chdir(REPO)
     try:
-        findings = lint_paths(["dragonboat_tpu", "bench.py"])
+        findings = lint_paths(["dragonboat_tpu"])
         baseline = load_baseline(
             os.path.join(REPO, "dragonboat_tpu/analysis/baseline.txt")
         )

@@ -599,7 +599,8 @@ class TestLaggardCatchup:
         measurement on a machine the rest of tier-1 is also loading;
         passes in isolation, and a real pacing regression fails both
         the first run and the settle-retry."""
-        out = _run_laggard_catchup(STATE_MB, cap_bytes=6 * 1024 * 1024)
+        cap = 6 * 1024 * 1024
+        out = _run_laggard_catchup(STATE_MB, cap_bytes=cap)
         assert out["caught_up"], out
         assert out["kills"] >= 1, out
         assert out["resumes"] >= 1, f"restart-from-zero, not resume: {out}"
@@ -609,6 +610,9 @@ class TestLaggardCatchup:
         # arrives via ordinary log replay after the install)
         assert out["stream_bytes"] >= (STATE_MB - 2) * 1024 * 1024, out
         assert out["throttled_s"] > 0, f"cap never engaged: {out}"
+        # the cap is respected: burst headroom and the final partial
+        # interval allow ~1.35x over the whole catch-up
+        assert out["stream_bytes"] / out["catchup_s"] <= 1.35 * cap, out
         assert out["window"] >= 1.0, out
         assert out["during"] >= 0.8 * out["base"], (
             f"commit path starved during catch-up: {out['during']:.0f}/s "

@@ -248,8 +248,7 @@ class TestColocatedChaos:
     reason="set CHAOS_ROUNDS=N for the long colocated schedule",
 )
 def test_extended_colocated_chaos_schedule():
-    """The drummer-style long soak over the colocated stack (the r4
-    recorded artifact is docs/CHAOS_r04.md)."""
+    """The drummer-style long soak over the colocated stack."""
     rounds = int(os.environ["CHAOS_ROUNDS"])
     cluster = ColocatedCluster()
     acked = {}

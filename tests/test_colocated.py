@@ -39,7 +39,7 @@ from test_vector_engine import read_r
 
 # budget 4 covers a leader's worst per-peer launch (several deferred
 # ticks' heartbeats + append replicate + commit broadcast) so steady
-# state stays fully on-device — same reasoning as bench.py's BUDGET
+# state stays fully on-device
 GEOM = dict(capacity=16, P=5, W=32, M=8, E=4, O=32, budget=4)
 
 

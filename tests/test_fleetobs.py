@@ -673,6 +673,21 @@ class TestObsOverRpc:
 
 
 # ---------------------------------------------------------------------------
+# the real thing: two OS processes polled over RPC_OP_OBS
+# ---------------------------------------------------------------------------
+def test_fleetobs_smoke_two_process_fleet():
+    from dragonboat_tpu.scenario.multiproc import run_fleetobs_smoke
+
+    # run_fleetobs_smoke itself asserts >= 1 trace stitched across the
+    # RPC boundary and a plain-JSON SLO ledger with the default catalog
+    out = run_fleetobs_smoke(n=2, workdir="/tmp/fleetobs-smoke-test",
+                             base_port=31150)
+    assert out["stitches"] >= 1, out
+    assert out["polls"] >= 2 and out["reply_bytes"] > 0, out
+    assert out["slo_objectives"] >= 2, out
+
+
+# ---------------------------------------------------------------------------
 # the 3-process SIGKILL-gap day (gated: real processes, real kill)
 # ---------------------------------------------------------------------------
 @pytest.mark.skipif(os.environ.get("DRAGONBOAT_MULTIPROC") != "1",

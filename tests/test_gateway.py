@@ -354,6 +354,7 @@ class TestGatewayEndToEnd:
             assert gw.read(1, "k9") == 9
             assert gw.routes.lookup(1) == leader
             st = gw.stats()
+            assert st["route_table"].get(1) == leader
             assert st["committed"] == 10 and st["failed"] == 0
             assert st["lease_reads"] + st["read_fallbacks"] >= 1
             h.close()

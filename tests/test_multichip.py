@@ -94,7 +94,7 @@ def test_sharded_step_parity():
     from dragonboat_tpu.ops.kernel import step
 
     step_single = jax.jit(functools.partial(step, out_capacity=O))
-    step_shard = make_step_sharded(mesh, st, ib, out_capacity=O)
+    step_shard = make_step_sharded(mesh, out_capacity=O)
     sa, sb = st, st
     for _ in range(4):
         sa, oa = step_single(sa, ib)

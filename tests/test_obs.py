@@ -44,6 +44,7 @@ from dragonboat_tpu.metrics import MetricsRegistry, _labeled
 from dragonboat_tpu.obs import (
     FlightRecorder,
     Tracer,
+    export_merged_json,
     format_timeline,
     hosts_timeline,
     merged_timeline,
@@ -735,6 +736,15 @@ class TestNodeHostSurface:
             data = json.loads(leader.export_trace_json(path))
             assert data["traceEvents"]
             assert json.load(open(path)) == data
+
+            # the cluster-wide forms: one parseable trace_event export
+            # over every host's tracer, and one merged timeline that
+            # saw the election
+            merged = json.loads(
+                export_merged_json([nh.tracer for nh in nhs.values()])
+            )
+            assert merged["traceEvents"]
+            assert "leader_change" in hosts_timeline(nhs.values())
 
             # engine gauges exist and scrape cleanly (values are racy
             # by design; the scrape itself must not throw)

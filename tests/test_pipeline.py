@@ -448,6 +448,9 @@ class TestFusedWaves:
                 inflight = len(core._inflight)
             assert st["fused_waves"] > 0, st
             assert st["fused_rounds_stepped"] >= 3 * st["fused_waves"]
+            # depth 2: host work ran while a readback was in flight
+            assert st["launches"] > 5, st
+            assert st["pipeline_overlap_s"] > 0, st
             assert st["readback_windows"] + inflight == (
                 st["launches"] + st.get("sel_fallbacks", 0)
             ), (st, inflight)
@@ -506,7 +509,7 @@ class TestFusedWaves:
 
     def test_fused_disabled_by_knob(self):
         """fused_rounds=1 is the PR 11 single-round loop: zero waves,
-        env/kwarg kill switch proven."""
+        the keyword is the reference the wave is compared with."""
         group, nhs = make_cluster(
             KVStore, "fusedoff", fused_rounds=1,
         )

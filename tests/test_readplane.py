@@ -40,7 +40,13 @@ from dragonboat_tpu import (
     NodeHost,
     NodeHostConfig,
 )
-from dragonboat_tpu.audit.model import audit_set_cmd
+from dragonboat_tpu.audit import run_audit
+from dragonboat_tpu.audit.history import (
+    AuditClient,
+    HistoryRecorder,
+    run_workload,
+)
+from dragonboat_tpu.audit.model import AuditKV, audit_set_cmd
 from dragonboat_tpu.pb import Message, MessageType
 from dragonboat_tpu.raft.raft import RaftRole
 from dragonboat_tpu.readplane import (
@@ -200,6 +206,15 @@ class TestLeaderCommitHint:
 # ---------------------------------------------------------------------------
 # end to end: consistency levels through the gateway
 # ---------------------------------------------------------------------------
+def _read_path_totals(nhs):
+    """Host-side served-path counters summed over the cluster."""
+    tot = {}
+    for nh in nhs.values():
+        for k, v in nh.read_path_counts().items():
+            tot[k] = tot.get(k, 0) + v
+    return tot
+
+
 class TestReadPlaneEndToEnd:
     def test_read_at_levels_stamps_and_counters(self):
         addrs, nhs = make_gw_cluster(tag="rp-lvl")
@@ -254,13 +269,38 @@ class TestReadPlaneEndToEnd:
             assert rp["lease"] + rp["read_index"] >= 1
             assert st["replica_table"][1], "replica set never learned"
             # host-side counters mirror the served paths
-            tot = {}
-            for nh in nhs.values():
-                for k, v in nh.read_path_counts().items():
-                    tot[k] = tot.get(k, 0) + v
+            tot = _read_path_totals(nhs)
             assert tot["follower"] >= 1 and tot["bounded"] >= 1
         finally:
             close_all(nhs, gw)
+
+    def test_recorded_mix_over_every_level_audits_green(self):
+        """A short recorded read/write mix over all three levels: the
+        Wing-Gong pass covers leader AND follower reads, the bounded
+        pass checks every staleness stamp."""
+        addrs, nhs = make_gw_cluster(AuditKV, tag="rp-mix")
+        try:
+            wait_leader(nhs)
+            rec = HistoryRecorder()
+            stop = threading.Event()
+            clients = [AuditClient(nhs, 1, rec, seed=i) for i in (2, 3)]
+            threads = run_workload(
+                clients, ["k", "k2"], stop, read_ratio=0.25,
+                stale_ratio=0.05, follower_ratio=0.2, bounded_ratio=0.2,
+                pace=0.001,
+            )
+            time.sleep(1.2)
+            stop.set()
+            for t in threads:
+                t.join(10.0)
+            ops = rec.ops()
+            assert {"r", "w"} <= {o.kind for o in ops}
+            rep = run_audit(ops)
+            assert rep.ok, rep.describe()
+            tot = _read_path_totals(nhs)
+            assert tot["follower"] >= 1 and tot["bounded"] >= 1, tot
+        finally:
+            close_all(nhs)
 
     def test_leader_transfer_never_serves_pre_transfer_state(self):
         addrs, nhs = make_gw_cluster(tag="rp-xfer")

@@ -101,8 +101,7 @@ def inbox_row_messages(inbox_np, g, shard_id) -> List[Message]:
 
 
 def test_route_tables_uniform_layout():
-    """Generic builder matches the analytic group-major formulas the
-    bench uses (bench.py phase B)."""
+    """Generic builder matches the analytic group-major formulas."""
     GROUPS, REPL = 4, 3
     shard_ids = np.repeat(np.arange(1, GROUPS + 1), REPL).astype(np.int32)
     replica_ids = np.tile(np.arange(1, REPL + 1), GROUPS).astype(np.int32)

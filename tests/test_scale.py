@@ -9,8 +9,9 @@ VectorStepEngine, at a shard count set by ``SCALE_SHARDS``:
     SCALE_SHARDS=10000 python -m pytest tests/test_scale.py -q -s
 
 It is env-gated (skipped by default) because a 10k-shard run takes
-minutes on the CPU backend; the recorded artifact for the round lives
-in ``docs/SCALE_r03.json`` (written by ``--artifact`` / main()).
+minutes on the CPU backend; ``SCALE_ARTIFACT=<path>`` writes the run's
+record (the round-5 one a configuration still cites is
+``docs/SCALE_r05b_10k.json``).
 
 What it proves:
   * NodeHost + ExecEngine + VectorStepEngine survive >=10k live Node
