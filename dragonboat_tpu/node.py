@@ -134,6 +134,7 @@ class Node:
         "pending_proposal", "pending_read_index", "pending_config_change",
         "pending_snapshot", "pending_leader_transfer", "pending_tables",
         "pending_deadline_hint", "device_reads", "hs_lane_slot",
+        "lease_cell",
         "tick_count", "leader_id", "proposal_count", "stopped", "stopping",
         "_snapshotting",
         "_applied_since_snapshot", "_retired_snapshots", "_apply_lock",
@@ -260,6 +261,11 @@ class Node:
         # the device merge tail's first batched lane save; stable for
         # the node's life (the node<->logdb binding never changes).
         self.hs_lane_slot = -1
+        # (lanes, row, token) while the colocated engine holds this
+        # replica's lease evidence in its age lanes, else None: handed
+        # at arm and taken back at disarm, under the engine's core lock
+        # (ops/hostplane.LeaseAges); read by lease_probe, lock-free
+        self.lease_cell = None
 
         self.tick_count = 0
         self.leader_id = 0
@@ -1398,11 +1404,29 @@ class Node:
         (``Raft._in_lease``), so no challenger can be elected until one
         full election window after a majority last heard from us; the
         leader renews the lease on every quorum of replicate/heartbeat
-        responses (``Raft.lease_remaining_ticks`` over the remotes'
-        ``last_resp_tick``), so a healthy leader holds it continuously
-        instead of saw-toothing with the check-quorum boundary.
-        Serving a local read additionally requires (same as ReadIndex
-        serving):
+        responses, so a healthy leader holds it continuously instead of
+        saw-toothing with the check-quorum boundary.  Where the
+        evidence lives depends on who steps the replica: on the scalar
+        path in the remotes' ``last_resp_tick``, a response anchored at
+        its probe's send tick (``Raft.lease_remaining_ticks``); on the
+        colocated engine in the engine's age lane, renewed by every
+        launch in which a quorum answered after the row's ticks were
+        fed (``ops/hostplane.LeaseAges``).  A replica the engine has
+        armed holds a cell for its row, and then the LANE ALONE
+        stands: its age is counted on the clock of the voter furthest
+        ahead, the row's own or a resident peer's, so a leader whose
+        row is stepped late — or whose ticker stands still — loses its
+        lease by its peers' clocks, with nothing assumed about how
+        evenly the launches feed the rows.  The remotes' anchors are
+        on this replica's clock only and say nothing of that, so they
+        serve a replica the engine does not step (the host path, the
+        host engine) and no other: a row that has just come back from
+        the host path reads through ReadIndex until its first quorum
+        of answers on the device, a launch or two (adding
+        :meth:`tick_lag` here instead was tried and measured, PERF.md
+        section 6: it cost most of the lease and bounded less).
+        Serving a local read additionally requires (same as
+        ReadIndex serving):
 
         * a committed entry in the CURRENT term (a fresh leader's
           commit index is not yet proven current);
@@ -1414,7 +1438,14 @@ class Node:
         ``NodeHost.lease_read``.  Lock-free probe off producer
         threads: every field read is one GIL-atomic load, and a lease
         lost immediately after a held answer is exactly the race the
-        margin exists for."""
+        margin exists for.  The age lane is the engine's, and a row can
+        be released and armed again for ANOTHER replica between two
+        loads: the cell carries the token the row had when it was
+        handed over, every disarm bumps the row's token before anything
+        is written for a later owner, and the probe loads the age
+        first and the token second — a token that still matches was
+        not yet bumped when it was loaded, so the age loaded before it
+        was this replica's; otherwise the cell yields nothing."""
         if self.stopped or self.stopping:
             return LEASE_MISS_NOT_LEADER, 0
         r = self.peer.raft
@@ -1429,7 +1460,15 @@ class Node:
             # which a concurrently-applying config change mutates
             # (review finding — "dictionary changed size" would crash
             # a metrics scrape)
-            left = r.lease_remaining_ticks()
+            cell = self.lease_cell
+            if cell is None:
+                left = r.lease_remaining_ticks()
+            else:
+                lanes, g, token = cell
+                age = int(lanes.age[g])  # the age FIRST ...
+                left = 0
+                if lanes.token[g] == token:  # ... the token SECOND
+                    left = r.lease_ticks_at_age(age)
         except Exception:  # noqa: BLE001 — racing a concurrent step's
             # log/membership mutation (compaction/append/config
             # change): no lease this probe

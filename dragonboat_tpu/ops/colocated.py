@@ -702,10 +702,14 @@ class ColocatedVectorEngine(VectorStepEngine):
             # rows the completion tail touched in Python: the live list
             # of every round (active rows, lane rows the round's flags
             # mark, resident rows with effects), plus the rows the
-            # lease pass armed, disarmed, started a window of or
-            # anchored.  The tick lane's other rows are clocked in one
-            # two-column loop and not counted
+            # lease pass armed or disarmed.  The tick lane's other rows
+            # are clocked in one two-column loop and not counted
             completion_rows_walked=0,
+            # the lease, renewed every launch (hostplane.LeaseAges):
+            # armed leader rows a completion stepped with ticks, and of
+            # those the ones a quorum had answered since the feed
+            # (F_QUORUM_FRESH), whose lease age started over
+            lease_rows_armed=0, lease_rows_fresh=0,
             # the apply workers' totals over every member NodeHost,
             # folded in once a step call (_fold_apply): batches and
             # entries applied, time inside node.apply(), from hand-off
@@ -813,30 +817,41 @@ class ColocatedVectorEngine(VectorStepEngine):
             st["wal_bytes"] += after[1] - before[1]
             st["wal_records"] += after[2] - before[2]
 
-    def _lease_pass(  # hostplane-hot
-        self, nodes, gs, fed, skip, flags, vals_np, pos_sum
-    ) -> Tuple[int, np.ndarray]:
-        """Per-generation device-lease evidence pass (ROADMAP 4b): see
-        hostplane.LeaseLanes.  Runs before the bulk mirror write (role
-        transitions read the OLD mirror) and before tick bookkeeping
-        (window starts stamp the pre-launch clock — the conservative
-        side).
+    def _make_lease(self):
+        """The lease as age lanes, renewed every launch, where the base
+        engine keeps the window model (hostplane.LeaseAges)."""
+        return hostplane.LeaseAges(
+            self.capacity, self.P, node_of=lambda g: self._meta[g].node
+        )
 
-        An array pass over the completion's rows: ``gs`` (the stepped
-        rows, then the other live ones), ``fed`` the ticks each was
-        fed, ``nodes`` their nodes and ``skip`` the rows to leave alone
-        (escalated, stopped, detached; None = none).  Python touches a
-        row only where its role changed (arm / disarm), its window
-        crossed (the start is the row's own clock) or its anchor moved
-        since it was last applied (hostplane.LeaseLanes.lanes_step);
-        returns how many rows that was, and the rows that hold an
-        anchor this launch.  ``_lease_pass_rows`` is the per-row twin
-        the parity oracle runs beside it."""
+    def _arm_lease(self, g: int, r) -> None:
+        self._lease.arm(g, r.election_timeout)
+
+    def _lease_pass(  # hostplane-hot
+        self, nodes, gs, clock, fed, n_step, skip, flags, vals_np, pos_sum
+    ) -> int:
+        """Per-generation device-lease evidence pass: see
+        hostplane.LeaseAges.  Runs before the bulk mirror write (role
+        transitions read the OLD mirror) and before tick bookkeeping
+        (the anchor is the clock BEFORE this launch's ticks, so the
+        pass takes the ticks the bookkeeping is about to add).
+
+        An array pass over the completion's rows: ``gs`` holds the
+        ``n_step`` stepped rows, then the other live ones; ``clock`` and
+        ``fed`` the clock ticks and the device ticks of the stepped
+        rows, ``nodes`` the rows' nodes and ``skip`` the rows to leave
+        alone (escalated, stopped, detached; None = none).  Python
+        touches a row only where its role changed (arm / disarm);
+        returns how many rows that was.  ``hostplane.lease_pass_rows``
+        is the per-row twin the parity oracle runs beside it."""
         lease = self._lease
+        gs_step = gs[:n_step]
         if skip is not None:
             keep = ~skip
-            gs, fed = gs[keep], fed[keep]
             nodes = list(itertools.compress(nodes, keep.tolist()))
+            gs = gs[keep]
+            keep = keep[:n_step]
+            gs_step, clock, fed = gs_step[keep], clock[keep], fed[keep]
         walked = 0
         if vals_np is not None and len(vals_np):
             k = pos_sum[gs]
@@ -844,59 +859,18 @@ class ColocatedVectorEngine(VectorStepEngine):
             g_has = gs[has]
             roles = vals_np[k[has], _R_ROLE]
             chg = np.nonzero(roles != self._mirror[_R_ROLE, g_has])[0]
-            walked += len(chg)
+            walked = len(chg)
             # raftlint: ignore[host-loop] residue: the rows whose role changed this launch
             for c in chg.tolist():
                 r = nodes[has[c]].peer.raft
                 if int(roles[c]) == _ROLE_LEADER_I and r.check_quorum:
-                    lease.arm(int(g_has[c]), r.election_timeout, 0)
+                    lease.arm(int(g_has[c]), r.election_timeout)
                 else:
                     lease.disarm(int(g_has[c]))
-        crossed, held, moved = lease.lanes_step(gs, fed, flags)
-        walked += len(crossed) + len(moved)
-        # raftlint: ignore[host-loop] residue: the rows whose window crossed (one in election_timeout launches a leader)
-        for i in crossed.tolist():
-            # the device's CheckQuorum sweep ran this launch: a fresh
-            # window starts on this row's clock NOW
-            lease.window_start[gs[i]] = nodes[i].tick_count
-        # raftlint: ignore[host-loop] residue: the rows whose anchor moved (once a window a leader)
-        for i in moved.tolist():
-            nodes[i].peer.raft.anchor_quorum_evidence(
-                int(lease.window_start[gs[i]])
-            )
-        return walked, gs[held]
-
-    # raftlint: ignore[host-loop] parity oracle: the per-row pass the array one replaced
-    def _lease_pass_rows(self, live, flags, vals_np, pos_sum, tick_fed,
-                         lease) -> Dict[int, int]:
-        """Per-row twin of :meth:`_lease_pass`, as every completion ran
-        it before PR 29: over ``live`` tuples, one ``row_step`` a row.
-        Steps ``lease`` (the oracle hands it a copy of the lanes) and
-        returns row -> anchor for the rows that hold one; the caller
-        compares, nothing is applied."""
-        anchors: Dict[int, int] = {}
-        for node, g, si in live:
-            if node.stopped or self._meta.get(g) is None:
-                continue
-            r = node.peer.raft
-            if vals_np is not None and len(vals_np):
-                k = int(pos_sum[g])
-                if k >= 0:
-                    role = int(vals_np[k, _R_ROLE])
-                    if role != int(self._mirror[_R_ROLE, g]):
-                        if (
-                            role == int(RaftRole.LEADER)
-                            and r.check_quorum
-                        ):
-                            lease.arm(g, r.election_timeout, 0)
-                        else:
-                            lease.disarm(g)
-            a = lease.row_step(
-                g, tick_fed.get(g, 0), node.tick_count, int(flags[g])
-            )
-            if a >= 0:
-                anchors[g] = a
-        return anchors
+        armed, fresh = lease.lanes_step(gs_step, clock, fed, flags)
+        self.stats["lease_rows_armed"] += armed
+        self.stats["lease_rows_fresh"] += fresh
+        return walked
 
     def device_coordinate(self, shard_id: int, replica_id=None):
         if self._mesh is None:
@@ -1011,6 +985,9 @@ class ColocatedVectorEngine(VectorStepEngine):
         self._host_replica[g] = 0
         self._host_peers[g, :] = 0
         self._wake_slot[g] = -1
+        # before the slot can be attached again: a probe that loaded
+        # the row's age must find its token moved (hostplane.LeaseAges)
+        self._lease.disarm(g)
         self._lanes.reset_row(g, attached=False)
         self._tables_dirty = True
         if not any(
@@ -1121,6 +1098,8 @@ class ColocatedVectorEngine(VectorStepEngine):
         dest, rank = build_route_tables(
             self._host_shard, self._host_replica, self._host_peers
         )
+        # the lease counts every resident peer's clock, cut off or not
+        self._lease.set_peers(dest)
         if self._part_fn is not None:
             # cut cross-partition links by severing the device route:
             # the message is left undelivered (dest<0, counted in
@@ -1906,7 +1885,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                             if gc_t:
                                 lane.gc[g] = gc_t
                     else:
-                        _tick_bookkeeping(node, ticks + gc_t)
+                        self._clock_idle(node, g, ticks + gc_t)
                     continue
             # ---- full path ------------------------------------------
             si = node.drain_step_inputs()
@@ -1928,7 +1907,7 @@ class ColocatedVectorEngine(VectorStepEngine):
             # every static eligibility check passed: arm the fast lane
             self._meta[g].plan_ok = True
             if not plan and not self._meta[g].dirty:
-                _tick_bookkeeping(node, si.ticks + si.gc_ticks)
+                self._clock_idle(node, g, si.ticks + si.gc_ticks)
                 continue
             batch.append((node, g, si, plan))
 
@@ -2094,8 +2073,8 @@ class ColocatedVectorEngine(VectorStepEngine):
     def _bookkeeping_pass(self, live) -> None:
         """Batched tick bookkeeping for one generation's live rows —
         hoisted out of the merge loops so every row pays it exactly
-        once, BEFORE any effects merge (and AFTER _lease_pass: lease
-        window starts stamp the PRE-launch clock).  Zero-tick rows (a
+        once, BEFORE any effects merge (and AFTER _lease_pass: the
+        lease anchor is the PRE-launch clock).  Zero-tick rows (a
         launch-rate above the wall-tick cadence makes them the
         majority) skip with two attribute loads; ticked rows advance
         both clocks and take the hint-gated single-lock pending-table
@@ -2144,13 +2123,23 @@ class ColocatedVectorEngine(VectorStepEngine):
         never run (a launch that raised, a pipeline reset): the ticks
         it drained are gone from the nodes' tick lanes, and a clock
         that lost them would deliver every pending deadline late."""
-        for node, _g, si, _plan in batch:
+        for node, g, si, _plan in batch:
             if si is not None and not node.stopped:
-                _tick_bookkeeping(node, si.ticks + si.gc_ticks)
+                self._clock_idle(node, g, si.ticks + si.gc_ticks)
         if len(lane):
             self._bookkeeping_lane(
                 lane, self._skip_mask(lane.nodes, lane.gs_np)
             )
+            self._lease.idle(lane.gs_np, lane.clock_np)
+
+    def _clock_idle(self, node, g: int, ticks: int) -> None:
+        """Both clocks of a resident row advance with no completion to
+        do it (ticks that quiesce swallowed, a launch that raised): its
+        lease ages by the same ticks, as it would on the scalar path
+        (``Raft.lease_remaining_ticks`` reads the raft clock)."""
+        if ticks:
+            _tick_bookkeeping(node, ticks)
+            self._lease.idle(g, ticks)
 
     def _skip_mask(  # hostplane-hot
         self, nodes, gs, esc_seen=(), n_stepped: int = 0
@@ -2170,7 +2159,7 @@ class ColocatedVectorEngine(VectorStepEngine):
     def _clock_and_lease(self, rec, live, flags, vals_np, pos_sum,
                          esc_seen, touched, whole=None) -> None:
         """One completion's lease pass, then its tick bookkeeping (in
-        that order: window starts stamp the PRE-launch clock), over
+        that order: the lease anchor is the PRE-launch clock), over
         the stepped rows as arrays plus the few other live rows.
         ``whole`` (the parity oracle's: the live list as every
         completion built it before PR 29, one tuple a stepped row) has
@@ -2181,6 +2170,12 @@ class ColocatedVectorEngine(VectorStepEngine):
         gs, fed = rec.batch_gs, rec.fed
         nodes = [row[0] for row in batch]
         nodes += lane.nodes
+        # what each stepped row's bookkeeping is about to add
+        clock = np.empty((n_step,), np.int64)
+        clock[:n_act] = [
+            si.ticks + si.gc_ticks for _n, _g, si, _plan in batch
+        ]
+        clock[n_act:] = lane.clock_np
         stepped = np.zeros((self.capacity,), bool)
         stepped[gs] = True
         others = [
@@ -2190,16 +2185,14 @@ class ColocatedVectorEngine(VectorStepEngine):
         if others:
             nodes += [node for node, _g in others]
             gs = np.concatenate([gs, [g for _n, g in others]])
-            fed = np.concatenate([fed, np.zeros((len(others),), np.int64)])
         skip = self._skip_mask(nodes, gs, esc_seen, n_step)
         if whole is not None:
             want = self._completion_reference(
-                rec, whole, flags, vals_np, pos_sum, touched
+                rec, whole, clock, flags, vals_np, pos_sum, touched
             )
-        walked, held = self._lease_pass(
-            nodes, gs, fed, skip, flags, vals_np, pos_sum
+        self.stats["completion_rows_walked"] += self._lease_pass(
+            nodes, gs, clock, fed, n_step, skip, flags, vals_np, pos_sum
         )
-        self.stats["completion_rows_walked"] += walked
         self._bookkeeping_pass(live)
         if len(lane):
             self._bookkeeping_lane(
@@ -2208,17 +2201,11 @@ class ColocatedVectorEngine(VectorStepEngine):
         rec.clocked = True
         if whole is not None:
             lease = self._lease
-            rows = want.rows
             hostplane.check_completion_parity(
                 hostplane.CompletionTrace(
                     emitted=_emitting(live, pos_sum, touched),
-                    rows=rows, et=lease.et[rows],
-                    dev_el=lease.dev_el[rows],
-                    window_start=lease.window_start[rows],
-                    anchors=dict(zip(
-                        held.tolist(),
-                        lease.window_start[held].tolist(),
-                    )),
+                    rows=want.rows, et=lease.et, age=lease.age,
+                    own=lease.own, since=lease.since, clk=lease.clk,
                     clocks={
                         g: (node.tick_count, node.peer.raft.tick_count)
                         for node, g, _si in whole
@@ -2228,16 +2215,20 @@ class ColocatedVectorEngine(VectorStepEngine):
             )
 
     # raftlint: ignore[host-loop] parity oracle: the per-row passes worked out over the whole live list
-    def _completion_reference(self, rec, whole, flags, vals_np, pos_sum,
-                              touched) -> "hostplane.CompletionTrace":
+    def _completion_reference(self, rec, whole, clock, flags, vals_np,
+                              pos_sum, touched) -> "hostplane.CompletionTrace":
         """What the per-row lease and bookkeeping passes make of
         ``whole``: the lease pass run on a COPY of the lease lanes
-        (anchors collected, none applied) and the clocks each row ends
-        on, computed and not written."""
+        (hostplane.lease_pass_rows) and the clocks each row ends on,
+        computed and not written."""
         lease = self._lease.copy()
-        anchors = self._lease_pass_rows(
-            whole, flags, vals_np, pos_sum,
-            dict(zip(rec.batch_gs.tolist(), rec.fed.tolist())), lease,
+        meta_get = self._meta.get
+        hostplane.lease_pass_rows(
+            lease, whole,
+            dict(zip(rec.batch_gs.tolist(),
+                     zip(clock.tolist(), rec.fed.tolist()))),
+            flags, vals_np, pos_sum, self._mirror[_R_ROLE],
+            lambda node, g: node.stopped or meta_get(g) is None,
         )
         clocks = {}
         for node, g, si in whole:
@@ -2252,9 +2243,8 @@ class ColocatedVectorEngine(VectorStepEngine):
         rows = np.asarray([g for _n, g, _si in whole], np.int64)
         return hostplane.CompletionTrace(
             emitted=_emitting(whole, pos_sum, touched),
-            rows=rows, et=lease.et[rows], dev_el=lease.dev_el[rows],
-            window_start=lease.window_start[rows], anchors=anchors,
-            clocks=clocks,
+            rows=rows, et=lease.et, age=lease.age, own=lease.own,
+            since=lease.since, clk=lease.clk, clocks=clocks,
         )
 
     def _wake_alive(self) -> None:  # hostplane-hot
@@ -3122,10 +3112,10 @@ class ColocatedVectorEngine(VectorStepEngine):
                 # round, probed against the PRE-write mirror — the
                 # final _lease_pass compares against the mirror too,
                 # and this write is about to refresh it, so a mid-wave
-                # election win would otherwise never arm its
-                # CheckQuorum lease (found by
-                # test_device_lease_reads_colocated: a resident leader
-                # whose win landed inside a wave held lease 0 forever)
+                # election win would otherwise never arm its lease
+                # (found by test_device_lease_reads_colocated: a
+                # resident leader whose win landed inside a wave held
+                # lease 0 forever)
                 chg = np.nonzero(
                     w[_R_ROLE] != self._mirror[_R_ROLE, gs_ok]
                 )[0]
@@ -3139,7 +3129,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                         int(w[_R_ROLE, i]) == _ROLE_LEADER_I
                         and r2.check_quorum
                     ):
-                        self._lease.arm(g2, r2.election_timeout, 0)
+                        self._lease.arm(g2, r2.election_timeout)
                     else:
                         self._lease.disarm(g2)
                 self._mirror[:6, gs_ok] = w
@@ -3360,9 +3350,9 @@ class ColocatedVectorEngine(VectorStepEngine):
             # ms/launch at storm-tier capacities (review finding)
             sel_vals = sel_vals[:n_sum_d]
             vals_np = sel_vals
-            # lease pass BEFORE bookkeeping: lease window starts must
-            # stamp the PRE-launch clock (see _lease_pass); then ONE
-            # batched bookkeeping pass for the whole generation
+            # lease pass BEFORE bookkeeping: the lease anchor is the
+            # PRE-launch clock (see _lease_pass); then ONE batched
+            # bookkeeping pass for the whole generation
             self._clock_and_lease(
                 rec, live, flags, vals_np, pos_sum, esc_seen, touched,
                 whole,
@@ -3462,16 +3452,16 @@ class ColocatedVectorEngine(VectorStepEngine):
                 self._sel_fit_streak = 0
         else:
             self._sel_fit_streak = 0
-        # device-plane lease evidence (ROADMAP 4b): advance each batch
-        # row's CheckQuorum window mirror and anchor the scalar voting
-        # remotes when the quorum-active flag holds — BEFORE the bulk
-        # mirror write below so role transitions are still observable.
-        # The dev_ok path already ran this pass (pre-early-commit, so
-        # window starts stamp the pre-launch clock); running it again
-        # would feed tick_fed twice and halve the modeled window period.
-        # On the exact-fallback path the bookkeeping + lane passes run
-        # here instead (detail and position maps only just landed) —
-        # same order as dev_ok: lease, bookkeeping, lane commit.
+        # device-plane lease evidence: age every armed stepped row by
+        # its ticks and start the age over where the quorum-fresh flag
+        # holds (hostplane.LeaseAges) — BEFORE the bulk mirror write
+        # below so role transitions are still observable.  The dev_ok
+        # path already ran this pass (pre-early-commit, so the anchor
+        # is the pre-launch clock); running it again would age every
+        # lease twice.  On the exact-fallback path the bookkeeping +
+        # lane passes run here instead (detail and position maps only
+        # just landed) — same order as dev_ok: lease, bookkeeping, lane
+        # commit.
         if not lease_done:
             self._clock_and_lease(
                 rec, live, flags, vals_np, pos_sum, esc_seen, touched,

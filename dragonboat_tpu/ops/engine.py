@@ -59,6 +59,8 @@ from . import hostplane
 from . import kernel as K
 from . import sync as S
 from .types import (
+    ACTIVE_FRESH,
+    ACTIVE_LIVE,
     APPEND_LO_NONE,
     ROLE_LEADER as ROLE_LEADER_I,
     N_FIELDS as N_FIELDS_BUF,
@@ -66,6 +68,7 @@ from .types import (
     F_MTYPE,
     F_N_ENTRIES,
     F_QUORUM_ACTIVE,
+    F_QUORUM_FRESH,
     F_SRC_SLOT,
     F_TO,
     HOT_TYPES,
@@ -232,16 +235,20 @@ def _summarize_flags(old: DeviceState, new: DeviceState, out) -> jnp.ndarray:
         self_lane & (new.peer_id != 0) & (new.peer_kind == KIND_VOTER),
         axis=1,
     )
-    n_active = 1 + jnp.sum(
-        voters & ~self_lane & (new.active == 1), axis=1
-    ).astype(I32)
-    q_active = (
-        (new.role == ROLE_LEADER_I)
-        & (new.check_quorum == 1)
-        & self_is_voter
-        & (n_active >= quorum)
+    cq_leader = (
+        (new.role == ROLE_LEADER_I) & (new.check_quorum == 1) & self_is_voter
     )
-    f = f | jnp.where(q_active, F_QUORUM_ACTIVE, 0)
+    others = voters & ~self_lane
+    # bit 0 of the active lane: the window's liveness (sticky until the
+    # CheckQuorum sweep); bit 1: answered since the row's last tick
+    # feed (sticky until the next one) — the same count over each
+    for bit, flag in (
+        (ACTIVE_LIVE, F_QUORUM_ACTIVE), (ACTIVE_FRESH, F_QUORUM_FRESH)
+    ):
+        n = 1 + jnp.sum(
+            others & ((new.active & bit) != 0), axis=1
+        ).astype(I32)
+        f = f | jnp.where(cq_leader & (n >= quorum), flag, 0)
     return f.astype(I32)
 
 
@@ -709,7 +716,7 @@ class VectorStepEngine(IStepEngine):
         # model of each resident leader's CheckQuorum activity window,
         # anchored from the F_QUORUM_ACTIVE flag bit — see
         # hostplane.LeaseLanes and _lease_row_step
-        self._lease = hostplane.LeaseLanes(capacity)
+        self._lease = self._make_lease()
         # array-side pb.Update lanes (ISSUE 13): the last SYNCED
         # absolute scalar words per row.  A generation's effects diff
         # against these in one vectorized pass (plan_update_sync), and
@@ -1282,7 +1289,7 @@ class VectorStepEngine(IStepEngine):
                 self._lane_dbi[g] = -1
             # lease evidence lanes follow device residency (ROADMAP 4b)
             if r.role == RaftRole.LEADER and r.check_quorum:
-                self._lease.arm(g, r.election_timeout, r.election_tick)
+                self._arm_lease(g, r)
             else:
                 self._lease.disarm(g)
             self._meta[g].dirty = False
@@ -1290,6 +1297,18 @@ class VectorStepEngine(IStepEngine):
             # facts (term, log span, remotes); require a fresh full
             # plan before the fast tick lane re-engages
             self._meta[g].plan_ok = False
+
+    def _make_lease(self):
+        """The lease evidence this engine keeps for its resident
+        leaders: the window form (hostplane.LeaseLanes).  A subclass
+        that takes its evidence another way builds its own here and
+        arms it in :meth:`_arm_lease`; both are disarmed by
+        ``disarm(g)``."""
+        return hostplane.LeaseLanes(self.capacity)
+
+    def _arm_lease(self, g: int, r) -> None:
+        """Arm row ``g``, uploaded as the CheckQuorum leader ``r``."""
+        self._lease.arm(g, r.election_timeout, r.election_tick)
 
     def _materialize_rows(
         self, gs: List[int], state: Optional[DeviceState] = None
@@ -1341,7 +1360,7 @@ class VectorStepEngine(IStepEngine):
                 rm.next = n_ + base if n_ > 0 else n_
                 rm.state = RemoteState(int(sub.rstate[k, p]))
                 rm.snapshot_index = s_ + base if s_ > 0 else s_
-                rm.active = bool(sub.active[k, p])
+                rm.active = bool(sub.active[k, p] & ACTIVE_LIVE)
                 granted = int(sub.granted[k, p])
                 if granted:
                     votes[pid] = granted == 1

@@ -56,6 +56,7 @@ from .types import (
     F_ESC,
     F_NEED_SS,
     F_QUORUM_ACTIVE,
+    F_QUORUM_FRESH,
     MT_TICK,
     R_COMMIT,
     R_LAST,
@@ -132,7 +133,13 @@ class RowLanes:
 
 class LeaseLanes:
     """Host model of resident CheckQuorum leaders' activity windows —
-    the device-plane lease evidence plumbing (ROADMAP 4b).
+    the WINDOW form of the device-plane lease evidence (ROADMAP 4b),
+    kept by ``VectorStepEngine`` alone (its peers answer over a
+    transport whose time in flight the device cannot see, so it takes
+    evidence once a CheckQuorum window; the colocated engine renews
+    every launch, :class:`LeaseAges`; the scalar path anchors every
+    response at its probe's send tick: docs/GATEWAY.md "Lease-read
+    safety" states the three side by side).
 
     The device SoA tracks ``check_quorum``/``active`` per row but never
     drove the scalar remotes' ``last_resp_tick``, so lease reads on
@@ -149,8 +156,7 @@ class LeaseLanes:
     * when the flag is up mid-window, the scalar voting remotes are
       anchored at that window start (``Raft.anchor_quorum_evidence``),
       and ``quorum_responded_tick``/``lease_remaining_ticks`` work
-      unchanged — the ~0.006 ms lease read stays on the engines that
-      host the most shards.
+      unchanged.
 
     SAFETY SHAPE: an ``active`` lane proves its peer responded AFTER
     the sweep observed it cleared, so the quorum's election clocks
@@ -164,35 +170,20 @@ class LeaseLanes:
     (kernel._become_leader), and only a window that began with a real
     on-device sweep counts as evidence.
 
-    All writes run under the engine's core lock, like RowLanes.
+    All writes run under the engine's lock, like RowLanes.
     """
 
-    __slots__ = ("window_start", "dev_el", "et", "anchored")
+    __slots__ = ("window_start", "dev_el", "et")
 
     def __init__(self, capacity: int):
         self.window_start = np.full((capacity,), -1, np.int64)
         self.dev_el = np.zeros((capacity,), np.int64)
         self.et = np.zeros((capacity,), np.int64)  # 0 = disarmed
-        # the anchor tick last handed to Raft.anchor_quorum_evidence
-        # for the row (-1 = none since it was armed): lanes_step names
-        # a row for anchoring only when its anchor moved past this
-        self.anchored = np.full((capacity,), -1, np.int64)
-
-    def copy(self) -> "LeaseLanes":
-        """Lanes of their own with the same contents (the parity
-        oracle steps a copy beside the real ones)."""
-        other = LeaseLanes(0)
-        other.window_start = self.window_start.copy()
-        other.dev_el = self.dev_el.copy()
-        other.et = self.et.copy()
-        other.anchored = self.anchored.copy()
-        return other
 
     def disarm(self, g: int) -> None:
         self.et[g] = 0
         self.dev_el[g] = 0
         self.window_start[g] = -1
-        self.anchored[g] = -1
 
     def arm(self, g: int, election_timeout: int, election_tick: int) -> None:
         """Arm a row entering device residency (or winning an election
@@ -202,7 +193,6 @@ class LeaseLanes:
         self.et[g] = election_timeout
         self.dev_el[g] = election_tick
         self.window_start[g] = -1  # first window: fabricated actives
-        self.anchored[g] = -1
 
     def row_step(self, g: int, fed_ticks: int, now: int,
                  flags_word: int) -> int:
@@ -228,39 +218,300 @@ class LeaseLanes:
             return int(ws)
         return -1
 
+
+# "no anchor" in the age lanes: past any election timeout, and far
+# enough from the int64 ceiling that adding fed ticks never wraps
+LEASE_NONE = 1 << 40
+# what a row's clock jumps by when the row stops being the engine's to
+# count (LeaseAges.disarm): more than any election timeout, so every
+# lease anchored against its earlier clock is over, and 2**40 such
+# jumps fit an int64
+LEASE_GONE = 1 << 20
+
+
+class LeaseAges:
+    """The colocated engine's lease evidence, renewed EVERY launch and
+    kept as lanes: for an armed row (a resident CheckQuorum leader)
+    ``age[g]`` is the ticks since its lease anchor ON THE CLOCK OF THE
+    VOTER FURTHEST AHEAD — the row's own or a resident peer's,
+    whichever the launches have fed more — and ``LEASE_NONE`` while it
+    has no anchor.  ``Node.lease_probe`` reads ``election_timeout -
+    age[g]`` straight off the lane (one element load on the reader's
+    thread, no lock); no scalar remote is touched and Python walks a
+    row only where its role changed.
+
+    EVIDENCE.  Bit 1 of the device's ``active`` lane is set by every
+    replicate / heartbeat response and cleared for a leader where a
+    launch's tick slot is handled (kernel._tick), and
+    ``F_QUORUM_FRESH`` is up while a quorum of voter lanes (self
+    implicit) carry it: a quorum answered AFTER the row's last tick
+    feed.  The tick slot is the last slot of a launch's host region and
+    the routed regions come first (colocated._assemble_inbox), so what
+    sets the bit in the feeding launch is an answer the later rounds of
+    that launch routed back, device to device; an answer handled
+    earlier in the same round is lost to the clear, the safe side.
+    Every answer that counts was therefore given by a row RESIDENT ON
+    THIS ENGINE, whose clock this engine feeds.
+
+    ANCHOR.  The reading of every clock BEFORE that feed's launch:
+    the row's own (``since[g]`` is what its clock has added since;
+    after the completion's bookkeeping adds the feed's ``t`` ticks the
+    anchor is exactly ``t`` old) and each resident peer's
+    (``mark[g, p]`` is ``clk`` of the peer's row at that reading).
+    They are kept until the next feed: a tick launch with nothing else
+    to do is a single round, and the answers to its heartbeat arrive
+    in the NEXT launch, which may feed the row no tick — the flag then
+    anchors it at the feed it still belongs to (``own = since``,
+    ``base = mark``).
+
+    AGE.  ``clk[r]`` counts every tick the engine has added to row
+    ``r``'s clock.  After every completion, and whenever a row leaves
+    the device, ``age[g] = max(own[g], max_p(clk[peers[g, p]] -
+    base[g, p]))`` for every anchored row of the engine, stepped or
+    not.  No quorum: it grows with whichever clock runs fastest, and
+    the lease is over once ANY voter it may rest on has been fed
+    ``election_timeout`` ticks since the anchor.
+
+    SAFETY SHAPE: a response handled after the clock reading that
+    becomes the anchor.  The responder reset its election clock when
+    it handled the probe it answers, in a round of the feeding launch
+    ``N`` or later — at the earliest in ``N``'s first round, BEFORE
+    its own tick slot (routed first) — so since its reset its clock
+    has been fed only ticks of launches ``>= N``, every one of which
+    ``clk`` counts from ``mark`` on, and it refuses every vote until
+    that is ``election_timeout`` of them (Raft._in_lease, kernel's
+    in_lease).  The lease is measured on the responder's own clock, so
+    it needs NO assumption about how evenly rows are stepped or how a
+    ticker keeps time: a leader whose row is starved, or whose ticker
+    stands still, loses its lease by its peers' clocks.  A completion
+    counts a launch's ticks before it hands anything of that launch on
+    (updates, messages, commits to apply), completions run in launch
+    order, and nothing a client can see of a later term leaves the
+    engine but through the completion of the launch that elected its
+    leader or a later one: by then this lane says the lease is over.
+    A row that leaves the device (evicted to the scalar path,
+    released, halted) or changes role is ``disarm``ed, which jumps its
+    ``clk`` past any lease: what the scalar path feeds it the engine
+    cannot count.  A row whose resident peers change (``set_peers``)
+    starts over.  Routed delivery takes exactly one round and is never
+    queued.  What is NOT bounded is a response that came through the
+    HOST inbox (a peer row on the host path, a route over budget): it
+    sat in a transport queue for a time the device cannot see.  It is
+    never counted: in a launch that feeds the row a tick every host
+    slot precedes the tick slot, so the clear takes it; a row stepped
+    with host input and NO tick has ``since`` set to ``LEASE_NONE``
+    until its next feed, and a leader whose peers answer through the
+    host reads through ReadIndex.  docs/GATEWAY.md "Lease-read safety"
+    and docs/PARITY.md carry the argument in full.
+
+    A FRESH LEADER has no bit 1 (``_become_leader`` fabricates bit 0
+    only), so its first anchor is a real quorum of answers.
+
+    THE PROBE'S RACE.  ``arm`` hands the row's node a cell ``(lanes, g,
+    token)``; ``disarm`` bumps ``token[g]``, clears ``age[g]`` and
+    takes the cell back, and every release of a row disarms it before
+    the slot can be attached again (all under the core lock).  The
+    probe loads ``age[g]`` FIRST and ``token[g]`` SECOND: a token that
+    still matches was not yet bumped when it was loaded, so the age
+    loaded before it was written while the row was this node's; any
+    age written for a later owner follows a bump, and is refused.
+
+    All writes run under the engine's core lock, like RowLanes.
+    """
+
+    __slots__ = ("et", "age", "own", "since", "clk", "peers", "mark",
+                 "base", "token", "holder", "node_of")
+
+    def __init__(self, capacity: int, P: int, node_of=None):
+        self.et = np.zeros((capacity,), np.int64)  # 0 = disarmed
+        # what the probe reads: ticks since the anchor on the clock of
+        # the voter furthest ahead
+        self.age = np.full((capacity,), LEASE_NONE, np.int64)
+        # the row's own clock since its anchor ...
+        self.own = np.full((capacity,), LEASE_NONE, np.int64)
+        # ... and since the reading BEFORE its last tick feed
+        # (LEASE_NONE: no feed that evidence may be anchored at)
+        self.since = np.full((capacity,), LEASE_NONE, np.int64)
+        # every tick the engine has added to each row's clock; one
+        # more slot, never moved, stands for "no such row"
+        self.clk = np.zeros((capacity + 1,), np.int64)
+        # each row's peers resident on this engine, as rows (capacity:
+        # none), whatever a partition cuts: set_peers
+        self.peers = np.full((capacity, P), capacity, np.int64)
+        # the peers' clk at the reading before the row's last tick
+        # feed, and at its anchor
+        self.mark = np.zeros((capacity, P), np.int64)
+        self.base = np.zeros((capacity, P), np.int64)
+        self.token = np.zeros((capacity,), np.int64)
+        # the node holding each armed row's cell, and how to find a
+        # row's node when it is armed
+        self.holder: List = [None] * capacity
+        self.node_of = node_of
+
+    def copy(self) -> "LeaseAges":
+        """Lanes of their own with the same contents and no readers
+        (the parity oracle steps a copy beside the real ones)."""
+        other = LeaseAges(0, 0)
+        for name in ("et", "age", "own", "since", "clk", "peers", "mark",
+                     "base", "token"):
+            setattr(other, name, getattr(self, name).copy())
+        other.holder = [None] * len(self.holder)
+        return other
+
+    def disarm(self, g: int) -> None:
+        """Row ``g`` is not, or no longer, a resident leader — and
+        whatever it is now, the engine stops vouching for its clock:
+        it leaves the device (the scalar path feeds it what the engine
+        cannot count), or its role changed.  Its ``clk`` jumps past
+        any lease, so every lease that may rest on its answer is over
+        NOW."""
+        self.token[g] += 1  # before anything a later owner may write
+        self.et[g] = 0
+        self.age[g] = self.own[g] = self.since[g] = LEASE_NONE
+        self.clk[g] += LEASE_GONE
+        self._refresh()
+        node = self.holder[g]
+        if node is not None:
+            self.holder[g] = None
+            node.lease_cell = None
+
+    def arm(self, g: int, election_timeout: int) -> None:
+        """Arm a row entering device residency (or winning an election
+        on-device) as a CheckQuorum leader, with no anchor yet, and
+        hand its node the cell."""
+        self.disarm(g)
+        self.et[g] = election_timeout
+        if self.node_of is not None:
+            node = self.node_of(g)
+            self.holder[g] = node
+            node.lease_cell = (self, g, int(self.token[g]))
+
+    def set_peers(self, dest: np.ndarray) -> None:
+        """``dest[g, p]``: the row of ``g``'s peer ``p`` on this engine,
+        -1 where it has none (the route table BEFORE any partition is
+        cut into it: a peer that is cut off is fed all the same).  A
+        row whose peers changed starts over: its marks were read
+        against the rows it had."""
+        new = np.where(dest >= 0, dest, len(self.et)).astype(np.int64)
+        changed = np.nonzero((new != self.peers).any(axis=1))[0]
+        self.peers = new
+        self.age[changed] = self.own[changed] = LEASE_NONE
+        self.since[changed] = LEASE_NONE
+
+    def _refresh(self) -> None:  # hostplane-hot
+        """``age`` of every anchored row from the clocks as they
+        stand: its own since the anchor, or a resident peer's if that
+        has been fed more."""
+        rows = np.nonzero(self.own < LEASE_NONE)[0]
+        if len(rows):
+            ahead = (self.clk[self.peers[rows]] - self.base[rows]).max(
+                axis=1, initial=0
+            )
+            self.age[rows] = np.minimum(
+                np.maximum(self.own[rows], ahead), LEASE_NONE
+            )
+
+    def idle(self, gs, clock) -> None:  # hostplane-hot
+        """Rows (an index array, or one row id) whose clocks advance by
+        ``clock`` ticks OUTSIDE a completion — ticks quiesce swallowed,
+        a launch that raised: their leases age with their clocks (and
+        those of the leaders they answer to at the next completion: no
+        device clock moved)."""
+        # (a disarmed row holds LEASE_NONE in all three, and stays there)
+        self.age[gs] = np.minimum(self.age[gs] + clock, LEASE_NONE)
+        self.own[gs] = np.minimum(self.own[gs] + clock, LEASE_NONE)
+        self.since[gs] = np.minimum(self.since[gs] + clock, LEASE_NONE)
+        self.clk[gs] += clock
+
     def lanes_step(  # hostplane-hot
-        self, gs: np.ndarray, fed: np.ndarray, flags: np.ndarray
-    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """:meth:`row_step` over a whole launch at once: ``gs`` are the
-        rows of one completion (distinct), ``fed`` the ticks each was
-        fed, ``flags`` the round's ``[G]`` flags word.  Advances
-        ``dev_el`` for every armed row and returns positions into
-        ``gs``: the rows whose window CROSSED (the caller stamps
-        ``window_start`` from each such row's own pre-launch clock, the
-        one per-row fact the lanes do not hold), the rows that hold an
-        anchor this launch (``row_step`` >= 0), and of those the rows
-        whose anchor MOVED since it was last applied — the only ones
-        ``Raft.anchor_quorum_evidence`` has anything to do for: it is a
-        monotone max over the remotes' ``last_resp_tick``, so applying
-        an anchor again changes nothing, and not applying it can only
-        leave the lease shorter.  ``anchored`` is written here for the
-        moved rows: the caller applies exactly those."""
-        et = self.et[gs]
-        armed = et > 0
-        el = self.dev_el[gs] + fed
-        crossed = armed & (el >= et)
-        self.dev_el[gs] = np.where(
-            armed, np.where(crossed, 0, el), self.dev_el[gs]
+        self, gs: np.ndarray, clock: np.ndarray, fed: np.ndarray,
+        flags: np.ndarray,
+    ) -> Tuple[int, int]:
+        """One completion's pass: ``gs`` the rows it stepped (distinct),
+        ``clock`` the clock ticks each one's bookkeeping is about to
+        add, ``fed`` the ticks its device row was fed, ``flags`` the
+        final round's ``[G]`` word.  Reads the peers' clocks for every
+        armed row fed a tick BEFORE any clock of this launch moves,
+        moves the clocks, anchors every armed row of the engine whose
+        flag is up at the feed it belongs to, and works out every
+        anchored row's age afresh.  Returns the armed rows stepped with
+        ticks and how many of them were anchored (``lease_rows_armed``,
+        ``lease_rows_fresh``).  :func:`lease_rows_step` is the per-row
+        twin."""
+        ticked = (self.et[gs] > 0) & (fed > 0)
+        tk = gs[ticked]
+        self.mark[tk] = self.clk[self.peers[tk]]
+        # (a disarmed row holds LEASE_NONE, and stays there)
+        self.own[gs] = np.minimum(self.own[gs] + clock, LEASE_NONE)
+        # a row stepped WITHOUT a tick took host slots with no clear
+        # behind them: nothing may be anchored until its next feed
+        self.since[gs] = np.where(ticked, clock, LEASE_NONE)
+        self.clk[gs] += clock
+        fresh = (flags & F_QUORUM_FRESH) != 0
+        hit = np.nonzero(fresh & (self.since < self.own))[0]
+        self.own[hit] = self.since[hit]
+        self.base[hit] = self.mark[hit]
+        self._refresh()
+        return (
+            int(np.count_nonzero(ticked)),
+            int(np.count_nonzero(ticked & fresh[gs])),
         )
-        ws = self.window_start[gs]
-        held = (
-            armed & ~crossed & (ws >= 0)
-            & ((flags[gs] & F_QUORUM_ACTIVE) != 0)
+
+
+def lease_rows_step(lease: LeaseAges, stepped: Dict[int, Tuple[int, int]],
+                    flags) -> None:
+    """Per-row twin of :meth:`LeaseAges.lanes_step`, a row at a time:
+    ``stepped`` is row -> (clock ticks, ticks fed) for the rows the
+    completion stepped."""
+    P = lease.peers.shape[1]
+    for g, (clock, fed) in stepped.items():
+        if lease.et[g] > 0 and fed > 0:
+            for p in range(P):
+                lease.mark[g, p] = lease.clk[lease.peers[g, p]]
+    for g, (clock, fed) in stepped.items():
+        lease.own[g] = min(int(lease.own[g]) + clock, LEASE_NONE)
+        lease.since[g] = (
+            clock if lease.et[g] > 0 and fed > 0 else LEASE_NONE
         )
-        moved = held & (ws != self.anchored[gs])
-        at = np.nonzero(moved)[0]
-        self.anchored[gs[at]] = ws[at]
-        return np.nonzero(crossed)[0], np.nonzero(held)[0], at
+        lease.clk[g] += clock
+    for g in range(len(lease.et)):
+        if int(flags[g]) & F_QUORUM_FRESH and lease.since[g] < lease.own[g]:
+            lease.own[g] = lease.since[g]
+            lease.base[g] = lease.mark[g]
+        if lease.own[g] < LEASE_NONE:
+            ahead = max(
+                [0] + [int(lease.clk[lease.peers[g, p]] - lease.base[g, p])
+                       for p in range(P)]
+            )
+            lease.age[g] = min(max(int(lease.own[g]), ahead), LEASE_NONE)
+
+
+def lease_pass_rows(lease: LeaseAges, whole, stepped, flags, vals_np,
+                    pos_sum, mirror_role, gone) -> None:
+    """Per-row twin of the colocated completion's lease pass, over
+    ``whole`` (one ``(node, g, si)`` a stepped or live row) on a COPY
+    of the lanes: a row whose role left or reached leader against
+    ``mirror_role`` is disarmed or armed, then :func:`lease_rows_step`
+    runs over the rows of ``stepped`` (row -> (clock ticks, ticks
+    fed)) that ``whole`` still holds.  ``gone(node, g)`` says a row is
+    stopped or detached since the launch."""
+    kept = {}
+    for node, g, _si in whole:
+        if gone(node, g):
+            continue
+        if vals_np is not None and len(vals_np):
+            k = int(pos_sum[g])
+            if k >= 0:
+                role = int(vals_np[k, R_ROLE])
+                if role != int(mirror_role[g]):
+                    r = node.peer.raft
+                    if role == ROLE_LEADER and r.check_quorum:
+                        lease.arm(g, r.election_timeout)
+                    else:
+                        lease.disarm(g)
+        if g in stepped:
+            kept[g] = stepped[g]
+    lease_rows_step(lease, kept, flags)
 
 
 class UpdateLanes:
@@ -903,26 +1154,26 @@ class CompletionTrace(NamedTuple):
 
     ``emitted`` is the set of rows the completion can emit anything
     for (a live row with values, or one an earlier round touched: the
-    array side walks no others); ``rows`` the rows the three lease
-    lanes are compared over, ``anchors`` row -> anchor tick for every
-    row that HOLDS an anchor this launch (the array side applies only
-    the ones that moved; the value a row is anchored at is what is
-    compared), ``clocks`` row -> (node clock, raft clock) after the
-    bookkeeping."""
+    array side walks no others); ``rows`` the rows the completion
+    stepped or found live; ``et``, ``age``, ``own``, ``since`` and
+    ``clk`` the lease lanes (:class:`LeaseAges`) over every row of the
+    engine after the pass; ``clocks`` row -> (node clock, raft clock)
+    after the bookkeeping."""
 
     emitted: frozenset = frozenset()
     rows: np.ndarray = _NO_ROWS
     et: np.ndarray = _NO_ROWS
-    dev_el: np.ndarray = _NO_ROWS
-    window_start: np.ndarray = _NO_ROWS
-    anchors: Dict[int, int] = {}
+    age: np.ndarray = _NO_ROWS
+    own: np.ndarray = _NO_ROWS
+    since: np.ndarray = _NO_ROWS
+    clk: np.ndarray = _NO_ROWS
     clocks: Dict[int, Tuple[int, int]] = {}
 
 
 def assert_completion_parity(got: CompletionTrace,
                              want: CompletionTrace) -> None:
     """The array passes' trace against the per-row passes': the same
-    rows emitted, the same lease lanes, anchors and clocks.  Raises
+    rows emitted, the same lease lanes and clocks.  Raises
     :class:`HostPlaneParityError` naming the first that differs."""
     if got.emitted != want.emitted:
         raise HostPlaneParityError(
@@ -930,25 +1181,21 @@ def assert_completion_parity(got: CompletionTrace,
         )
     if not np.array_equal(got.rows, want.rows):
         raise HostPlaneParityError(_diff("lease rows", got.rows, want.rows))
-    for name in ("et", "dev_el", "window_start"):
-        a, b = getattr(got, name), getattr(want, name)
+    for name in ("et", "age", "own", "since", "clk"):
+        a, b = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
         if not np.array_equal(a, b):
-            bad = np.nonzero(np.asarray(a) != np.asarray(b))[0][:8]
+            bad = np.nonzero(a != b)[0][:8]
             raise HostPlaneParityError(
-                f"lease {name}: rows {got.rows[bad].tolist()} array "
-                f"{np.asarray(a)[bad].tolist()} != per-row "
-                f"{np.asarray(b)[bad].tolist()}"
+                f"lease {name}: rows {bad.tolist()} array "
+                f"{a[bad].tolist()} != per-row {b[bad].tolist()}"
             )
-    for name in ("anchors", "clocks"):
-        a, b = getattr(got, name), getattr(want, name)
-        if a != b:
-            bad = sorted(
-                g for g in set(a) | set(b) if a.get(g) != b.get(g)
-            )[:8]
-            raise HostPlaneParityError(
-                f"{name}: rows {bad} array {[a.get(g) for g in bad]} "
-                f"!= per-row {[b.get(g) for g in bad]}"
-            )
+    a, b = got.clocks, want.clocks
+    if a != b:
+        bad = sorted(g for g in set(a) | set(b) if a.get(g) != b.get(g))[:8]
+        raise HostPlaneParityError(
+            f"clocks: rows {bad} array {[a.get(g) for g in bad]} "
+            f"!= per-row {[b.get(g) for g in bad]}"
+        )
 
 
 def check_completion_parity(got: CompletionTrace,

@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from .types import (
+    ACTIVE_FRESH,
+    ACTIVE_LIVE,
     APPEND_LO_NONE,
     DeviceOut,
     DeviceState,
@@ -650,8 +652,12 @@ def _become_leader(st, out, mask, E) -> Tuple[DeviceState, DeviceOut]:
     # full activity window for a fresh leader (oracle + etcd-raft's
     # RecentActive=true at becomeLeader): with fused ticks an election
     # window can elapse in two launches — one ack round-trip — and the
-    # first CheckQuorum against empty lanes deposed every winner
-    st = st._replace(active=_wp(mask & _valid(st), 1, st.active))
+    # first CheckQuorum against empty lanes deposed every winner.
+    # Bit 0 only: a fresh leader's first lease anchor is a real quorum
+    # of answers, never this fabricated window
+    st = st._replace(
+        active=_wp(mask & _valid(st), ACTIVE_LIVE, st.active)
+    )
     any_cc, esc = _pending_cc_scan(st, mask)
     out = out._replace(escalate=out.escalate | jnp.where(esc, ESC_WINDOW, 0))
     st = st._replace(
@@ -761,9 +767,11 @@ def _handle_election(st, out, mask, hint, E):
 def _check_quorum(st, mask) -> DeviceState:
     voters = _voters(st)
     is_self = jnp.arange(_P(st))[:, None] == st.self_slot[None, :]
-    cnt = 1 + jnp.sum(voters & ~is_self & (st.active == 1), axis=0).astype(I32)
+    live = (st.active & ACTIVE_LIVE) != 0
+    cnt = 1 + jnp.sum(voters & ~is_self & live, axis=0).astype(I32)
+    # the sweep clears bit 0 alone: bit 1 is the tick feed's to clear
     st = st._replace(
-        active=_wp(mask & voters, 0, st.active)
+        active=_wp(mask & voters, st.active & ACTIVE_FRESH, st.active)
     )
     down = mask & (cnt < _quorum(st))
     return _become_follower(st, down, st.term, 0)
@@ -792,6 +800,12 @@ def _tick(
     lead = mask & (st.role == ROLE_LEADER)
     non = mask & (st.role != ROLE_LEADER)
     # --- leader tick ---------------------------------------------------
+    # "answered since this row's ticks were fed" starts over: a launch
+    # feeds a row's ticks as ONE slot, the last of its host region, so
+    # only the answers the later rounds route back set bit 1 again
+    st = st._replace(
+        active=_wp(lead[None, :], st.active & ACTIVE_LIVE, st.active)
+    )
     el = st.election_tick + n
     hb = st.heartbeat_tick + n
     fired = el >= st.election_timeout
@@ -1045,7 +1059,9 @@ def _handle_heartbeat(st, out, msg, mask):
 def _handle_replicate_resp(st, out, msg, mask, E):
     slot, found = _slot_of(st, msg["from_id"])
     m = mask & found
-    st = st._replace(active=_set_col(st.active, slot, m, 1))
+    st = st._replace(
+        active=_set_col(st.active, slot, m, ACTIVE_LIVE | ACTIVE_FRESH)
+    )
     rs = _col(st.rstate, slot)
     match = _col(st.match, slot)
     nxt = _col(st.next_idx, slot)
@@ -1140,7 +1156,9 @@ def _handle_replicate_resp(st, out, msg, mask, E):
 def _handle_heartbeat_resp(st, out, msg, mask, E):
     slot, found = _slot_of(st, msg["from_id"])
     m = mask & found
-    st = st._replace(active=_set_col(st.active, slot, m, 1))
+    st = st._replace(
+        active=_set_col(st.active, slot, m, ACTIVE_LIVE | ACTIVE_FRESH)
+    )
     rs = _col(st.rstate, slot)
     st = st._replace(
         rstate=_set_col(st.rstate, slot, m & (rs == RS_WAIT), RS_RETRY)

@@ -231,6 +231,10 @@ def row_diff(state: DeviceState, g: int, r: Raft) -> List[str]:
             errs.append(f"{k}: device={got} oracle={int(want[k])}")
     for k in ROW_PEER:
         got = np.asarray(getattr(state, k))[g]
+        if k == "active":
+            # bit 0 is the oracle's Remote.active; bit 1 (answered
+            # since the last tick feed) has no scalar twin
+            got = got & 1
         if not np.array_equal(got, want[k]):
             errs.append(f"{k}: device={got.tolist()} oracle={want[k].tolist()}")
     # ring: compare only the in-window slice

@@ -126,6 +126,20 @@ F_PEERS_BEHIND = 32
 # Deliberately NOT in F_ANY_LIVE: it must ride the flags word for free
 # without promoting a quiet leader into the values-readback set.
 F_QUORUM_ACTIVE = 64
+# the same leader row with a quorum of voter lanes that answered SINCE
+# THE ROW'S LAST TICK FEED (bit 1 of the ``active`` lane: set with bit
+# 0 by every replicate / heartbeat response, cleared for a leader where
+# a launch's tick slot is handled — kernel._tick).  F_QUORUM_ACTIVE is
+# sticky over a CheckQuorum window; this bit is sticky from one tick
+# feed to the next, so the colocated engine renews the lease from it
+# every launch (ops/hostplane.LeaseAges).  Not in F_ANY_LIVE either.
+F_QUORUM_FRESH = 128
+# the two bits of DeviceState.active: bit 0 is the oracle's
+# ``Remote.active`` (CheckQuorum liveness, cleared by the sweep), bit 1
+# says the peer answered since this row's last tick feed.  A response
+# sets both; no field was added for it (benchmark/harness/costs.py
+# counts the state's fields).
+ACTIVE_LIVE, ACTIVE_FRESH = 1, 2
 F_ANY_LIVE = F_CHANGED | F_COUNT | F_APPEND | F_NEED_SS
 
 # per-row VALUES block layout (engine._gather_vals order) — the columns
@@ -192,7 +206,9 @@ class DeviceState(NamedTuple):
     next_idx: jnp.ndarray
     rstate: jnp.ndarray             # RS_*
     snap_index: jnp.ndarray
-    active: jnp.ndarray             # 0/1, CheckQuorum liveness
+    active: jnp.ndarray             # bit 0: CheckQuorum liveness;
+    #                                 bit 1: answered since the
+    #                                 row's last tick feed
     granted: jnp.ndarray            # votes: 0 unknown / 1 granted / 2 rejected
     # -- in-window log ring, [G, W] -------------------------------------
     ring_term: jnp.ndarray

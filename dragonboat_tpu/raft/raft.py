@@ -727,9 +727,11 @@ class Raft:
         )
 
     def anchor_quorum_evidence(self, tick: int) -> None:
-        """Device-plane lease evidence (ROADMAP 4b): the engine proved
-        a quorum of voter lanes active since ``tick`` (the device
-        CheckQuorum window start — ops/hostplane.LeaseLanes), so raise
+        """Device-plane lease evidence, window form (ROADMAP 4b;
+        ``VectorStepEngine`` alone since PR 31 — the colocated engine
+        keeps its evidence in lanes, :meth:`lease_ticks_at_age`): the
+        engine proved a quorum of voter lanes active since ``tick`` (the
+        device CheckQuorum window start — ops/hostplane.LeaseLanes), so raise
         every voting remote's ``last_resp_tick`` floor to it.  Raising
         ALL voters is exact for the lease: ``quorum_responded_tick``
         takes the quorum-th freshest, which becomes >= ``tick`` — the
@@ -783,14 +785,28 @@ class Raft:
         zeroes the lease: transfer votes (hint != 0) bypass the vote-
         refusal lease by design, so the target can be elected well
         inside the claimed window (review finding)."""
-        if not self.check_quorum or self.role != RaftRole.LEADER:
-            return 0
-        if self.leader_transfer_target != NO_NODE:
+        if not self.check_quorum:
             return 0
         base = self.quorum_responded_tick()
         if base < 0:
             return 0
-        return max(0, base + self.election_timeout - self.tick_count)
+        return self.lease_ticks_at_age(self.tick_count - base)
+
+    def lease_ticks_at_age(self, age: int) -> int:
+        """The lease left on quorum evidence ``age`` ticks of this
+        replica's clock old, whoever holds the evidence: the remotes
+        (:meth:`lease_remaining_ticks`) or the colocated engine's age
+        lane (ops/hostplane.LeaseAges).  One set of gates for both — no
+        CheckQuorum or not leader: none; a transfer in flight zeroes
+        it; a leader no longer among the voters has none."""
+        if not self.check_quorum or self.role != RaftRole.LEADER:
+            return 0
+        if self.leader_transfer_target != NO_NODE:
+            return 0
+        rid = self.replica_id
+        if rid not in self.remotes and rid not in self.witnesses:
+            return 0
+        return max(0, self.election_timeout - age)
 
     # ------------------------------------------------------------------
     # Step: the single entry point

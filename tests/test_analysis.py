@@ -612,14 +612,15 @@ def test_host_loop_real_tree_tick_lane_annotation_is_live():
 
 def test_host_loop_real_tree_completion_lane_annotations_are_live():
     """The array passes PR 29 put under the completion (the lease step
-    over a whole launch, the lane's columns, the rows a completion
+    over a whole launch — since PR 31 the launch form's,
+    ``LeaseAges.lanes_step`` — the lane's columns, the rows a completion
     leaves alone, the wake) carry the # hostplane-hot marker; a per-row
     loop seeded into each must surface, so the ~2,000-3,300 tick-only
     rows a launch they keep out of Python cannot grow back in."""
     seeds = {
         "dragonboat_tpu/ops/hostplane.py": [
             ("    def lanes_step(  # hostplane-hot",
-             "        et = self.et[gs]\n",
+             "        ticked = (self.et[gs] > 0) & (fed > 0)\n",
              "        for g in gs:\n            pass\n"),
             ("    def seal(self) -> \"TickLane\":  # hostplane-hot",
              "        self.gs_np = np.asarray(self.gs, np.int64)\n",
@@ -627,7 +628,7 @@ def test_host_loop_real_tree_completion_lane_annotations_are_live():
         ],
         "dragonboat_tpu/ops/colocated.py": [
             ("    def _lease_pass(  # hostplane-hot",
-             "        lease = self._lease\n        if skip is not None:\n",
+             "        lease = self._lease\n        gs_step = gs[:n_step]\n",
              "        for node in nodes:\n            pass\n"),
             ("    def _skip_mask(  # hostplane-hot",
              "        skip = ~self._lanes.attached[gs]\n",
@@ -645,13 +646,14 @@ def test_host_loop_real_tree_completion_lane_annotations_are_live():
             assert src.count(needle) == 1, needle
             fs = lint_source(src.replace(needle, junk + needle, 1), rel)
             assert [f.rule for f in fs] == ["host-loop"], (def_line, fs)
-        # the documented residue loops are exempt by their point
-        # ignores, and only by them
+        # the documented residue loop (the rows whose role changed: the
+        # window form's two others went with it, PR 31) is exempt by
+        # its point ignore, and only by it
         stripped = src.replace(
             "# raftlint: ignore[host-loop] residue:", "# stripped:")
         if stripped != src:
             fs = lint_source(stripped, rel)
-            assert len(fs) == 3 and {f.rule for f in fs} == {"host-loop"}
+            assert len(fs) == 1 and {f.rule for f in fs} == {"host-loop"}
 
 
 def test_host_loop_lane_scalar_oracle_ignore_is_live():

@@ -365,12 +365,14 @@ def test_anchor_quorum_evidence():
 
 def test_device_lease_reads_colocated():
     """ROADMAP 4b end to end: a device-RESIDENT CheckQuorum leader
-    holds a positive, window-bounded lease (the F_QUORUM_ACTIVE flag ->
-    LeaseLanes -> anchor_quorum_evidence plumbing), so gateway lease
-    reads stay on device-hosted shards instead of falling back to
-    ReadIndex.  Also pins the clock-lockstep invariant: the device tick
-    tail advances the scalar raft's logical clock (a frozen r.tick_count
-    overstated the lease by the whole residency)."""
+    holds a positive, window-bounded lease (the F_QUORUM_FRESH flag ->
+    hostplane.LeaseAges -> Node.lease_probe, renewed every launch since
+    PR 31), so gateway lease reads stay on device-hosted shards instead
+    of falling back to ReadIndex — and holds it CONTINUOUSLY, which the
+    window form (one anchor a CheckQuorum window) never could.  Also
+    pins the clock-lockstep invariant: the device tick tail advances
+    the scalar raft's logical clock (a frozen r.tick_count overstated
+    the lease by the whole residency)."""
     import shutil
     import time
 
@@ -434,6 +436,19 @@ def test_device_lease_reads_colocated():
         assert group.core._row_of.get((1, leader)) is not None, (
             "leader row left the device"
         )
+        # once held, held: a healthy leader's lease is renewed by every
+        # launch a quorum answers in, so it never saw-tooths to the
+        # margin between two CheckQuorum sweeps
+        samples = []
+        for _ in range(200):
+            samples.append(node.lease_remaining_ticks())
+            time.sleep(0.005)
+        assert sum(lt > 2 for lt in samples) >= 190, sorted(samples)[:20]
+        # the evidence is the engine's: the node holds its row's cell,
+        # and no scalar remote was anchored to get here
+        assert node.lease_cell is not None
+        assert node.lease_cell[0] is group.core._lease
+        assert group.core.stats["lease_rows_armed"] > 0
         # ONE lease pass per merged generation: the dev_ok merge path
         # once ran _lease_pass twice (review finding), feeding tick_fed
         # twice and halving the modeled CheckQuorum window period

@@ -404,12 +404,15 @@ def test_the_wake_reaches_the_shards_the_per_row_loop_reached(cluster):
 
 
 # -- (e) the oracle's own comparisons ------------------------------------
+NONE = hostplane.LEASE_NONE
+
+
 def _trace(**over):
     rows = np.asarray([3, 5, 9], np.int64)
     base = dict(
         emitted=frozenset({3, 9}), rows=rows,
-        et=np.asarray([20, 0, 20]), dev_el=np.asarray([4, 0, 0]),
-        window_start=np.asarray([100, -1, 131]), anchors={3: 100},
+        et=np.asarray([20, 0, 20]), age=np.asarray([4, NONE, 9]),
+        since=np.asarray([4, NONE, 3]),
         clocks={3: (140, 140), 5: (139, 139), 9: (131, 131)})
     base.update(over)
     return hostplane.CompletionTrace(**base)
@@ -419,10 +422,10 @@ def _trace(**over):
     ("emitted", frozenset({3}), "rows emitted"),
     ("rows", np.asarray([3, 5, 8], np.int64), "lease rows"),
     ("et", np.asarray([20, 20, 20]), "lease et"),
-    ("dev_el", np.asarray([4, 0, 2]), "lease dev_el"),
-    ("window_start", np.asarray([100, -1, 130]), "lease window_start"),
-    ("anchors", {3: 100, 9: 131}, "anchors"),
-    ("anchors", {3: 99}, "anchors"),
+    ("age", np.asarray([4, NONE, 3]), "lease age"),
+    ("age", np.asarray([NONE, NONE, 9]), "lease age"),
+    ("since", np.asarray([4, NONE, NONE]), "lease since"),
+    ("since", np.asarray([5, NONE, 3]), "lease since"),
     ("clocks", {3: (140, 139), 5: (139, 139), 9: (131, 131)}, "clocks"),
 ])
 def test_the_completion_oracle_names_what_differs(field, value, names):
@@ -436,43 +439,170 @@ def test_the_completion_oracle_names_what_differs(field, value, names):
     hostplane.PARITY_FAILURES.clear()
 
 
-def test_the_array_lease_step_is_row_step_over_every_row():
-    """``LeaseLanes.lanes_step`` against ``row_step`` a row, over
-    seeded launches of 64 rows: the same ``dev_el``, the same rows
-    crossing, the same anchors held; and a row is named for anchoring
-    once a window, when its anchor moved."""
-    from dragonboat_tpu.ops.types import F_QUORUM_ACTIVE
+def _groups_of_four(G):
+    """A route table for ``G`` rows in shards of four resident
+    replicas: every row's peers are its shard's rows, itself among
+    them, as ``build_route_tables`` lays a colocated shard out."""
+    rows = np.arange(G)
+    return (rows[:, None] // 4) * 4 + np.arange(4)[None, :]
 
-    rng = np.random.default_rng(29)
+
+def test_the_array_lease_step_is_row_step_over_every_row():
+    """``LeaseAges.lanes_step`` against ``lease_rows_step`` a row, over
+    seeded launches of 64 rows in shards of four: the same lanes on
+    every row of the engine, stepped or not; a row fed ticks with the
+    flag up is exactly those ticks old on its own clock and as old as
+    the peer fed most in that launch on the lane the probe reads, a row
+    stepped without a tick anchors nothing until its next feed, and a
+    flag that comes up in a later launch anchors the row at the feed it
+    belongs to."""
+    from dragonboat_tpu.ops.types import F_QUORUM_ACTIVE, F_QUORUM_FRESH
+
+    rng = np.random.default_rng(31)
     G = 64
-    a, b = hostplane.LeaseLanes(G), hostplane.LeaseLanes(G)
-    for g in range(0, G, 2):
-        for lanes in (a, b):
-            lanes.arm(g, 20, int(g % 7))
-    clock = np.zeros((G,), np.int64)
-    applied = {}
-    n_moved = n_held = 0
+    a, b = hostplane.LeaseAges(G, 4), hostplane.LeaseAges(G, 4)
+    peers = _groups_of_four(G)
+    for lanes in (a, b):
+        lanes.set_peers(peers)
+        for g in range(0, G, 2):
+            lanes.arm(g, 20)
+    n_armed = n_fresh = n_late = n_ahead = 0
     for launch in range(200):
         gs = np.sort(rng.choice(G, size=40, replace=False)).astype(np.int64)
         fed = rng.integers(0, 4, size=40).astype(np.int64)
-        flags = np.where(rng.random(G) < 0.7, F_QUORUM_ACTIVE, 0).astype(
-            np.int32)
-        want = {}
-        for g, n in zip(gs.tolist(), fed.tolist()):
-            an = b.row_step(g, n, int(clock[g]), int(flags[g]))
-            if an >= 0:
-                want[g] = an
-        crossed, held, moved = a.lanes_step(gs, fed, flags)
-        a.window_start[gs[crossed]] = clock[gs[crossed]]
-        assert np.array_equal(a.dev_el, b.dev_el)
-        assert np.array_equal(a.window_start, b.window_start)
-        assert dict(zip(gs[held].tolist(),
-                        a.window_start[gs[held]].tolist())) == want
-        for g in gs[moved].tolist():
-            assert applied.get(g) != int(a.window_start[g])
-            applied[g] = int(a.window_start[g])
-        assert all(applied[g] == t for g, t in want.items())
-        n_moved += len(moved)
-        n_held += len(held)
-        clock[gs] += fed
-    assert 0 < n_moved < n_held / 3
+        clock = fed + (rng.random(40) < 0.1)  # a dropped tick now and then
+        # the window bit rides the same word and anchors nothing here
+        flags = (
+            np.where(rng.random(G) < 0.6, F_QUORUM_FRESH, 0)
+            | np.where(rng.random(G) < 0.5, F_QUORUM_ACTIVE, 0)
+        ).astype(np.int32)
+        before = a.own.copy()
+        hostplane.lease_rows_step(
+            b, dict(zip(gs.tolist(), zip(clock.tolist(), fed.tolist()))),
+            flags)
+        armed, fresh = a.lanes_step(gs, clock, fed, flags)
+        for name in ("age", "own", "since", "clk", "mark", "base"):
+            assert np.array_equal(getattr(a, name), getattr(b, name)), name
+        up = (flags & F_QUORUM_FRESH) != 0
+        ticked = (a.et[gs] > 0) & (fed > 0)
+        assert armed == ticked.sum() and fresh == (ticked & up[gs]).sum()
+        # fed with the flag up: the anchor is the clock before the feed
+        # -- the row's own, and every peer's: the lane reads what the
+        # peer fed most has been fed since
+        hit = gs[ticked & up[gs]]
+        assert np.array_equal(a.own[hit], clock[ticked & up[gs]])
+        moved = np.zeros((G,), np.int64)
+        moved[gs] = clock
+        assert np.array_equal(a.age[hit], moved[peers[hit]].max(axis=1))
+        n_ahead += int((a.age[hit] > a.own[hit]).sum())
+        # never younger than on the row's own clock
+        anchored = a.own < NONE
+        assert (a.age[anchored] >= a.own[anchored]).all()
+        assert (a.age[~anchored] == NONE).all()
+        # stepped with no tick: nothing to anchor at, whatever the flag
+        dry = gs[(a.et[gs] > 0) & (fed == 0)]
+        assert (a.since[dry] == NONE).all()
+        assert (a.own[dry] >= np.minimum(before[dry], NONE)).all()
+        # unarmed rows hold nothing
+        assert (a.age[1::2] == NONE).all() and (a.since[1::2] == NONE).all()
+        rest = np.setdiff1d(np.arange(0, G, 2), gs)
+        late = rest[up[rest] & (a.own[rest] < before[rest])]
+        assert np.array_equal(a.own[late], a.since[late])
+        n_armed += armed
+        n_fresh += fresh
+        n_late += len(late)
+    assert 0 < n_fresh < n_armed and n_late > 0 and n_ahead > 0
+    # a disarm forgets the row; its token moved
+    tok = int(a.token[2])
+    a.disarm(2)
+    assert a.age[2] == NONE and a.et[2] == 0 and a.token[2] == tok + 1
+    # idle ticks (quiesce, a launch that raised) age a lease, never renew
+    a.arm(4, 20)
+    a.lanes_step(np.asarray([4]), np.asarray([3]), np.asarray([3]),
+                 np.full((G,), F_QUORUM_FRESH, np.int32))
+    assert a.age[4] == 3
+    a.idle(4, 5)
+    assert a.age[4] == 8 and a.since[4] == 8
+    a.idle(np.asarray([4, 5]), np.asarray([2, 2]))
+    assert a.age[4] == 10 and a.age[5] == NONE
+
+
+NO_FLAGS = np.zeros((8,), np.int32)
+
+
+def _anchored_leader():
+    """Row 0 of a shard of four (rows 0-3), armed and anchored by a
+    launch that fed it 3 ticks and its peers 2."""
+    from dragonboat_tpu.ops.types import F_QUORUM_FRESH
+
+    lanes = hostplane.LeaseAges(8, 4)
+    lanes.set_peers(_groups_of_four(8))
+    lanes.arm(0, 20)
+    lanes.lanes_step(
+        np.arange(4), np.asarray([3, 2, 2, 2]), np.asarray([3, 2, 2, 2]),
+        np.where(np.arange(8) == 0, F_QUORUM_FRESH, 0).astype(np.int32))
+    assert lanes.own[0] == 3 and lanes.age[0] == 3
+    return lanes
+
+
+@pytest.mark.parametrize("starved", ["row", "ticker"])
+def test_a_leader_that_is_not_stepped_ages_by_its_peers_clocks(starved):
+    """The review's case (PR 31): the cut-off leader's row is stepped
+    late, or its ticker stands still, while the launches go on feeding
+    its peers.  Its own clock stands; the lane the probe reads goes by
+    the peer fed most and passes the lease's end with it, before that
+    peer may grant a vote."""
+    lanes = _anchored_leader()
+    for n in range(1, 9):
+        if starved == "row":
+            # the others' launches, the leader in none of them
+            gs, t = np.asarray([1, 2]), np.asarray([2, 1])
+        else:
+            # stepped with host input, its ticker silent: no tick fed
+            gs, t = np.asarray([0, 1, 2]), np.asarray([0, 2, 1])
+        lanes.lanes_step(gs, t, t, NO_FLAGS)
+        assert lanes.own[0] == 3
+        assert lanes.age[0] == 2 + 2 * n  # row 1: 2 in the anchor's launch
+    assert lanes.age[0] == 18  # ET - margin: no lease read from here on
+    # another shard's clocks are nothing to it
+    lanes.lanes_step(np.asarray([5, 6]), np.asarray([9, 9]),
+                     np.asarray([9, 9]), NO_FLAGS)
+    assert lanes.age[0] == 18
+
+
+@pytest.mark.parametrize("how", ["evicted", "role", "peers", "idle"])
+def test_a_peer_the_engine_stops_counting_ends_the_lease(how):
+    """What the engine cannot count it does not vouch for: a peer that
+    leaves the device (``disarm`` at materialize / release) or changes
+    role jumps its clock past any lease AT ONCE, not at the next
+    completion; a row whose resident peers change starts over; ticks a
+    peer is given outside a completion show at the next one."""
+    from dragonboat_tpu.ops.types import F_QUORUM_FRESH
+
+    lanes = _anchored_leader()
+    if how == "evicted":
+        lanes.disarm(2)
+        assert lanes.age[0] >= hostplane.LEASE_GONE > 20
+        assert lanes.own[0] == 3  # its own clock says nothing of it
+    elif how == "role":
+        lanes.arm(1, 20)  # a peer won an election on the device
+        assert lanes.age[0] >= hostplane.LEASE_GONE
+        assert lanes.age[1] == NONE  # and has no anchor of its own yet
+    elif how == "peers":
+        table = _groups_of_four(8)
+        table[0, 3] = -1  # replica 3 left the shard
+        lanes.set_peers(table)
+        assert lanes.age[0] == NONE and lanes.own[0] == NONE
+        assert lanes.since[0] == NONE
+    else:
+        lanes.idle(np.asarray([3]), np.asarray([7]))
+        assert lanes.age[0] == 3
+        lanes.lanes_step(np.asarray([4]), np.asarray([1]), np.asarray([1]),
+                         NO_FLAGS)
+        assert lanes.age[0] == 9
+    # a later quorum of answers anchors it again, at clocks read afresh
+    gs = np.arange(4)
+    lanes.lanes_step(gs, np.full(4, 2), np.full(4, 2),
+                     np.where(np.arange(8) == 0, F_QUORUM_FRESH,
+                              0).astype(np.int32))
+    assert lanes.age[0] == 2 and lanes.own[0] == 2
