@@ -41,7 +41,7 @@ from typing import Dict, Optional
 from ..client import LatencyBudget, Session
 from ..logger import get_logger
 from ..metrics import MetricsRegistry
-from ..node import LEASE_HELD, LEASE_MISS_UNREPORTED
+from ..node import LEASE_HELD, LEASE_MISS_NOT_LEADER, LEASE_MISS_UNREPORTED
 from ..profiling import annotate
 from ..readplane import (
     BOUND_TICKS_DEFAULT,
@@ -316,6 +316,10 @@ class Gateway:
         # (slot 0, LEASE_HELD, stays 0); bumped beside _fallback_reads
         # under the same lock-free-ish convention
         self._lease_miss = [0] * (LEASE_MISS_UNREPORTED + 1)
+        # operations sent again: a proposal that came back DROPPED, a
+        # read whose routed host did not lead the group or whose
+        # ReadIndex attempt failed (same convention)
+        self._reroutes = 0
         # read-plane counters (docs/READPLANE.md): one per served path
         # plus sheds; pre-resolved so the read path never takes the
         # registry lock (counter() locks on lookup)
@@ -378,6 +382,7 @@ class Gateway:
             self.metrics.gauge(
                 "gateway_" + key, lambda k=key: self._worker_total(k)
             )
+        self.metrics.gauge("gateway_reroutes", lambda: self._reroutes)
         for i, name in enumerate(_LEASE_MISS_KEYS):
             self.metrics.gauge(
                 "gateway_read_fallback_" + name,
@@ -766,7 +771,12 @@ class Gateway:
             req.proposed = True
             acc["t_queue_wait_ms"] += (now - req.t_admit) * 1000.0
         try:
-            return nh.propose(req.handle.session, req.cmd, remaining)
+            # leader-or-nothing: a host that does not lead answers
+            # DROPPED, which the poll below sends again through a fresh
+            # route, where a forwarded proposal that the leader drops
+            # (its transfer in flight) would be told to nobody
+            return nh.propose(req.handle.session, req.cmd, remaining,
+                              forward=False)
         except Exception as e:  # noqa: BLE001 — classified below
             self.routes.invalidate(req.handle.shard_id)
             self._fail(req, e)
@@ -834,6 +844,7 @@ class Gateway:
         if retryable and req.deadline - time.monotonic() > 0.01:
             # pacing comes from the node round trip + the poll cadence
             self.routes.invalidate(req.handle.shard_id)
+            self._reroutes += 1
             return self._propose_once(req, acc)  # None => future completed
         err = _CODE_ERRORS.get(code, TimeoutError_)
         self._fail(req, err(code.name if code is not None else "unknown"))
@@ -927,20 +938,36 @@ class Gateway:
                         self._count_read(PATH_LEASE)
                         return ReadResult(val, PATH_LEASE, host=key)
                     self._lease_miss[why] += 1
+                    if why == LEASE_MISS_NOT_LEADER:
+                        self._reroutes += 1
+                        self.routes.invalidate(shard_id)
                 except Exception:  # noqa: BLE001 — host/shard stopping:
                     # fall through to the quorum path
                     self.routes.invalidate(shard_id)
-        # ReadIndex fallback, retried across hosts until the deadline
+        # ReadIndex fallback, retried across hosts until the deadline.
+        # For one election window it is leader-or-nothing, as the
+        # proposals are: it goes to a host that leads the group, and a
+        # replica that stopped leading before it stepped the request
+        # drops it (sync_read forward=False) and the loop finds the
+        # leader.  A follower would forward it, a read that is
+        # forwarded is the host path's at both ends (the kernel's
+        # ReadIndex answers its own replica only), and a leader whose
+        # row is taken out for it has no lease when it comes back, so
+        # the next reads of the group fall back too (PERF.md section 6,
+        # PR 32).  A group that shows no leader for that long is read
+        # through whoever carries it, forwarded, as before
         self._fallback_reads.add()
         self._read_event(shard_id, "lease->read_index")
         last_exc: Optional[BaseException] = None
+        leader_only = time.monotonic() + self.budget.election_window
         while True:
-            remaining = deadline - time.monotonic()
+            now = time.monotonic()
+            remaining = deadline - now
             if remaining <= 0:
                 from ..nodehost import TimeoutError_
 
                 raise last_exc or TimeoutError_("gateway read deadline")
-            nh = self._host_for(shard_id, any_ok=True)
+            nh = self._host_for(shard_id, any_ok=now >= leader_only)
             if nh is None:
                 time.sleep(0.02)
                 continue
@@ -953,12 +980,14 @@ class Gateway:
                 val = nh.sync_read(
                     shard_id, query,
                     timeout=min(remaining, self.budget.per_try_timeout()),
+                    forward=now >= leader_only,
                 )
                 self._count_read(PATH_READ_INDEX)
                 return ReadResult(val, PATH_READ_INDEX)
             except Exception as e:  # noqa: BLE001 — reads are
                 # idempotent; retry through another route
                 last_exc = e
+                self._reroutes += 1
                 self.routes.invalidate(shard_id)
                 time.sleep(0.02)
 
@@ -1155,6 +1184,7 @@ class Gateway:
             # (retries included), admit -> first propose, node's notify
             # -> the poll that saw it, pending pairs examined and passes
             **{k: self._worker_total(k) for k in _WORKER_COUNTERS},
+            "reroutes": self._reroutes,
             # per-consistency-path serve counts + the router's observed
             # per-replica p99 (the read plane's ledger row inputs)
             "read_paths": dict(self._read_paths),

@@ -89,6 +89,7 @@ from ..transport.wire import (
     RPC_OP_SESSION_CLOSE,
     RPC_OP_SESSION_OPEN,
     RPC_OP_STATS,
+    RPC_PROPOSE_NO_FORWARD,
     RPC_READ_BOUNDED,
     RPC_READ_FOLLOWER,
     RPC_READ_INDEX,
@@ -326,7 +327,10 @@ class RpcServer:
                 parent = (
                     _WireCtx(q.trace_id, q.span_id) if q.trace_id else None
                 )
-                rs = nh.propose(s, q.payload, timeout, parent=parent)
+                rs = nh.propose(
+                    s, q.payload, timeout, parent=parent,
+                    forward=not q.flags & RPC_PROPOSE_NO_FORWARD,
+                )
                 # sliced wait: a NodeHost closed mid-flight leaves its
                 # RequestStates permanently pending — detecting that
                 # here turns a full client-timeout stall into a fast
@@ -407,7 +411,8 @@ class RpcServer:
                 return RpcResponse(req_id=q.req_id, code=RPC_ERR_NO_LEASE,
                                    error="lease not held")
         elif q.flags == RPC_READ_INDEX:
-            val = nh.sync_read(q.shard_id, query, timeout=timeout)
+            val = nh.sync_read(q.shard_id, query, timeout=timeout,
+                               forward=q.arg != 1)
         elif q.flags == RPC_READ_STALE:
             val = nh.stale_read(q.shard_id, query)
         elif q.flags == RPC_READ_FOLLOWER:
@@ -894,7 +899,10 @@ class RemoteHostHandle:
 
     # -- NodeHost surface (what the Gateway multiplexes) ------------------
     def propose(self, session: Session, cmd: bytes, timeout: float,
-                parent=None) -> _RemoteCall:
+                parent=None, forward: bool = True) -> _RemoteCall:
+        # ``forward`` is NodeHost.propose's, carried in the request's
+        # flags byte: the serving host drops a leader-or-nothing proposal
+        # it does not lead, and the gateway sends it again
         if not session.is_noop():
             # per-ATTEMPT bound, not per-op: an exactly-once proposal
             # that lands on a follower right as the leader dies is
@@ -924,6 +932,7 @@ class RemoteHostHandle:
         try:
             return self._submit(
                 RPC_OP_PROPOSE, shard_id=session.shard_id, session=session,
+                flags=0 if forward else RPC_PROPOSE_NO_FORWARD,
                 timeout=timeout, payload=cmd, span=span,
             )
         except (RequestDropped, SystemBusy, OSError) as e:
@@ -965,10 +974,12 @@ class RemoteHostHandle:
         ok, value = self.try_lease_read(shard_id, query, margin_ticks)
         return (LEASE_HELD if ok else LEASE_MISS_UNREPORTED), value
 
-    def sync_read(self, shard_id: int, query, timeout: float = 5.0):
+    def sync_read(self, shard_id: int, query, timeout: float = 5.0,
+                  forward: bool = True):
         rc = self._submit(
             RPC_OP_READ, flags=RPC_READ_INDEX, shard_id=shard_id,
-            timeout=timeout, payload=encode_rpc_value(query),
+            timeout=timeout, arg=0 if forward else 1,
+            payload=encode_rpc_value(query),
         )
         result = self._finish(rc, timeout + 0.5)
         return decode_rpc_value(result.data)

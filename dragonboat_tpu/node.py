@@ -29,6 +29,7 @@ from .pb import (
     Membership,
     Message,
     MessageType,
+    NO_NODE,
     Snapshot,
     State,
     SystemCtx,
@@ -45,6 +46,8 @@ from .request import (
     PendingProposal,
     PendingReadIndex,
     PendingSnapshot,
+    HostTotals,
+    RequestResultCode,
     RequestState,
     SystemBusy,
     gc_tables,
@@ -127,9 +130,10 @@ class Node:
     __slots__ = (
         "config", "shard_id", "replica_id", "logdb", "snapshot_storage",
         "transport", "on_leader_updated", "events", "registry",
+        "host_totals",
         "_qlock", "_received", "_proposals", "_read_indexes",
         "_config_changes", "_cc_to_apply", "_snapshot_reqs",
-        "_leader_transfers", "_pending_ticks",
+        "_leader_transfers", "_doomed", "_pending_ticks",
         "_ticks_in", "_ticks_taken",
         "pending_proposal", "pending_read_index", "pending_config_change",
         "pending_snapshot", "pending_leader_transfer", "pending_tables",
@@ -157,6 +161,7 @@ class Node:
         event_listener=None,
         registry=None,
         tracer=None,
+        host_totals: Optional[HostTotals] = None,
     ):
         self.config = config
         self.shard_id = config.shard_id
@@ -177,6 +182,11 @@ class Node:
         self.on_leader_updated = on_leader_updated
         self.events = event_listener
         self.registry = registry
+        # the NodeHost's request.HOST_TOTALS; a replica built alone
+        # (tests) counts for itself
+        self.host_totals = (
+            host_totals if host_totals is not None else HostTotals()
+        )
 
         # --- queues (thread-safe inputs to step) -------------------------
         # plain lists, not deques: producers only append and the drain
@@ -191,6 +201,10 @@ class Node:
         self._cc_to_apply: list = []  # (ConfigChange|None, accepted); guarded-by: _qlock
         self._snapshot_reqs: list = []  # (key, overhead); guarded-by: _qlock
         self._leader_transfers: list = []  # target; guarded-by: _qlock
+        # (conflict index, old term there, [(table, key)]): proposals
+        # made here whose entries another leader's replaced, waiting for
+        # the commit that settles them (_note_doomed); guarded-by: _qlock
+        self._doomed: list = []
         self._pending_ticks = 0  # guarded-by: _qlock
         # single-writer tick lane: the HOST TICKER is the only writer of
         # _ticks_in and the owning step worker the only writer of
@@ -245,6 +259,7 @@ class Node:
         self.pending_leader_transfer = PendingLeaderTransfer(
             _tables_lock, key_base=key_base(),
             deadline_hint=self.pending_deadline_hint,
+            totals=self.host_totals,
         )
         self.pending_tables = (
             self.pending_proposal, self.pending_read_index,
@@ -476,7 +491,8 @@ class Node:
                     m.pop(k, None)
 
     def propose(
-        self, session: Session, cmd: bytes, timeout_ticks: int, span=None
+        self, session: Session, cmd: bytes, timeout_ticks: int, span=None,
+        forward: bool = True,
     ) -> RequestState:
         if self.peer.raft.rate_limited():
             # MaxInMemLogSize exceeded: refuse new load until the window
@@ -485,7 +501,7 @@ class Node:
             # it only shifts WHEN the busy signal flips.
             raise SystemBusy("in-memory log over MaxInMemLogSize")
         entry, rs = self.pending_proposal.propose(
-            session, cmd, self.tick_count + timeout_ticks
+            session, cmd, self.tick_count + timeout_ticks, forward
         )
         if span is not None:
             rs.span = span
@@ -516,8 +532,11 @@ class Node:
             self.pending_proposal.seal(rs)
         return rs
 
-    def read_index(self, timeout_ticks: int, span=None) -> RequestState:
-        ctx, rs = self.pending_read_index.read(self.tick_count + timeout_ticks)
+    def read_index(self, timeout_ticks: int, span=None,
+                   forward: bool = True) -> RequestState:
+        ctx, rs = self.pending_read_index.read(
+            self.tick_count + timeout_ticks, forward
+        )
         if span is not None:
             rs.span = span
             span.annotate("request:queued")
@@ -674,6 +693,26 @@ class Node:
                 self._snapshot_reqs = []
             self._pending_ticks = 0
         return si
+
+    def requeue_inputs(self, received=(), proposals=(), read_indexes=(),
+                       transfers=()) -> None:
+        """Drained inputs the step could not take go back to the HEAD
+        of their queues, in their order, ahead of whatever arrived
+        since the drain, and the step engine is told that the node
+        still has work."""
+        if not (received or proposals or read_indexes or transfers):
+            return
+        with self._qlock:
+            if received:
+                self._received[:0] = received
+            if proposals:
+                self._proposals[:0] = proposals
+            if read_indexes:
+                self._read_indexes[:0] = read_indexes
+            if transfers:
+                self._leader_transfers[:0] = transfers
+        if self.notify_work is not None:
+            self.notify_work()
 
     def drain_ticks_only(self, step_cap: int):
         """Consume ONLY the tick inputs — the lock-free ticker lane plus
@@ -947,6 +986,82 @@ class Node:
                 self.pending_proposal.dropped(e.key)
         for ctx in u.dropped_read_indexes:
             self.pending_read_index.dropped(ctx)
+        if u.truncated:
+            self._note_doomed(u.truncated)
+
+    def _note_doomed(self, records) -> None:
+        """Another leader's entries replaced a stretch of this replica's
+        uncommitted tail (raft/log.py ``InMemory._note_truncated``).
+        Proposals made HERE whose entries went are doomed, not yet dead:
+        a replica that still holds the old branch can win a later
+        election and commit it after all.  One is dead once ANOTHER
+        entry is committed at the conflict index, or at any index from
+        there up to its own — every log that held it held the old
+        entries there — and the apply worker sees that
+        (``_settle_doomed``); until then they stay pending."""
+        for index, term, entries in records:
+            keyed = []
+            for e in entries:
+                table = (
+                    self.pending_config_change
+                    if e.type == EntryType.CONFIG_CHANGE
+                    else self.pending_proposal
+                )
+                if table.has(e.key):
+                    keyed.append((e.index, e.term, table, e.key))
+            if keyed:
+                with self._qlock:
+                    self._doomed.append((index, term, keyed))
+
+    def _settle_doomed(self, applied: List[Entry]) -> None:
+        """Apply worker, after a contiguous batch of committed entries:
+        a doomed stretch whose conflict index the batch covers is
+        settled as far as the batch goes.  Another term at the conflict
+        index: none of the stretch can ever commit, and its proposals
+        are told so now and not at their deadline — DROPPED is
+        definitive, so the gateway's retry of it cannot apply a write
+        twice.  The term that stood there: the old branch came back,
+        perhaps not all of it, so each of its entries is held to the
+        entry applied at ITS index — the same term is the same entry
+        and completes as it is applied, the first that differs takes
+        the rest of the stretch with it, and what lies past the batch
+        waits, a shorter stretch, for the batch that covers it."""
+        first, last = applied[0].index, applied[-1].index
+        with self._qlock:
+            due = [d for d in self._doomed if d[0] <= last]
+            if not due:
+                return
+            self._doomed = [d for d in self._doomed if d[0] > last]
+        dead, waiting = [], []
+        for index, term, keyed in due:
+            # below the batch: a snapshot skipped the index and nothing
+            # can be said; those are left to their deadline, as before
+            if index < first:
+                continue
+            if applied[index - first].term != term:
+                dead += keyed
+                continue
+            for n, (i, t, _table, _key) in enumerate(keyed):
+                if i > last:
+                    waiting.append((i, t, keyed[n:]))
+                    break
+                if applied[i - first].term != t:
+                    dead += keyed[n:]
+                    break
+        if waiting:
+            with self._qlock:
+                self._doomed += waiting
+        n = 0
+        ts = self._trace_spans
+        for _i, _t, table, key in dead:
+            rs = table.pop(key)
+            if rs is not None:
+                if ts:
+                    ts.pop(key, None)
+                rs.notify(RequestResultCode.DROPPED)
+                n += 1
+        if n:
+            self.host_totals.add("proposals_dropped_truncated", n)
 
     def _sync_registry(self, membership: Membership) -> None:
         """Every replica (not just the API caller) must be able to resolve
@@ -1007,6 +1122,17 @@ class Node:
         lid = self.peer.leader_id()
         if lid != self.leader_id:
             self.leader_id = lid
+            if lid != self.replica_id:
+                # a replica the device steps keeps the transfer target
+                # only here, in the scalar mirror, written by the host
+                # step that took the request; the kernel clears its own
+                # copy with every change of role, and nothing reads that
+                # back.  Cleared here as Raft._reset does on the scalar
+                # path, or the replica's next term as leader would find
+                # the old target still standing and never hold a lease
+                # (Raft.lease_ticks_at_age).  Safe: a replica that does
+                # not lead has no lease whatever this field says
+                self.peer.raft.leader_transfer_target = NO_NODE
             if lid != 0:
                 self.pending_leader_transfer.notify_leader(lid)
             elif self.quiesce.enabled and (
@@ -1116,6 +1242,9 @@ class Node:
                 wait_s += t_start - task.t_handoff
                 results = self.sm.handle(task)
                 self._complete_applied(results)
+                # raftlint: ignore[guarded-by] lock-free empty probe; the settle takes _qlock
+                if self._doomed and task.entries:
+                    self._settle_doomed(task.entries)
                 self._applied_since_snapshot += len(task.entries)
             elif task.type == TaskType.SNAPSHOT_RECOVER:
                 self._recover_from_snapshot(task.snapshot)

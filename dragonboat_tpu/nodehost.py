@@ -37,6 +37,8 @@ from .readplane import (
     StaleBoundExceeded,
 )
 from .request import (
+    HOST_TOTALS,
+    HostTotals,
     RequestError,
     RequestResultCode,
     RequestState,
@@ -111,6 +113,10 @@ class NodeHost:
         # a leader nor the same one met again (always on;
         # docs/OBSERVABILITY.md "Counters")
         self.leader_changes = 0
+        # what this host's replicas count on it (request.HOST_TOTALS):
+        # leader transfers by how they ended, proposals told DROPPED
+        # because another leader's entries replaced theirs
+        self.host_totals = HostTotals()
         # shard id -> (term, leader) last met; guarded-by: _leader_lock
         self._leader_seen: Dict[int, tuple] = {}
         self._leader_lock = threading.Lock()
@@ -338,6 +344,11 @@ class NodeHost:
             self.metrics.gauge(
                 "raft_nodehost_leader_changes", lambda: self.leader_changes
             )
+            for key in HOST_TOTALS:
+                self.metrics.gauge(
+                    "raft_nodehost_" + key,
+                    lambda k=key: self.host_totals.values[k],
+                )
 
             self._ticks_paused = False
             self._ticker_stop = threading.Event()
@@ -511,6 +522,7 @@ class NodeHost:
                 event_listener=self.events,
                 registry=self.registry,
                 tracer=self.tracer,
+                host_totals=self.host_totals,
             )
             self._nodes[config.shard_id] = node
             node.wake = functools.partial(self._wake_node, node)
@@ -702,8 +714,14 @@ class NodeHost:
 
     # -- proposals --------------------------------------------------------
     def propose(
-        self, session: Session, cmd: bytes, timeout: float, parent=None
+        self, session: Session, cmd: bytes, timeout: float, parent=None,
+        forward: bool = True,
     ) -> RequestState:
+        """``forward=False``: leader-or-nothing.  A replica that does
+        not lead when it steps the proposal completes it ``DROPPED``
+        (definitive: nothing was sent anywhere) where it would have
+        forwarded it to the leader; the caller finds the leader and
+        sends it again (the gateway does)."""
         node = self._get_node(session.shard_id)
         tracer = self.tracer  # None when disabled: one attribute load
         span = None
@@ -721,7 +739,8 @@ class NodeHost:
                 span.annotate(f"client:propose bytes={len(cmd)}")
         try:
             rs = node.propose(
-                session, cmd, self._timeout_ticks(timeout), span=span
+                session, cmd, self._timeout_ticks(timeout), span=span,
+                forward=forward,
             )
         except Exception as e:
             # a rejected request (SystemBusy, closed shard, ...) must
@@ -758,14 +777,21 @@ class NodeHost:
         _check(rs.wait(timeout), rs)
 
     # -- reads ------------------------------------------------------------
-    def read_index(self, shard_id: int, timeout: float) -> RequestState:
+    def read_index(self, shard_id: int, timeout: float,
+                   forward: bool = True) -> RequestState:
+        """``forward=False``: leader-or-nothing, as :meth:`propose`'s.
+        A replica that does not lead when it steps the request
+        completes it ``DROPPED`` where it would have forwarded it to
+        the leader (``pb.CTX_NO_FORWARD``)."""
         node = self._get_node(shard_id)
         tracer = self.tracer
         span = None
         if tracer is not None:
             span = tracer.start_trace("read_index", shard_id=shard_id)
         try:
-            rs = node.read_index(self._timeout_ticks(timeout), span=span)
+            rs = node.read_index(
+                self._timeout_ticks(timeout), span=span, forward=forward
+            )
         except Exception as e:
             if span is not None:
                 span.end(status=type(e).__name__)
@@ -773,8 +799,9 @@ class NodeHost:
         self.engine.notify(shard_id)
         return rs
 
-    def sync_read(self, shard_id: int, query, timeout: float = 5.0):
-        rs = self.read_index(shard_id, timeout)
+    def sync_read(self, shard_id: int, query, timeout: float = 5.0,
+                  forward: bool = True):
+        rs = self.read_index(shard_id, timeout, forward)
         _check(rs.wait(timeout), rs)
         self._count_read("read_index")
         return self._get_node(shard_id).lookup(query)

@@ -64,7 +64,7 @@ from ..logger import get_logger
 from ..node import StepInputs
 from ..pb import Entry
 from ..raft.raft import RaftRole
-from ..request import gc_tables
+from ..request import HOST_TOTALS, gc_tables
 from . import kernel as K
 from . import sync as S
 from .engine import (
@@ -516,6 +516,12 @@ class _InFlightGen:
         # completion's bookkeeping ran, or a pipeline reset did it)
         self.clocked = False
 
+    def fed_of(self, g: int) -> int:
+        """The ticks this generation fed device row ``g``, 0 for a row
+        it did not step."""
+        at = np.flatnonzero(self.batch_gs == g)
+        return int(self.fed[at[0]]) if len(at) else 0
+
     def stepped_row(self, i: int) -> Tuple:
         """``(node, g, si)`` of position ``i`` of ``batch_gs``; a lane
         row's ``StepInputs`` is made here, for the one row asked for."""
@@ -719,6 +725,14 @@ class ColocatedVectorEngine(VectorStepEngine):
             apply_batches=0, apply_entries=0, t_apply_ms=0.0,
             t_apply_wait_ms=0.0, t_sm_update_ms=0.0, sm_wal_appends=0,
             sm_wal_bytes=0, leader_changes=0,
+            # and what the members' replicas count on their NodeHost
+            # (request.HOST_TOTALS): leader transfers asked for, ended
+            # with the target leading (and the time that took) or
+            # otherwise, proposals told DROPPED because another leader's
+            # entries replaced theirs (node.py _settle_doomed)
+            leader_transfers_requested=0, leader_transfers_done=0,
+            leader_transfers_aborted=0, t_transfer_ms=0.0,
+            proposals_dropped_truncated=0,
             # pipeline observability: host work overlapped with an
             # in-flight readback request (the double-buffering win),
             # fences (drains to depth 0 forced by membership mutation),
@@ -2199,6 +2213,8 @@ class ColocatedVectorEngine(VectorStepEngine):
                 lane, None if skip is None else skip[n_act:n_step]
             )
         rec.clocked = True
+        if self._xfer_watch:
+            self._transfer_targets_pass(batch, rec.fed_of)
         if whole is not None:
             lease = self._lease
             hostplane.check_completion_parity(
@@ -2605,9 +2621,11 @@ class ColocatedVectorEngine(VectorStepEngine):
         fed[n_act:] = lane.fed_np
         if hostplane.PARITY:
             n_reads = self.stats["device_reads"]
+            n_xfer = self.stats["device_transfers"]
             whole_batch = batch + self._lane_as_batch(lane)
             whole = self._encode_generation(whole_batch, (), ())
             self.stats["device_reads"] = n_reads
+            self.stats["device_transfers"] = n_xfer
             hostplane.check_encode_parity(
                 whole_batch, batch_gs, enc, whole, lane
             )
@@ -3691,11 +3709,11 @@ class ColocatedVectorEngine(VectorStepEngine):
 
 
 # what _ColocatedFacade.totals() counts, name -> (stats key, scale):
-# ExecEngine.APPLY_TOTALS, seconds folded in as ms, and the NodeHost's
-# leader_changes
+# ExecEngine.APPLY_TOTALS, seconds folded in as ms, the NodeHost's
+# leader_changes and its request.HOST_TOTALS
 _MEMBER_TOTALS = {
     name: (name[:-1] + "ms", 1000.0) if name.startswith("t_") else (name, 1)
-    for name in (*APPLY_TOTALS, "leader_changes")
+    for name in (*APPLY_TOTALS, "leader_changes", *HOST_TOTALS)
 }
 
 
@@ -3722,15 +3740,16 @@ class _ColocatedFacade(IStepEngine):
         self.core.step_shards(nodes, worker_id)
 
     def totals(self) -> dict:
-        """This member's ``ExecEngine.apply_totals()`` and its
-        NodeHost's ``leader_changes``, by the names of
+        """This member's ``ExecEngine.apply_totals()``, its NodeHost's
+        ``leader_changes`` and ``host_totals``, by the names of
         ``_MEMBER_TOTALS``; nothing until the NodeHost has built its
         engine (the factory runs before that)."""
         nh = self._nodehost
         engine = getattr(nh, "engine", None)
         if engine is None:
             return {}
-        return {**engine.apply_totals(), "leader_changes": nh.leader_changes}
+        return {**engine.apply_totals(), "leader_changes": nh.leader_changes,
+                **nh.host_totals.snapshot()}
 
     def stop(self) -> None:
         # the member is going away: take its last totals, then forget it

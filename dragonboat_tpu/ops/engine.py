@@ -17,10 +17,11 @@ BASELINE.json north_star).  The division of labor:
 
 Row routing per step (see `_plan_device`):
 
-  * hot inputs (ticks, hot wire messages, application proposals) →
-    encoded into the device inbox;
-  * cold inputs (config change, read index, snapshot request, leader
-    transfer, cold message types, oversized batches) → the row is
+  * hot inputs (ticks, hot wire messages, application proposals, a
+    leader's own transfer request) → encoded into the device inbox;
+  * cold inputs (config change, read index, snapshot request, a
+    transfer request asked of a replica that does not lead, cold
+    message types, oversized batches) → the row is
     **materialized** (device → scalar copy) and stepped by the scalar
     path; the row is re-uploaded when it goes hot again;
   * kernel escalation (ESC_* bits) → the row's device effects are
@@ -50,8 +51,16 @@ import numpy as np
 from ..analysis import jitcheck
 from ..engine.execengine import IStepEngine
 from ..logger import get_logger
-from ..pb import Entry, EntryType, Message, MessageType, Snapshot
-from ..raft.raft import Raft, RaftRole
+from ..pb import (
+    CTX_NO_FORWARD,
+    NO_NODE,
+    Entry,
+    EntryType,
+    Message,
+    MessageType,
+    Snapshot,
+)
+from ..raft.raft import Raft, RaftRole, forwardable
 from ..raft.remote import RemoteState
 from ..request import gc_tables
 from ..rsm.statemachine import Task, TaskType
@@ -725,6 +734,13 @@ class VectorStepEngine(IStepEngine):
         self._ulanes = hostplane.UpdateLanes(capacity)
         # lane rows classified by the last _device_step, drained by
         # step_shards AFTER the core lock releases (_persist_lane_rows)
+        # rows whose scalar mirror holds a transfer target the plan set
+        # (_plan_device "xfer"): row -> (node, ticks the device row is
+        # still to be fed before the kernel has given the transfer up;
+        # None until the launch that carries the request completes).
+        # _transfer_targets_pass clears the mirror then, as the kernel
+        # clears its own lane and tells nobody
+        self._xfer_watch: Dict[int, Tuple] = {}
         self._lane_pending: List[Tuple] = []
         # array-batched STATE-ONLY persists (no per-row tuples at all):
         # (db, slots, terms, votes, commits, live, js) per LogDB — see
@@ -796,6 +812,12 @@ class VectorStepEngine(IStepEngine):
             "divergence_halts": 0,
             "save_failures": 0,
             "device_reads": 0,
+            # leader-transfer requests planned as a slot of the leader's
+            # own row (_plan_device "xfer"); the rest go by the host path
+            "device_transfers": 0,
+            # rows whose inputs outran their M host slots: the rest were
+            # put back for the next launch (_defer_past_room)
+            "deferred_inputs": 0,
         }
         self._warm()
 
@@ -1017,12 +1039,11 @@ class VectorStepEngine(IStepEngine):
         "millions of idle groups cost nothing".  Exiting quiesce needs
         the scalar poke path (LEADER_HEARTBEAT), so that step goes host.
         """
-        if (
-            si.config_changes
-            or si.cc_results
-            or si.snapshot_reqs
-            or si.transfers
-        ):
+        if si.config_changes or si.cc_results or si.snapshot_reqs:
+            return None
+        if si.transfers and not mirror_leader:
+            # a replica that does not lead forwards the request over
+            # the wire: the scalar path's
             return None
         inj = self.fault_injector
         if (
@@ -1032,11 +1053,29 @@ class VectorStepEngine(IStepEngine):
             and inj.on_engine_step(node.shard_id, node.replica_id)
         ):
             return None  # nemesis: forced scalar excursion for this row
-        if si.read_indexes and not mirror_leader:
-            return None
+        r = node.peer.raft
+        meta = self._meta.get(g)
+        if si.read_indexes and not mirror_leader and not (
+            # a row about to be uploaded is the scalar replica's copy:
+            # its own role stands for the mirror's, or the reads that
+            # met a leader on its way back would send it out again
+            meta is not None and meta.dirty and r.role == RaftRole.LEADER
+        ):
+            # a replica that does not lead forwards its reads over the
+            # wire, the scalar path's — but for those that asked for
+            # leader-or-nothing, told DROPPED here as Raft._step_follower
+            # tells them, with the row left where it is
+            rest = []
+            for ctx in si.read_indexes:
+                if ctx.high & CTX_NO_FORWARD:
+                    node.pending_read_index.dropped(ctx)
+                else:
+                    rest.append(ctx)
+            si.read_indexes = rest
+            if rest:
+                return None
         if node in self._save_quarantine:
             return None  # WAL faulting: scalar path is save-before-send
-        meta = self._meta.get(g)
         if meta is not None and meta.esc_hold > 0:
             meta.esc_hold -= 1
             return None  # post-escalation scalar hold (see _RowMeta)
@@ -1068,14 +1107,18 @@ class VectorStepEngine(IStepEngine):
                     kept.append(m)
             si.received = kept
         if node.quiesce.enabled and node.quiesce.is_quiesced() and (
-            si.received or si.proposals
+            si.received or si.proposals or si.transfers
         ):
             # activity exits quiesce; peers must be poked — scalar path
             # (quiesce state deliberately untouched: step_with_inputs
             # re-processes these inputs and performs the exit + poke)
             return None
-        r = node.peer.raft
         if r.read_index.pending or r.read_index.queue:
+            # the scalar replica's own ReadIndex rounds end on the host
+            # path; reads that arrive meanwhile wait for the device, or
+            # a hot group's readers would keep its leader out for good
+            node.requeue_inputs(read_indexes=si.read_indexes)
+            si.read_indexes = ()
             return None
         if r.snapshotting:
             return None
@@ -1120,6 +1163,11 @@ class VectorStepEngine(IStepEngine):
         for m in si.received:
             if int(m.type) not in _HOT_SET:
                 return None
+            if int(m.type) == int(MessageType.LEADER_TRANSFER):
+                # a follower-FORWARDED transfer request: hot only as the
+                # leader's own (below), where the plan sets the mirror's
+                # target that the lease gate reads
+                return None
             if int(m.type) == int(MessageType.READ_INDEX):
                 # a follower-FORWARDED read: the kernel's hot path only
                 # answers to self, so the wire response to the origin
@@ -1154,15 +1202,37 @@ class VectorStepEngine(IStepEngine):
                 return None
             slots.append(("msg", m))
         E = self.E
+        if (
+            len(slots) - (-len(si.proposals) // E)
+            + len(si.read_indexes) + len(si.transfers)
+        ) > self.M:
+            self._defer_past_room(node, si)
+            del slots[len(si.received):]
         props = si.proposals
         for i in range(0, len(props), E):
             slots.append(("prop", props[i : i + E]))
         for ctx in si.read_indexes:
             slots.append(("read", ctx))
-        # conservative capacity check BEFORE consuming quiesce state so a
-        # host fallback never double-processes ticks/activity
-        if len(slots) > self.M:
-            return None
+        for target in si.transfers:
+            # the leader's own transfer request stays on the device: the
+            # kernel holds the target, sends TIMEOUT_NOW and aborts, and
+            # a trip through the host path would fence the pipeline and
+            # take the row out and back (130 ms of a launch at 5,250
+            # rows, PERF.md section 6).  The lease gate reads the SCALAR
+            # mirror's leader_transfer_target (Raft.lease_ticks_at_age)
+            # and the kernel reports nothing back, so the mirror is set
+            # HERE, when the request is planned, not when its effects
+            # come back: a lease that outlives a TIMEOUT_NOW is a stale
+            # read.  The kernel gives a transfer up after one election
+            # window of the row's ticks and reports that neither: the
+            # completions count the same ticks and clear the mirror no
+            # sooner (_transfer_targets_pass).  A request the kernel
+            # ignores (a transfer in flight) leaves the mirror set as
+            # the one in flight did, for a window from its own launch
+            slots.append(("xfer", target))
+            if target != r.replica_id and target in r.remotes:
+                r.leader_transfer_target = target
+                self._xfer_watch[g] = (node, None)
         # multi-tick fusion: ALL of a row's drained ticks ride one
         # count-carrying LOCAL_TICK slot (kernel._tick advances timers
         # by n).  The count cap mirrors the scalar step's half-election-
@@ -1188,7 +1258,7 @@ class VectorStepEngine(IStepEngine):
             # (QUIESCE enter-hints are a cold type and never reach here.)
             for m in si.received:
                 node.quiesce.record_activity(m.type)
-            if si.proposals:
+            if si.proposals or si.transfers:
                 node.quiesce.record_activity(MessageType.PROPOSE)
             ticks = 0
             if self._meta[g].dirty:
@@ -1206,6 +1276,70 @@ class VectorStepEngine(IStepEngine):
         if ticks:
             slots.append(("tick", ticks))
         return slots
+
+    def _transfer_targets_pass(self, batch, fed_of) -> None:
+        """Once a completed launch: ``batch`` its active rows,
+        ``fed_of(g)`` the ticks it fed device row ``g`` (0: not
+        stepped).  The kernel drops a transfer that has not ended when
+        the leader's election clock, set to 0 where the request is
+        taken, reaches the election timeout (kernel._tick); the scalar
+        mirror's target, which zeroes the lease, is dropped here once
+        the launches AFTER the one that carried the request have fed
+        the row that many ticks — the carrying launch's own are not
+        counted, so never before the kernel.  A row that left the
+        device was materialized, target and all, and a replica that
+        met another leader cleared it (Node._check_leader_change):
+        both are watched no longer."""
+        watch = self._xfer_watch
+        for g in list(watch):
+            node, left = watch[g]
+            meta = self._meta.get(g)
+            r = node.peer.raft
+            if (
+                meta is None or meta.node is not node or meta.dirty
+                or r.leader_transfer_target == NO_NODE
+            ):
+                del watch[g]
+            elif left is not None:
+                left -= fed_of(g)
+                if left <= 0:
+                    r.leader_transfer_target = NO_NODE
+                    del watch[g]
+                else:
+                    watch[g] = (node, left)
+        for node, g, si, _plan in batch:
+            if si.transfers and g in watch:
+                watch[g] = (node, node.peer.raft.election_timeout)
+
+    def _defer_past_room(self, node, si) -> None:
+        """A row's inputs need more than its ``M`` host slots: the first
+        that fit go with this launch (one slot kept for the ticks) and
+        the rest go back to the head of the node's queues for the next,
+        in their order.  The host path would take them all at once, at
+        the price of the row's trip out and back and a fence of the
+        pipeline; and a leader out there hears several heartbeat rounds
+        a launch, so it met the same overflow at every return (PERF.md
+        section 6, PR 32).  Trims ``si`` in place: what an escalation
+        replays is what the launch was given."""
+        room = self.M - (1 if si.ticks else 0)
+        n_msg = min(len(si.received), room)
+        room -= n_msg
+        n_ent = min(len(si.proposals), room * self.E)
+        room -= -(-n_ent // self.E)
+        n_read = min(len(si.read_indexes), room)
+        room -= n_read
+        n_xfer = min(len(si.transfers), room)
+        node.requeue_inputs(
+            received=si.received[n_msg:],
+            proposals=si.proposals[n_ent:],
+            read_indexes=si.read_indexes[n_read:],
+            transfers=si.transfers[n_xfer:],
+        )
+        si.received = si.received[:n_msg]
+        si.proposals = si.proposals[:n_ent]
+        si.read_indexes = si.read_indexes[:n_read]
+        si.transfers = si.transfers[:n_xfer]
+        self.stats["deferred_inputs"] += 1
 
     # ------------------------------------------------------------------
     # device <-> scalar state movement
@@ -1779,6 +1913,11 @@ class VectorStepEngine(IStepEngine):
                         )
                     )
                     stage[slot] = list(payload)
+                elif kind == "xfer":
+                    self.stats["device_transfers"] += 1
+                    row_msgs.append(
+                        Message(type=MessageType.LEADER_TRANSFER, hint=payload)
+                    )
                 elif kind == "read":
                     self.stats["device_reads"] += 1
                     row_msgs.append(
@@ -1880,6 +2019,8 @@ class VectorStepEngine(IStepEngine):
                     updates.append((node, u))
         self._state = new_state
         esc_set = {g for _, g, _ in esc_rows}
+        if self._xfer_watch:
+            self._transfer_targets_pass(batch, lambda g: tick_fed.get(g, 0))
 
         # ---- gather detail for affected rows (ONE fused dispatch: the
         # per-step latency floor is dispatch round-trips, which on remote
@@ -2365,9 +2506,15 @@ class VectorStepEngine(IStepEngine):
                     continue  # stale vs final log; dropping is raft-safe
                 msg = dataclasses.replace(msg, entries=tuple(ents))
             elif msg.type == MessageType.PROPOSE and src_slot >= 0:
-                msg = dataclasses.replace(
-                    msg, entries=tuple(stage.get(src_slot, ()))
-                )
+                # a follower row's proposals on their way to the leader
+                # (always carried by the host: ops/route.py); what asked
+                # for leader-or-nothing stays here and is told DROPPED,
+                # as Raft._step_follower does on the scalar path
+                staged = stage.get(src_slot, ())
+                ents = forwardable(staged, r.dropped_entries)
+                if staged and not ents:
+                    continue
+                msg = dataclasses.replace(msg, entries=tuple(ents))
             r.msgs.append(msg)
 
     def _replicate_payload(

@@ -76,6 +76,7 @@ from .types import (
     MT_SNAPSHOT_RECEIVED,
     MT_SNAPSHOT_STATUS,
     MT_TICK,
+    MT_LEADER_TRANSFER,
     MT_TIMEOUT_NOW,
     MT_UNREACHABLE,
     N_FIELDS,
@@ -1277,6 +1278,35 @@ def _handle_snapshot_status(st, msg, mask):
 
 
 # ---------------------------------------------------------------------------
+# leader transfer request (oracle: _handle_leader_transfer)
+# ---------------------------------------------------------------------------
+def _handle_leader_transfer(st, out, msg, mask, E):
+    """A leader's own transfer request, ``hint`` the target: a voter
+    other than self, and no transfer in flight, or the request is
+    ignored.  The target caught up gets TIMEOUT_NOW at once; otherwise
+    it is sent what it lacks, and ``_handle_replicate_resp`` sends
+    TIMEOUT_NOW when its answer shows it caught up.  ``mask`` holds
+    leader rows only: a replica that does not lead forwards the request
+    over the wire, which is the scalar path's (the caller escalates)."""
+    target = msg["hint"]
+    slot, found = _slot_of(st, target)
+    ok = (
+        mask
+        & found
+        & (_col(st.peer_kind, slot) == KIND_VOTER)
+        & (target != st.replica_id)
+        & (st.transfer_target == 0)
+    )
+    st = st._replace(
+        transfer_target=_w(ok, target, st.transfer_target),
+        election_tick=_w(ok, 0, st.election_tick),
+    )
+    caught_up = ok & (_col(st.match, slot) == st.last_index)
+    out = _emit(out, caught_up, mtype=MT_TIMEOUT_NOW, to=target, term=st.term)
+    return _send_replicate(st, out, ok & ~caught_up, slot, E)
+
+
+# ---------------------------------------------------------------------------
 # propose (oracle: _handle_propose)
 # ---------------------------------------------------------------------------
 def _handle_propose(st, out, msg, mask, slot_i, E):
@@ -1446,6 +1476,15 @@ def _process_slot(st, out, msg, slot_i, E):
 
         def _rare(st, out):
             lead = role_routed & (st.role == ROLE_LEADER)
+            # a transfer request on a row that does not lead (the host's
+            # mirror was behind) goes back to the scalar path whole
+            out = out._replace(escalate=out.escalate | jnp.where(
+                role_routed & ~lead & (mt == MT_LEADER_TRANSFER),
+                ESC_COLD, 0,
+            ))
+            st, out = _handle_leader_transfer(
+                st, out, msg, lead & (mt == MT_LEADER_TRANSFER), E
+            )
             st = _check_quorum(st, lead & (mt == MT_CHECK_QUORUM))
             st = _handle_unreachable(st, msg, lead & (mt == MT_UNREACHABLE))
             st = _handle_snapshot_status(
@@ -1458,7 +1497,7 @@ def _process_slot(st, out, msg, slot_i, E):
 
         st, out = _gate(
             _has(MT_CHECK_QUORUM, MT_UNREACHABLE, MT_SNAPSHOT_STATUS,
-                 MT_SNAPSHOT_RECEIVED),
+                 MT_SNAPSHOT_RECEIVED, MT_LEADER_TRANSFER),
             _rare, st, out,
         )
 

@@ -94,6 +94,10 @@ HOT_TYPES = (
     MT_UNREACHABLE,
     MT_SNAPSHOT_STATUS,
     MT_SNAPSHOT_RECEIVED,
+    # a leader's OWN transfer request (hint = target), planned by the
+    # host for a row its mirror knows as leader; one that arrives over
+    # the wire from a follower stays the scalar path's (_plan_device)
+    MT_LEADER_TRANSFER,
 )
 
 # escalation reason bits (DeviceOut.escalate)

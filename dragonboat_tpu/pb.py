@@ -130,6 +130,14 @@ class Entry:
     series_id: int = 0
     responded_to: int = 0
     cmd: bytes = b""
+    # the proposer asked for leader-or-nothing (the gateway does): a
+    # replica that does not lead drops the proposal, DROPPED and so
+    # definitive, and never forwards it — a forwarded proposal that the
+    # leader then drops (a transfer in flight, leadership lost) is told
+    # to nobody and waits out its whole deadline.  Of the proposal on
+    # its way into a log only: not compared, not on the wire, and the
+    # entry a log holds does not carry it
+    no_forward: bool = field(default=False, compare=False, repr=False)
 
     def is_noop(self) -> bool:
         return (
@@ -348,6 +356,14 @@ class SystemCtx:
     high: int = 0
 
 
+# bit 30 of ``SystemCtx.high``, which a key leaves clear (request.py:
+# keys start below 2^61): the reader asked for leader-or-nothing (the
+# gateway does).  A replica that does not lead drops such a ReadIndex,
+# DROPPED, where it would have forwarded it; a forwarded read is the
+# host path's on the device engines, at the follower and at the leader
+CTX_NO_FORWARD = 1 << 30
+
+
 @dataclass(frozen=True)
 class ReadyToRead:
     """ReadIndex confirmation (reference: raftpb.ReadyToRead [U])."""
@@ -393,6 +409,10 @@ class Update:
     ready_to_reads: List[ReadyToRead] = field(default_factory=list)
     dropped_entries: List[Entry] = field(default_factory=list)
     dropped_read_indexes: List[SystemCtx] = field(default_factory=list)
+    # (conflict index, the term that stood there, keyed entries from it
+    # on) for each stretch another leader's entries replaced in the
+    # uncommitted tail (raft/log.py InMemory._note_truncated)
+    truncated: list = field(default_factory=list)
     update_commit: UpdateCommit = field(default_factory=UpdateCommit)
     fast_apply: bool = False
     has_update: bool = False

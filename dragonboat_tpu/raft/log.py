@@ -139,11 +139,15 @@ class InMemLogReader:
 
 
 class InMemory:
-    __slots__ = ("entries", "marker", "saved_to", "snapshot", "bytes")
+    __slots__ = ("entries", "marker", "saved_to", "snapshot", "bytes",
+                 "truncated")
     """The unpersisted/unapplied in-memory window of the log.
 
     reference: internal/raft/inmemory.go [U].  ``marker`` is the raft index
     of ``entries[0]``; ``saved_to`` the highest index known persisted.
+    ``truncated`` records what a ``merge`` took out of the uncommitted
+    tail, another leader's entries over it (``_note_truncated``);
+    ``Peer.get_update`` drains it into ``Update.truncated``.
     """
 
     def __init__(self, last_saved_index: int):
@@ -151,6 +155,7 @@ class InMemory:
         self.entries: List[Entry] = []
         self.saved_to = last_saved_index
         self.snapshot: Snapshot = EMPTY_SNAPSHOT  # pending restore
+        self.truncated: List[Tuple[int, int, List[Entry]]] = []
         # byte size of the window — the MaxInMemLogSize rate-limit input
         # (reference: internal/server/rate.go InMemRateLimiter [U])
         self.bytes = 0
@@ -189,16 +194,37 @@ class InMemory:
             self.entries = self.entries + list(entries)
             self.bytes += added
         elif first_new <= self.marker:
+            self._note_truncated(self.entries, entries)
             self.marker = first_new
             self.entries = list(entries)
             self.bytes = added
             self.saved_to = min(self.saved_to, first_new - 1)
         else:
             keep = first_new - self.marker
+            self._note_truncated(self.entries[keep:], entries)
             self.bytes -= sum(e.size_bytes() for e in self.entries[keep:])
             self.entries = self.entries[:keep] + list(entries)
             self.bytes += added
             self.saved_to = min(self.saved_to, first_new - 1)
+
+    def _note_truncated(self, gone: Sequence[Entry],
+                        entries: Sequence[Entry]) -> None:
+        """``gone`` leaves the window for ``entries``.  The same index
+        with the same term is the same entry (log matching: a resend, or
+        the device path rebuilding a range it already held) and stays;
+        from the first index whose term differs, the conflict, all of
+        ``gone`` is another branch.  Kept as one record ``(conflict
+        index, the term that stood there, the keyed entries from it
+        on)``; entries nobody can be waiting on (key 0: a leader's
+        barrier) are left out, a record without any is not made."""
+        first = entries[0].index
+        for n, e in enumerate(gone):
+            at = e.index - first
+            if not (0 <= at < len(entries) and entries[at].term == e.term):
+                keyed = [x for x in gone[n:] if x.key]
+                if keyed:
+                    self.truncated.append((e.index, e.term, keyed))
+                return
 
     def restore(self, ss: Snapshot) -> None:
         self.snapshot = ss

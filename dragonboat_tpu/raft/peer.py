@@ -142,6 +142,7 @@ class Peer:
             or r.ready_to_reads
             or r.dropped_entries
             or r.dropped_read_indexes
+            or r.log.inmem.truncated
         )
 
     def get_update(self, more_to_apply: bool = True, last_applied: int = 0) -> Update:
@@ -157,6 +158,7 @@ class Peer:
         de, dr = r.drain_dropped()
         u.dropped_entries = de
         u.dropped_read_indexes = dr
+        u.truncated = r.drain_truncated()
         u.last_applied = last_applied
         if not r.log.inmem.snapshot.is_empty():
             u.snapshot = r.log.inmem.snapshot

@@ -17,6 +17,7 @@ from typing import Dict, List, Optional, Tuple
 from .. import settings
 from ..logger import get_logger
 from ..pb import (
+    CTX_NO_FORWARD,
     ConfigChange,
     ConfigChangeType,
     Entry,
@@ -69,6 +70,17 @@ def election_jitter(shard_id: int, replica_id: int, seq: int, span: int) -> int:
     """Deterministic jitter in [0, span)."""
     h = splitmix32(((shard_id << 24) ^ (replica_id << 8) ^ seq) & 0xFFFFFFFF)
     return h % span
+
+
+def forwardable(entries, dropped: list):
+    """The ``entries`` of a proposal that a replica which does not lead
+    may send on to the leader; those that asked for leader-or-nothing
+    (``Entry.no_forward``) go to ``dropped`` instead, and so are told
+    DROPPED here, where nothing has been sent anywhere yet."""
+    if not any(e.no_forward for e in entries):
+        return entries
+    dropped.extend(e for e in entries if e.no_forward)
+    return [e for e in entries if not e.no_forward]
 
 
 class Raft:
@@ -1210,10 +1222,12 @@ class Raft:
             if self.leader_id == NO_LEADER:
                 self.dropped_entries.extend(m.entries)
                 return
-            # forward to leader
-            self._send(
-                Message(type=MessageType.PROPOSE, to=self.leader_id, entries=m.entries)
-            )
+            # forward to leader, but for what asked for leader-or-nothing
+            entries = forwardable(m.entries, self.dropped_entries)
+            if entries:
+                self._send(
+                    Message(type=MessageType.PROPOSE, to=self.leader_id, entries=entries)
+                )
         elif t == MessageType.REPLICATE:
             self.election_tick = 0
             self._observe_leader(m.from_)
@@ -1240,7 +1254,9 @@ class Raft:
                 pass
             if self.is_witness():
                 return
-            if self.leader_id == NO_LEADER:
+            if self.leader_id == NO_LEADER or m.hint_high & CTX_NO_FORWARD:
+                # no leader to forward it to, or the reader asked for
+                # leader-or-nothing: DROPPED, and the reader goes round
                 self.dropped_read_indexes.append(
                     SystemCtx(low=m.hint, high=m.hint_high)
                 )
@@ -1487,6 +1503,13 @@ class Raft:
         de, dr = self.dropped_entries, self.dropped_read_indexes
         self.dropped_entries, self.dropped_read_indexes = [], []
         return de, dr
+
+    def drain_truncated(self) -> list:
+        im = self.log.inmem
+        out = im.truncated
+        if out:
+            im.truncated = []
+        return out
 
     def get_membership(self) -> Membership:
         return Membership(

@@ -16,7 +16,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
 from .client import Session
-from .pb import Entry, EntryType, SystemCtx
+from .pb import CTX_NO_FORWARD, Entry, EntryType, SystemCtx
 from .statemachine import Result
 
 # Pending-table keys ride Entry.key across every boundary as a uint64
@@ -44,6 +44,39 @@ def random_key_base() -> int:
 
 class RequestError(Exception):
     pass
+
+
+# what a NodeHost's replicas count on it, always on (docs/OBSERVABILITY.md
+# "Counters"): leader transfers by how they ended, the host-clock
+# seconds from a request to the target's leading, and proposals told
+# DROPPED because a newer leader's entries replaced theirs
+HOST_TOTALS = (
+    "leader_transfers_requested", "leader_transfers_done",
+    "leader_transfers_aborted", "t_transfer_s",
+    "proposals_dropped_truncated",
+)
+
+
+class HostTotals:
+    """The ``HOST_TOTALS`` of one NodeHost.  The events are rare (a
+    transfer, a truncated tail), their writers many (callers' threads,
+    every step worker), so each ``add`` takes the lock."""
+
+    __slots__ = ("_lock", "values")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.values = dict.fromkeys(HOST_TOTALS, 0)  # guarded-by: _lock
+
+    def add(self, key: str, n=1) -> None:
+        with self._lock:
+            self.values[key] += n
+
+    def snapshot(self) -> dict:
+        # an add replaces one value of a dict whose keys never change,
+        # and the engine reads this once a step call
+        # raftlint: ignore[guarded-by] lock-free copy of a fixed-key dict
+        return dict(self.values)
 
 
 class ShardNotFound(RequestError):
@@ -160,16 +193,23 @@ class _PendingBase:
 
     def _alloc(self, deadline: int) -> RequestState:
         with self._lock:
-            self._next_key += 1
-            rs = RequestState(self._next_key, deadline)
-            self._pending[self._next_key] = rs
-            if deadline < self._hint[0]:
-                self._hint[0] = deadline  # guarded-by: _lock
-            return rs
+            return self._alloc_locked(deadline)
+
+    def _alloc_locked(self, deadline: int) -> RequestState:  # guarded-by: _lock
+        self._next_key += 1
+        rs = RequestState(self._next_key, deadline)
+        self._pending[self._next_key] = rs
+        if deadline < self._hint[0]:
+            self._hint[0] = deadline
+        return rs
 
     def pop(self, key: int) -> Optional[RequestState]:
         with self._lock:
             return self._pending.pop(key, None)
+
+    def has(self, key: int) -> bool:
+        with self._lock:
+            return key in self._pending
 
     def dropped(self, key: int) -> None:
         rs = self.pop(key)
@@ -282,7 +322,8 @@ class PendingProposal(_PendingBase):
     single dict suffices under the GIL) [U]."""
 
     def propose(
-        self, session: Session, cmd: bytes, deadline: int
+        self, session: Session, cmd: bytes, deadline: int,
+        forward: bool = True,
     ) -> Tuple[Entry, RequestState]:
         rs = self._alloc(deadline)
         entry = Entry(
@@ -292,6 +333,7 @@ class PendingProposal(_PendingBase):
             series_id=session.series_id,
             responded_to=session.responded_to,
             cmd=cmd,
+            no_forward=not forward,
         )
         return entry, rs
 
@@ -335,14 +377,18 @@ class PendingReadIndex(_PendingBase):
             (i, k) for i, k in self._waiting if k not in expired_keys
         ]
 
-    def read(self, deadline: int) -> Tuple[SystemCtx, RequestState]:
+    def read(self, deadline: int,
+             forward: bool = True) -> Tuple[SystemCtx, RequestState]:
         rs = self._alloc(deadline)
         # each half stays < 2^31 so the ctx can ride the device inbox's
         # int32 hint fields (ops/engine.py device ReadIndex) and every
         # wire codec without sign trouble; keys are sequential from a
-        # 61-bit randomized base, so the split stays injective
+        # 61-bit randomized base, so the split stays injective — and
+        # the high half's bit 30 is free to say leader-or-nothing
         ctx = SystemCtx(
-            low=rs.key & 0x7FFFFFFF, high=(rs.key >> 31) & 0x7FFFFFFF
+            low=rs.key & 0x7FFFFFFF,
+            high=(rs.key >> 31) & 0x7FFFFFFF
+            | (0 if forward else CTX_NO_FORWARD),
         )
         with self._lock:
             self._ctx_map[(ctx.low, ctx.high)] = rs.key
@@ -413,14 +459,48 @@ class PendingSnapshot(_PendingBase):
 
 
 class PendingLeaderTransfer(_PendingBase):
-    __slots__ = ()
+    __slots__ = ("_asked", "totals")
+    """Every request ends once, and is counted as it does: ``done``
+    when the next leader this replica meets is the target it asked for
+    (with the host-clock time since the request), ``aborted`` when it
+    is another, or the request's deadline, a stop or a seal comes first
+    — so ``requested = done + aborted`` once nothing is pending.  It is
+    the REQUESTING replica that counts: the target's host never learns
+    that a transfer was asked for (on the device path the kernel
+    consumes ``TIMEOUT_NOW``), and the hosts of a deployment share
+    nothing."""
+
+    def __init__(self, lock=None, key_base=None, deadline_hint=None,
+                 totals: Optional[HostTotals] = None):
+        super().__init__(lock, key_base, deadline_hint)
+        self._asked: Dict[int, Tuple[int, float]] = {}  # key -> (target, at); guarded-by: _lock
+        self.totals = totals if totals is not None else HostTotals()
+
     def request(self, target: int, deadline: int) -> RequestState:
-        return self._alloc(deadline)
+        with self._lock:
+            rs = self._alloc_locked(deadline)
+            self._asked[rs.key] = (target, time.monotonic())
+        self.totals.add("leader_transfers_requested")
+        return rs
+
+    def _gc_extra(self, expired_keys) -> None:  # guarded-by: _lock
+        # deadline, stop or seal: whichever comes first counts, once
+        n = sum(1 for k in expired_keys
+                if self._asked.pop(k, None) is not None)
+        if n:
+            self.totals.add("leader_transfers_aborted", n)
 
     def notify_leader(self, leader_id: int) -> None:
+        now = time.monotonic()
         with self._lock:
             keys = list(self._pending)
             for k in keys:
                 self._pending.pop(k).notify(
                     RequestResultCode.COMPLETED, Result(value=leader_id)
                 )
+                target, at = self._asked.pop(k, (0, now))
+                if target == leader_id:
+                    self.totals.add("leader_transfers_done")
+                    self.totals.add("t_transfer_s", now - at)
+                else:
+                    self.totals.add("leader_transfers_aborted")

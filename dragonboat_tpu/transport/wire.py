@@ -752,9 +752,15 @@ RPC_OP_OBS = 7  # fleet-scope telemetry (obs/fleetscope.py); old
                 # servers answer RPC_ERR "unknown op 7" and the
                 # collector marks the process "no-obs"
 
+# PROPOSE flag (RpcRequest.flags; 0 before PR 32): leader-or-nothing,
+# NodeHost.propose(forward=False).  A server from before it takes no
+# notice of the byte on a PROPOSE and forwards, as it always did
+RPC_PROPOSE_NO_FORWARD = 1
+
 # READ flags (RpcRequest.flags)
 RPC_READ_LEASE = 0   # lease fast path ONLY; ERR_NO_LEASE when not held
-RPC_READ_INDEX = 1   # full ReadIndex quorum read
+RPC_READ_INDEX = 1   # full ReadIndex quorum read; arg 1 (0 before
+                     # PR 32): leader-or-nothing, sync_read(forward=False)
 RPC_READ_STALE = 2   # local stale read (no linearizability)
 # readplane consistency byte (docs/READPLANE.md).  Old servers answer
 # unknown flags with code=RPC_ERR "unknown read mode N" — the client's

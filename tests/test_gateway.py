@@ -651,6 +651,7 @@ class _LostReadHost:
     def __init__(self, lose: int):
         self.lose = lose
         self.timeouts = []
+        self.forwards = []
 
     def is_leader_of(self, shard_id):
         return True
@@ -663,10 +664,11 @@ class _LostReadHost:
 
         return LEASE_MISS_UNREPORTED, None
 
-    def sync_read(self, shard_id, query, timeout=5.0):
+    def sync_read(self, shard_id, query, timeout=5.0, forward=True):
         from dragonboat_tpu.nodehost import TimeoutError_
 
         self.timeouts.append(timeout)
+        self.forwards.append(forward)
         if len(self.timeouts) <= self.lose:
             time.sleep(timeout)
             raise TimeoutError_("TIMEOUT")
@@ -688,6 +690,9 @@ class TestReadIndexFallbackPerTry:
             gw.close()
         # two tries lost, the third answered: two per-try waits, not 30 s
         assert host.timeouts == [pytest.approx(per_try)] * 3
+        # leader-or-nothing for one election window (0.1 s, over after
+        # the first try), forwarded by whoever carries the group after
+        assert host.forwards == [False, True, True]
         assert 2 * per_try <= took < 5.0
         assert gw.stats()["read_fallbacks"] == 1
 

@@ -52,12 +52,16 @@ ENGINE_KEYS = PHASES + (
     "t_wal_ms", "wal_appends", "wal_bytes", "wal_records",
     "device_rows_active", "apply_batches", "apply_entries", "t_apply_ms",
     "t_apply_wait_ms", "tick_lane_rows", "completion_rows_walked",
+    # PR 32: leader transfers and what a change of leader costs proposals
+    "leader_transfers_requested", "leader_transfers_done",
+    "leader_transfers_aborted", "t_transfer_ms",
+    "proposals_dropped_truncated", "device_transfers", "deferred_inputs",
 )
 GATEWAY_KEYS = (
     "proposed", "t_queue_wait_ms", "t_ack_lag_ms", "poll_checks",
     "poll_passes", "read_fallback_not_leader",
     "read_fallback_no_commit_in_term", "read_fallback_apply_lag",
-    "read_fallback_lease_expiring",
+    "read_fallback_lease_expiring", "reroutes",
 )
 REGIONS = tuple(
     "raft-colocated-" + p for p in (

@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import random
 
+import numpy as np
+
 import pytest
 
 from dragonboat_tpu.pb import Entry, EntryType, Message, MessageType
@@ -115,6 +117,99 @@ def test_leader_transfer_timeout_now():
     for _ in range(6):
         c.step(c.deliver_batches(tick=False))
     assert c.leader_of(2) == target
+
+
+def _transfer(target):
+    return Message(type=MessageType.LEADER_TRANSFER, hint=target)
+
+
+@pytest.mark.parametrize("behind", [False, True])
+def test_a_leaders_own_transfer_request_is_hot(behind):
+    """PR 32: the request is an inbox slot of the leader's row (hint =
+    target).  A target that holds the whole log gets TIMEOUT_NOW in the
+    same step; one that is behind is sent what it lacks first, and its
+    answer brings the TIMEOUT_NOW.  Every step compared with the oracle
+    (``Cluster.step``), ``transfer_target`` and ``election_tick`` among
+    the row's fields."""
+    c = Cluster({2: [1, 2, 3, 4, 5]}, check_quorum=True, pre_vote=True)
+    lid = c.elect(2)
+    c.run(4, tick=False)
+    target = lid % 5 + 1
+    batch = [_transfer(target)]
+    if behind:
+        batch.insert(0, c.propose(2, lid, [b"x", b"y"]))
+    out = c.step({(2, lid): batch})[(2, lid)]
+    sent = [m.type for m in out if m.to == target]
+    if behind:
+        assert MessageType.TIMEOUT_NOW not in sent
+        assert MessageType.REPLICATE in sent
+    else:
+        assert sent == [MessageType.TIMEOUT_NOW]
+    g = c.row_of[(2, lid)]
+    assert int(np.asarray(c.state.transfer_target)[g]) == target
+    assert int(np.asarray(c.state.election_tick)[g]) == 0
+    # a proposal during the transfer is dropped, on both sides alike
+    c.step(c.deliver_batches(
+        tick=False, extra={(2, lid): [c.propose(2, lid, [b"late"])]}))
+    for _ in range(8):
+        c.step(c.deliver_batches(tick=False))
+    assert c.leader_of(2) == target
+    assert int(np.asarray(c.state.transfer_target)[g]) == 0  # reset with the role
+
+
+def test_a_transfer_request_that_cannot_stand_is_ignored():
+    c = Cluster({2: [1, 2, 3], 5: [1, 2, 3, 4]}, non_votings={5: [4]})
+    lid = c.elect(2)
+    lid5 = c.elect(5)
+    c.run(4, tick=False)
+    g = c.row_of[(2, lid)]
+    other = [r for r in (1, 2, 3) if r != lid]
+    # self, a replica nobody knows, and (group 5) a non-voter
+    c.step({(2, lid): [_transfer(lid), _transfer(9)],
+            (5, lid5): [_transfer(4)]})
+    assert int(np.asarray(c.state.transfer_target)[g]) == 0
+    assert int(np.asarray(c.state.transfer_target)[c.row_of[(5, lid5)]]) == 0
+    # a second request while the first is in flight: the first stands
+    out = c.step({(2, lid): [_transfer(other[0]), _transfer(other[1])]})
+    assert int(np.asarray(c.state.transfer_target)[g]) == other[0]
+    assert [m.to for m in out[(2, lid)]
+            if m.type == MessageType.TIMEOUT_NOW] == [other[0]]
+
+
+def test_a_transfer_nobody_answers_is_given_up_after_an_election_window():
+    c = Cluster({2: [1, 2, 3]}, election_timeout=6)
+    lid = c.elect(2)
+    c.run(4, tick=False)
+    target = lid % 3 + 1
+    g = c.row_of[(2, lid)]
+    c.step({(2, lid): [_transfer(target)]})
+    for k in c.rows:          # the TIMEOUT_NOW never arrives
+        c.net[k].clear()
+    tick = Message(type=MessageType.LOCAL_TICK)
+    for _ in range(5):
+        c.step({(2, lid): [tick]})
+        for k in c.rows:
+            c.net[k].clear()
+    assert int(np.asarray(c.state.transfer_target)[g]) == target
+    c.step({(2, lid): [tick]})
+    assert int(np.asarray(c.state.transfer_target)[g]) == 0
+    assert c.rafts[(2, lid)].is_leader()
+
+
+def test_a_transfer_request_on_a_row_that_does_not_lead_escalates():
+    """The host plans the request for a row its mirror knows as leader;
+    should the row have stepped down meanwhile, the kernel hands the
+    whole row back (the scalar path forwards the request over the
+    wire)."""
+    c = Cluster({2: [1, 2, 3]})
+    lid = c.elect(2)
+    c.run(4, tick=False)
+    follower = lid % 3 + 1
+    c.allow_escalation = True
+    out = c.step({(2, follower): [_transfer(follower % 3 + 1)]})
+    assert c.escalations == 1
+    assert [(m.type, m.to) for m in out[(2, follower)]] == [
+        (MessageType.LEADER_TRANSFER, lid)]
 
 
 def test_partition_and_rejoin_log_repair():

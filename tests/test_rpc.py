@@ -220,6 +220,29 @@ class TestRpcEndToEnd:
             time.sleep(0.05)
         raise AssertionError("lease never held")
 
+    @pytest.mark.parametrize("forward", [True, False])
+    def test_leader_or_nothing_rides_the_request(self, rpc_host, forward):
+        """``forward=False`` (the gateway's proposals) reaches the
+        serving host's ``NodeHost.propose`` through the request's flags
+        byte; without it the host forwards as it always did."""
+        nh, _, h = rpc_host
+        seen = []
+        real = nh.propose
+
+        def spy(*a, **kw):
+            seen.append(kw.get("forward"))
+            return real(*a, **kw)
+
+        nh.propose = spy
+        try:
+            rc = h.propose(h.get_noop_session(1),
+                           audit_set_cmd("fk", f"fv{forward}"), 10.0,
+                           forward=forward)
+            assert rc.wait(10.0) == RequestResultCode.COMPLETED
+        finally:
+            del nh.propose
+        assert seen == [forward]
+
     def test_leader_surface_and_placement(self, rpc_host):
         nh, _, h = rpc_host
         assert h.get_leader_id(1) == (1, True)
