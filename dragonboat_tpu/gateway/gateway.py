@@ -33,9 +33,11 @@ be mistaken for a retry of the dead one.
 """
 from __future__ import annotations
 
+import heapq
 import threading
 import time
 from collections import deque
+from functools import partial
 from typing import Dict, Optional
 
 from ..client import LatencyBudget, Session
@@ -64,10 +66,18 @@ from .routing import RoutingCache
 _log = get_logger("gateway")
 
 _WORKER_COUNTERS = ("proposed", "t_queue_wait_ms", "t_ack_lag_ms",
-                    "poll_checks", "poll_passes")
+                    "poll_checks", "poll_passes", "wakes", "wakes_timed",
+                    "t_worker_cpu_ms")
 # stats() keys "read_fallback_<name>", in node.LEASE_MISS_* order from 1
 _LEASE_MISS_KEYS = ("not_leader", "no_commit_in_term", "apply_lag",
                     "lease_expiring")
+
+# a request answered DROPPED is sent again at once the first time (a
+# stale route: the fresh one is usually right) and after a pause that
+# doubles between these bounds from then on: a group with no leader
+# answers DROPPED as fast as it is asked
+_RETRY_PAUSE_MIN_S = 0.001
+_RETRY_PAUSE_MAX_S = 0.032
 
 # orders GatewayFuture.add_done_callback against _complete.  One lock
 # for every future, not one each: it is held for two stores, and a
@@ -206,7 +216,7 @@ class GatewayFuture:
 
 class _GwReq:
     __slots__ = ("handle", "cmd", "deadline", "future", "t_admit",
-                 "ambiguous", "proposed")
+                 "ambiguous", "proposed", "retry_pause")
 
     def __init__(self, handle, cmd: bytes, deadline: float):
         self.handle = handle
@@ -215,6 +225,7 @@ class _GwReq:
         self.future = GatewayFuture()
         self.t_admit = time.monotonic()
         self.proposed = False  # its first nh.propose went out
+        self.retry_pause = 0.0  # before its next re-proposal (seconds)
         # True once ANY attempt of this op may have committed (a node-
         # side timeout, or termination with the outcome unobserved):
         # the series must then be burned on EVERY terminal path, not
@@ -223,6 +234,42 @@ class _GwReq:
         # finding: reusing the series for the next op would let the
         # dedupe registry swallow it as a retry of this one)
         self.ambiguous = False
+
+
+class _Worker:
+    """What one worker sleeps on and what it alone owns.  Other threads
+    append to ``ready`` (under the gateway's ``_lanes_lock``) and to
+    ``done`` (a deque's append is atomic) and set ``event``; the rest is
+    the worker's own."""
+
+    __slots__ = ("event", "ready", "done", "pending", "expiry", "seq",
+                 "acc")
+
+    def __init__(self):
+        self.event = threading.Event()
+        # shard ids whose lane holds requests for this worker; a lane is
+        # in it once while it is non-empty.  guarded-by: Gateway._lanes_lock
+        self.ready: deque = deque()
+        # (req, rs) whose RequestState was notified
+        self.done: deque = deque()
+        # req -> the RequestState of its newest attempt, not yet answered
+        self.pending: Dict[_GwReq, object] = {}
+        # heap of (when, seq, req): a pending request's deadline, and the
+        # end of its pause where a DROPPED one waits to be sent again.
+        # An entry whose request was answered is dropped when it reaches
+        # the head, so the head is always the next time to wake at
+        self.expiry: list = []
+        self.seq = 0
+        # always-on counters of the propose path (summed in stats())
+        self.acc = dict.fromkeys(_WORKER_COUNTERS, 0)
+
+    def notified(self, req: _GwReq, rs) -> None:
+        """The waker a submitted RequestState carries: ``notify`` calls
+        it last, on whatever thread completed the request (an apply
+        worker, a step path, under locks of theirs).  So it queues and
+        wakes and does nothing else: no gateway lock, no gateway logic."""
+        self.done.append((req, rs))
+        self.event.set()
 
 
 class ClientHandle:
@@ -341,7 +388,9 @@ class Gateway:
         self._latency = self.metrics.histogram("gateway_request_seconds")
         # per-shard submission lanes: shard -> deque of _GwReq released
         # by their handles; lanes are partitioned over workers by
-        # shard_id so one shard's batch is always built by one worker
+        # shard_id so one shard's batch is always built by one worker.
+        # A lane is made once a shard and stays; nothing walks the dict
+        # but close()
         self._lanes: Dict[int, deque] = {}
         self._lanes_lock = threading.Lock()
         self._closed = False
@@ -368,15 +417,8 @@ class Gateway:
                 name="tpu-gw-capfeedback",
             )
             self._cap_thread.start()
-        self._wake_events = [
-            threading.Event() for _ in range(self.config.workers)
-        ]
-        # always-on counters of the propose path, one dict a worker
-        # (each written by that worker alone) and summed in stats()
-        self._worker_acc = [
-            dict.fromkeys(_WORKER_COUNTERS, 0)
-            for _ in range(self.config.workers)
-        ]
+        # lanes are partitioned over the workers by shard_id
+        self._wstate = [_Worker() for _ in range(self.config.workers)]
         # both sets as gauges too, read at scrape
         for key in _WORKER_COUNTERS:
             self.metrics.gauge(
@@ -634,6 +676,7 @@ class Gateway:
 
     def _enqueue(self, req: _GwReq) -> None:
         sid = req.handle.shard_id
+        w = self._wstate[sid % self.config.workers]
         with self._lanes_lock:
             # re-check closed UNDER the lanes lock: close() swaps the
             # lanes dict out under this lock and seals what it swapped —
@@ -644,6 +687,10 @@ class Gateway:
                 lane = self._lanes.get(sid)
                 if lane is None:
                     lane = self._lanes[sid] = deque()
+                if not lane:
+                    # empty -> non-empty: the one time it is listed (the
+                    # worker lists it again itself if it leaves some)
+                    w.ready.append(sid)
                 lane.append(req)
                 sealed = False
             else:
@@ -651,7 +698,7 @@ class Gateway:
         if sealed:
             self._fail(req, GatewayClosed("gateway closed"))
             return
-        self._wake_events[sid % self.config.workers].set()
+        w.event.set()
 
     def _release_next(self, handle: ClientHandle) -> None:
         """Completion of a handle's in-flight op releases its next one
@@ -674,51 +721,121 @@ class Gateway:
             nxt.future._complete(exc=GatewayClosed("gateway closed"))
 
     # -- worker pool ---------------------------------------------------------
-    def _my_lanes(self, idx: int):
-        with self._lanes_lock:
-            return [
-                sid for sid in self._lanes
-                if sid % self.config.workers == idx
-            ]
-
-    def _drain(self, sid: int, limit: int):
+    def _drain_ready(self, w: _Worker):
+        """The worker's next ready lane, up to ``max_batch`` requests of
+        it in order; a lane with more left goes to the end of the line."""
         out = []
+        limit = self.config.max_batch
         with self._lanes_lock:
+            sid = w.ready.popleft()  # only this worker takes from it
             lane = self._lanes.get(sid)
             while lane and len(out) < limit:
                 out.append(lane.popleft())
+            if lane:
+                w.ready.append(sid)
         return out
 
+    def _watch(self, w: _Worker, req: _GwReq, rs) -> None:
+        """Arm ``rs`` to report to ``w``, THEN look at it once: a notify
+        that ran before the arm found no waker to call.  One that runs
+        between the two is reported twice, and the second report finds
+        ``pending[req]`` is no longer ``rs`` and is dropped."""
+        if req not in w.pending:
+            self._wake_at(w, req.deadline, req)
+        w.pending[req] = rs
+        rs.waker = partial(w.notified, req)
+        if rs._event.is_set():
+            w.done.append((req, rs))
+
+    @staticmethod
+    def _wake_at(w: _Worker, when: float, req: _GwReq) -> None:
+        w.seq += 1
+        heapq.heappush(w.expiry, (when, w.seq, req))
+
+    def _check(self, w: _Worker, req: _GwReq, rs) -> None:
+        w.acc["poll_checks"] += 1
+        nrs = self._poll_finish(req, rs, w.acc)
+        if nrs is None:
+            del w.pending[req]
+        elif nrs is not rs:
+            # DROPPED and sent again: a new RequestState to wait for
+            req.retry_pause = min(
+                max(2.0 * req.retry_pause, _RETRY_PAUSE_MIN_S),
+                _RETRY_PAUSE_MAX_S)
+            self._watch(w, req, nrs)
+
+    def _next_wait(self, w: _Worker) -> Optional[float]:
+        """Seconds until the earliest time a pending pair wants a look
+        by the clock (its deadline; the end of a DROPPED one's pause);
+        None (sleep until an event) when nothing is pending."""
+        expiry, pending = w.expiry, w.pending
+        while expiry and expiry[0][2] not in pending:
+            heapq.heappop(expiry)
+        if len(expiry) > 2 * len(pending) + 64:
+            # answered requests queued up behind one that is not (a
+            # shard without quorum holds the head for its whole
+            # deadline): let them go, they pin their commands
+            expiry[:] = [e for e in expiry if e[2] in pending]
+            heapq.heapify(expiry)
+        if not expiry:
+            return None
+        return max(0.0, expiry[0][0] - time.monotonic())
+
     def _worker_main(self, idx: int) -> None:
-        """Drain-submit-poll loop.  Completions are POLLED, never
-        blocked on: a shard that lost quorum must not head-of-line
-        block the other shards mapped to this worker for its requests'
-        whole deadlines (review finding) — its pending pairs just ride
-        the ``pending`` list while every other lane keeps draining.
-        The poll cadence (5ms with work in flight) bounds the added
-        completion latency."""
-        ev = self._wake_events[idx]
-        acc = self._worker_acc[idx]
-        pending = []  # (req, rs) submitted, awaiting completion
+        """Sleep until something of this worker's own happens, then
+        touch only that: a lane of its shards got a request
+        (``_enqueue``), a RequestState it submitted was notified
+        (``_Worker.notified``), or the earliest time one of its pending
+        pairs wants a look by the clock came (``_next_wait``: a
+        deadline, the end of a pause).  Completions are never blocked on: a
+        shard that lost quorum must not head-of-line block the other
+        shards mapped to this worker for its requests' whole deadlines
+        (review finding) -- its pairs cost their place in ``pending``
+        and one timed wake at their deadline, while every other lane
+        keeps draining."""
+        w = self._wstate[idx]
+        ev, ready, done, acc = w.event, w.ready, w.done, w.acc
+        pending, expiry = w.pending, w.expiry
         while not self._closed:
-            ev.wait(timeout=0.005 if pending else 0.05)
+            if not ready and not done:  # else: left over by the last pass
+                timed_out = not ev.wait(self._next_wait(w))
+                acc["wakes"] += 1
+                if timed_out:
+                    acc["wakes_timed"] += 1
             ev.clear()
+            cpu0 = time.thread_time()
             with annotate("gateway-poll"):
-                for sid in self._my_lanes(idx):
-                    for req in self._drain(sid, self.config.max_batch):
+                # the lanes that were ready when the pass began: one
+                # with more than max_batch left waits for the next
+                for _ in range(len(ready)):
+                    for req in self._drain_ready(w):
                         rs = self._propose_once(req, acc)
                         if rs is not None:
-                            pending.append((req, rs))
-                if pending:
+                            self._watch(w, req, rs)
+                checks = acc["poll_checks"]
+                now = time.monotonic()
+                for _ in range(len(done)):
+                    req, rs = done.popleft()
+                    if pending.get(req) is not rs:
+                        continue  # reported twice (see _watch)
+                    if (req.retry_pause
+                            and rs.code == RequestResultCode.DROPPED):
+                        self._wake_at(w, now + req.retry_pause, req)
+                        continue
+                    self._check(w, req, rs)
+                # times passed: a pause's end finds its pair notified,
+                # a deadline finds it expired (monotonic: _poll_finish
+                # reads the clock after this and agrees)
+                while expiry and expiry[0][0] <= now:
+                    req = heapq.heappop(expiry)[2]
+                    rs = pending.get(req)
+                    if rs is not None and (
+                            rs._event.is_set() or req.deadline <= now):
+                        self._check(w, req, rs)
+                if acc["poll_checks"] != checks:
                     acc["poll_passes"] += 1
-                    acc["poll_checks"] += len(pending)
-                    still = []
-                    for req, rs in pending:
-                        nrs = self._poll_finish(req, rs, acc)
-                        if nrs is not None:
-                            still.append((req, nrs))
-                    pending = still
-        for req, _rs in pending:
+            acc["t_worker_cpu_ms"] += (time.thread_time() - cpu0) * 1000.0
+        for req in pending:
             # submitted but unresolved at close: may still commit
             req.ambiguous = True
             self._fail(req, GatewayClosed("gateway closed"))
@@ -786,7 +903,7 @@ class Gateway:
         """Non-blocking completion check for one submitted request.
         Returns None when the gateway future was completed (done,
         failed, or timed out), else the RequestState — possibly a NEW
-        one after a dedupe-safe resubmission — to keep polling.
+        one after a dedupe-safe resubmission — to keep waiting for.
         ``acc`` is the calling worker's counters."""
         from ..nodehost import _CODE_ERRORS, TimeoutError_
 
@@ -1161,7 +1278,7 @@ class Gateway:
 
     # -- observability ----------------------------------------------------------
     def _worker_total(self, key: str):
-        return sum(acc[key] for acc in self._worker_acc)
+        return sum(w.acc[key] for w in self._wstate)
 
     def stats(self) -> dict:
         with self._done_lock:
@@ -1182,7 +1299,9 @@ class Gateway:
             },
             # the propose path, summed over the workers: submissions
             # (retries included), admit -> first propose, node's notify
-            # -> the poll that saw it, pending pairs examined and passes
+            # -> the worker's look at it, _poll_finish calls and the
+            # passes that made any, returns of the workers' wait and
+            # those the clock made, the workers' own processor time
             **{k: self._worker_total(k) for k in _WORKER_COUNTERS},
             "reroutes": self._reroutes,
             # per-consistency-path serve counts + the router's observed
@@ -1212,8 +1331,8 @@ class Gateway:
             # hosts outlive the gateway: give them their configured
             # caps back (see _retire_cap_loop)
             self._retire_cap_loop(ent)
-        for ev in self._wake_events:
-            ev.set()
+        for w in self._wstate:
+            w.event.set()
         for t in self._workers:
             t.join(timeout=2.0)
         for nh, tap in self._taps:

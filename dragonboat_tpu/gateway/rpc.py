@@ -514,7 +514,7 @@ class _RemoteCall:
 
     __slots__ = ("req_id", "op", "noop", "sent", "expires", "code",
                  "result", "resp", "error", "span", "traced", "_event",
-                 "t_notified")
+                 "t_notified", "waker")
 
     def __init__(self, req_id: int, op: int, noop: bool, expires: float):
         self.req_id = req_id
@@ -532,6 +532,7 @@ class _RemoteCall:
         self.traced = False
         self._event = threading.Event()
         self.t_notified = 0.0  # as RequestState's
+        self.waker = None  # as RequestState's: called last in notify
 
     def notify(self, code: RequestResultCode, result=None, resp=None,
                error: str = "") -> None:
@@ -546,6 +547,9 @@ class _RemoteCall:
             sp.end(
                 "ok" if code == RequestResultCode.COMPLETED else code.name
             )
+        w = self.waker
+        if w is not None:
+            w(self)
 
     def wait(self, timeout: float) -> RequestResultCode:
         if not self._event.wait(timeout):

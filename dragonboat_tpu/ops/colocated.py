@@ -589,6 +589,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         # _coalesce); 0 = never scanned yet
         self._last_coalesce_scan = 0.0
         self._scan_cost = 0.0
+        self._scan_skipped = False  # the last launch went without one
         # adaptive device-select capacities for the single-sync launch
         # blob (see _select_and_blob): detail rows are ~2 KB each so
         # CAP_D tracks actual peaks tightly; vals rows are 40 B so
@@ -1642,8 +1643,20 @@ class ColocatedVectorEngine(VectorStepEngine):
         # scan can never consume more than ~10% of wall time: at 250k
         # resident rows one scan is 1-2 s of Python and a fixed 200 ms
         # interval let it dominate the 50k-shard election
-        if now - self._last_coalesce_scan < max(0.2, 10 * self._scan_cost):
+        # Inside the 200 ms floor at most ONE launch goes without a
+        # scan: a launch that carries its caller's nodes alone feeds
+        # ticks to that member's rows alone, and when launches of 80 ms
+        # went two and three between scans, a member that won the lock
+        # twice fed its followers twice between two anchors of their
+        # leaders, whose leases then ran out on the followers' clocks
+        # (hostplane.LeaseAges; PERF.md section 6, PR 34:
+        # lease_read_pct 99 -> 95 at P=5 on the chip)
+        since = now - self._last_coalesce_scan
+        if since < 10 * self._scan_cost or (
+                since < 0.2 and not self._scan_skipped):
+            self._scan_skipped = True
             return list(nodes)
+        self._scan_skipped = False
         self._last_coalesce_scan = now
         seen = {id(n) for n in nodes}
         out = list(nodes)

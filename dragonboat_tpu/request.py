@@ -114,7 +114,7 @@ class RequestState:
     timed-out, terminated and sealed futures alike."""
 
     __slots__ = ("key", "deadline", "_event", "code", "result", "_committed",
-                 "span", "t_notified")
+                 "span", "t_notified", "waker")
 
     def __init__(self, key: int, deadline: int):
         self.key = key
@@ -127,6 +127,12 @@ class RequestState:
         # time.monotonic() at notify: a poller's lag behind the
         # completion (the gateway's t_ack_lag_ms) runs from it
         self.t_notified = 0.0
+        # waker(self), called last in notify, for a waiter that sleeps
+        # on something else than _event (a gateway worker).  It runs on
+        # the notifying thread, under whatever that thread holds, so it
+        # may queue and wake and nothing more.  Whoever sets it reads
+        # _event afterwards: a notify that ran first called nobody
+        self.waker = None
 
     # -- completion (engine side) ---------------------------------------
     def notify(self, code: RequestResultCode, result: Optional[Result] = None):
@@ -138,6 +144,9 @@ class RequestState:
             s.end(status=code.name if code is not None else "unknown")
         self.t_notified = time.monotonic()
         self._event.set()
+        w = self.waker
+        if w is not None:
+            w(self)
 
     def notify_committed(self):
         self._committed = True

@@ -597,3 +597,40 @@ class TestChipSmoke:
         want = os.path.join(os.path.dirname(self.SMOKE), ".jax_cache")
         assert placement.configure_compile_cache(FakeJax) == want
         assert ("jax_compilation_cache_dir", want) in FakeJax.config.calls
+
+
+def test_coalesce_scan_skips_one_launch_inside_its_floor_and_no_second():
+    """A launch that carries its caller's nodes alone feeds ticks to
+    that member's rows alone; inside the scan's 200 ms floor at most
+    ONE launch goes without a scan (two in a row starved the leases of
+    the other members' leaders: PERF.md section 6, PR 34), and the
+    cost rule (a scan at most every ten times its own length) still
+    overrides that."""
+    from types import SimpleNamespace
+
+    from dragonboat_tpu.ops.colocated import ColocatedVectorEngine
+
+    busy = SimpleNamespace(stopped=False, stopping=False,
+                           has_work=lambda: True)
+    core = SimpleNamespace(
+        _last_coalesce_scan=0.0, _scan_cost=0.0, _scan_skipped=False,
+        _meta={1: SimpleNamespace(node=busy)}, stats={"coalesced_rows": 0})
+
+    def launch():
+        time.sleep(0.002)  # well over ten times what this scan costs
+        return len(ColocatedVectorEngine._coalesce(core, []))
+
+    # 2 ms apart, well inside the floor: scan, skip, scan, skip
+    assert [launch() for _ in range(6)] == [1, 0, 1, 0, 1, 0]
+    assert core.stats["coalesced_rows"] == 3
+    # past the floor a scan follows a scan
+    core._last_coalesce_scan = time.monotonic() - 0.3
+    core._scan_skipped = False
+    assert launch() == 1
+    # a scan that costs 50 ms is made at most every 500 ms, whatever
+    # was skipped (the mass-start case the throttle was written for)
+    core._scan_cost = 0.05
+    core._last_coalesce_scan = time.monotonic() - 0.3
+    assert [launch() for _ in range(4)] == [0, 0, 0, 0]
+    core._last_coalesce_scan = time.monotonic() - 0.6
+    assert launch() == 1

@@ -877,3 +877,324 @@ class TestCapFeedbackWiring:
             assert gw.cap_feedback_stats() == {}
         finally:
             gw.close()
+
+
+# ---------------------------------------------------------------------------
+# the worker wakes on events (docs/GATEWAY.md "The worker")
+# ---------------------------------------------------------------------------
+class _ScriptedHost:
+    """A NodeHost stand-in for the propose path alone: it leads every
+    shard, records what it was asked in order, and answers each
+    proposal as ``answer(shard_id, cmd)`` says -- ``"now"`` (notified
+    COMPLETED before ``propose`` returns), ``"dropped"`` (notified
+    DROPPED before it returns), ``"soon"`` (COMPLETED from another
+    thread, 10 ms later) or ``"never"``.  ``gate``, when given, holds
+    the proposal of ``b"plug"`` inside ``propose`` until it is set."""
+
+    _closed = False
+
+    def __init__(self, answer=lambda shard_id, cmd: "now", gate=None):
+        self.answer = answer
+        self.gate = gate
+        self.asked = []   # (shard_id, cmd) in the order proposed
+        self.states = []
+
+    def is_leader_of(self, shard_id):
+        return True
+
+    def _get_node(self, shard_id):
+        return object()
+
+    def add_event_tap(self, tap):
+        pass
+
+    def remove_event_tap(self, tap):
+        pass
+
+    def propose(self, session, cmd, timeout, parent=None, forward=True):
+        from dragonboat_tpu.request import RequestResultCode, RequestState
+
+        if self.gate is not None and cmd == b"plug":
+            assert self.gate.wait(10.0)
+        self.asked.append((session.shard_id, cmd))
+        rs = RequestState(len(self.asked), 0)
+        self.states.append(rs)
+        how = self.answer(session.shard_id, cmd)
+        if how == "now":
+            rs.notify(RequestResultCode.COMPLETED)
+        elif how == "dropped":
+            rs.notify(RequestResultCode.DROPPED)
+        elif how == "soon":
+            threading.Timer(
+                0.01, rs.notify, (RequestResultCode.COMPLETED,)).start()
+        return rs
+
+
+def _scripted_gw(host, **config):
+    config.setdefault("cap_feedback", False)
+    return Gateway({"h1": host}, GatewayConfig(**config))
+
+
+def _wakes_few_checks_no_timed_wake():
+    shards = (1, 2, 3, 4, 5)
+    addrs, nhs = make_gw_cluster(shards=shards, tag="gwt-wake")
+    gw = Gateway(nhs, GatewayConfig(workers=2))
+    try:
+        handles = []
+        for sid in shards:
+            wait_leader(nhs, sid)
+            handles.append(gw.noop_handle(sid))
+            handles[-1].sync_propose(set_cmd("warm", 0), 20.0)  # the route
+        s0 = gw.stats()
+        futs = [handles[i % len(handles)].propose(set_cmd(f"k{i}", i), 20.0)
+                for i in range(60)]
+        for f in futs:
+            f.result(20.0)
+        s1 = gw.stats()
+        d = {k: s1[k] - s0[k] for k in (
+            "committed", "poll_checks", "poll_passes", "wakes",
+            "wakes_timed", "t_worker_cpu_ms")}
+        assert d["committed"] == 60
+        # one check a notified pair, and one more only after a DROPPED
+        assert d["committed"] <= d["poll_checks"] <= 2 * d["committed"], d
+        assert 0 < d["poll_passes"] <= d["poll_checks"]
+        assert d["wakes_timed"] == 0, d
+        # a wake brings a submission or a completion: never more wakes
+        # than those, with one to spare for a set() that landed late
+        assert 0 < d["wakes"] <= 2 * d["committed"] + 2, d
+        assert d["t_worker_cpu_ms"] > 0.0
+    finally:
+        close_all(nhs, gw)
+
+
+def _notify_before_arm_is_not_lost():
+    host = _ScriptedHost()  # every RequestState comes back notified
+    gw = _scripted_gw(host, workers=2)
+    try:
+        futs = [gw.noop_handle(sid).propose(b"c%d" % sid, 5.0)
+                for sid in range(1, 9)]
+        for f in futs:
+            f.result(5.0)
+        st = gw.stats()
+        assert st["committed"] == 8 and st["failed"] == 0
+        assert st["poll_checks"] == 8 and st["wakes_timed"] == 0
+        # armed all the same: a later notify would have found a waker
+        assert all(rs.waker is not None for rs in host.states)
+    finally:
+        gw.close()
+
+
+def _never_notified_times_out_by_the_clock_and_blocks_nobody():
+    from dragonboat_tpu.nodehost import TimeoutError_
+
+    # shards 2 and 4 are the same worker's (of two); 2 lost its quorum
+    host = _ScriptedHost(
+        lambda sid, cmd: {2: "never", 4: "soon"}.get(sid, "now"))
+    gw = _scripted_gw(host, workers=2)
+    try:
+        t0 = time.monotonic()
+        lost = gw.noop_handle(2).propose(b"lost", 0.4)
+        h4, h6 = gw.noop_handle(4), gw.noop_handle(6)
+        for i in range(5):
+            h4.sync_propose(b"live%d" % i, 5.0)
+        for i in range(300):
+            h6.sync_propose(b"more%d" % i, 5.0)
+        assert time.monotonic() - t0 < 0.35 and not lost.done()
+        # the answered ones do not queue up behind the lost one's
+        # deadline for the length of it
+        assert len(gw._wstate[0].expiry) <= 2 * 1 + 64 + 1
+        with pytest.raises(TimeoutError_):
+            lost.result(5.0)
+        assert 0.4 <= lost.t_done - t0 < 0.5
+        st = gw.stats()
+        assert st["committed"] == 305 and st["failed"] == 1
+        # the lost pair was looked at once, when its deadline came
+        assert st["poll_checks"] == 306 and st["wakes_timed"] == 1, st
+    finally:
+        gw.close()
+
+
+def _a_leaderless_shard_is_asked_again_at_a_pause_that_doubles():
+    from dragonboat_tpu.nodehost import RequestDropped
+
+    host = _ScriptedHost(lambda sid, cmd: "dropped" if sid == 2 else "soon")
+    gw = _scripted_gw(host, workers=1)
+    try:
+        t0 = time.monotonic()
+        lost = gw.noop_handle(2).propose(b"lost", 0.3)
+        gw.noop_handle(4).sync_propose(b"live", 5.0)
+        assert time.monotonic() - t0 < 0.25
+        with pytest.raises(RequestDropped):
+            lost.result(5.0)
+        # at once, then after 1, 2, 4, 8, 16, 32, 32, ... ms: ~14 in
+        # 0.3 s, where asking as fast as it answers would be thousands
+        asked = sum(1 for sid, _ in host.asked if sid == 2)
+        assert 6 <= asked <= 20, asked
+        st = gw.stats()
+        assert st["reroutes"] == asked - 1 and st["proposed"] == asked + 1
+        assert st["wakes_timed"] <= asked
+    finally:
+        gw.close()
+
+
+def _arm_against_notify_loses_nothing_and_checks_nothing_twice():
+    import queue
+    import sys
+
+    # the notify of every proposal races the worker's arm of it: four
+    # threads complete what the host was just asked, while the worker
+    # is between ``nh.propose`` returning and ``rs.waker`` being set
+    asked = queue.SimpleQueue()
+    host = _ScriptedHost(lambda sid, cmd: "never")
+    propose = host.propose
+
+    def racing_propose(*a, **kw):
+        rs = propose(*a, **kw)
+        asked.put(rs)
+        return rs
+
+    def notifier():
+        from dragonboat_tpu.request import RequestResultCode
+
+        while True:
+            rs = asked.get()
+            if rs is None:
+                return
+            rs.notify(RequestResultCode.COMPLETED)
+
+    host.propose = racing_propose
+    notifiers = [threading.Thread(target=notifier, daemon=True)
+                 for _ in range(4)]
+    gw = _scripted_gw(host, workers=2)
+    clients, each, errors = 16, 150, []
+
+    def client(i):
+        h = gw.noop_handle(1 + i % 8)
+        try:
+            for n in range(each):
+                h.sync_propose(b"%d-%d" % (i, n), 20.0)
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in notifiers:
+            t.start()
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60.0)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+        for _ in notifiers:
+            asked.put(None)
+        gw.close()
+    assert not errors, errors[:3]
+    st = gw.stats()
+    assert st["committed"] == clients * each and st["failed"] == 0
+    # a pair reported twice (by its waker and by the look after the
+    # arm) is checked once
+    assert st["poll_checks"] == clients * each
+    assert st["wakes_timed"] == 0
+
+
+def _idle_lanes_cost_no_wake():
+    host = _ScriptedHost()
+    gw = _scripted_gw(host, workers=4)
+    try:
+        futs = [gw.noop_handle(sid).propose(b"x", 10.0)
+                for sid in range(1, 1001)]
+        for f in futs:
+            f.result(10.0)
+        assert len(gw._lanes) == 1000
+        assert _wait_for(lambda: not any(w.pending for w in gw._wstate))
+        time.sleep(0.05)  # a last set() that landed late
+        s0 = gw.stats()
+        time.sleep(0.3)
+        s1 = gw.stats()
+        for k in ("wakes", "wakes_timed", "poll_passes", "t_worker_cpu_ms"):
+            assert s1[k] == s0[k], k
+        # and one more operation is one wake or two, not a scan
+        gw.noop_handle(7).sync_propose(b"y", 5.0)
+        assert 1 <= gw.stats()["wakes"] - s1["wakes"] <= 2
+    finally:
+        gw.close()
+
+
+def _fifo_in_a_shard_and_max_batch_a_pass():
+    gate = threading.Event()
+    host = _ScriptedHost(gate=gate)
+    gw = _scripted_gw(host, workers=1, max_batch=4)
+    try:
+        # the worker sits inside propose(plug) while both lanes fill up
+        futs = [gw.noop_handle(1).propose(b"plug", 10.0)]
+        assert _wait_for(lambda: not gw._lanes.get(1, True))
+        for sid in (1, 2):
+            futs += [gw.noop_handle(sid).propose(b"%d-%02d" % (sid, i), 10.0)
+                     for i in range(10)]
+        gate.set()
+        for f in futs:
+            f.result(10.0)
+        turns = [(1, 0, 4), (2, 0, 4), (1, 4, 8), (2, 4, 8),
+                 (1, 8, 10), (2, 8, 10)]
+        assert host.asked == [(1, b"plug")] + [
+            (sid, b"%d-%02d" % (sid, i))
+            for sid, lo, hi in turns for i in range(lo, hi)]
+    finally:
+        gate.set()
+        gw.close()
+
+
+def _close_seals_the_queued_and_marks_the_pending_ambiguous():
+    from dragonboat_tpu.client import Session
+    from dragonboat_tpu.gateway import ClientHandle
+
+    gate = threading.Event()
+    host = _ScriptedHost(lambda sid, cmd: "never", gate=gate)
+    gw = _scripted_gw(host, workers=1)
+    once = Session.new_session(3)
+    once.prepare_for_propose()
+    series = once.series_id
+    h_once, h_noop = ClientHandle(gw, once), gw.noop_handle(1)
+    pend = [h_once.propose(b"p-once", 10.0), h_noop.propose(b"p-noop", 10.0)]
+    assert _wait_for(lambda: len(gw._wstate[0].pending) == 2)
+    noop_req = next(r for r in gw._wstate[0].pending if r.handle is h_noop)
+    behind = h_noop.propose(b"behind-its-handle", 10.0)
+    plug = gw.noop_handle(5).propose(b"plug", 10.0)  # holds the worker
+    assert _wait_for(lambda: not gw._lanes.get(5, True))
+    in_lane = gw.noop_handle(7).propose(b"in-its-lane", 10.0)
+    closer = threading.Thread(target=gw.close)
+    closer.start()
+    assert _wait_for(lambda: gw._closed)
+    gate.set()
+    closer.join(10.0)
+    assert not closer.is_alive()
+    for f in pend + [behind, plug, in_lane]:
+        with pytest.raises(GatewayClosed):
+            f.result(5.0)
+    # neither queued request was ever proposed
+    assert sorted(c for _, c in host.asked) == [b"p-noop", b"p-once", b"plug"]
+    # the pending ones may still commit: the exactly-once series is
+    # burned, the at-most-once request stays marked
+    assert once.series_id == series + 1
+    assert noop_req.ambiguous
+    with pytest.raises(GatewayClosed):
+        h_noop.propose(b"late", 1.0)
+
+
+@pytest.mark.parametrize("scenario", [
+    _wakes_few_checks_no_timed_wake,
+    _notify_before_arm_is_not_lost,
+    _never_notified_times_out_by_the_clock_and_blocks_nobody,
+    _a_leaderless_shard_is_asked_again_at_a_pause_that_doubles,
+    _arm_against_notify_loses_nothing_and_checks_nothing_twice,
+    _idle_lanes_cost_no_wake,
+    _fifo_in_a_shard_and_max_batch_a_pass,
+    _close_seals_the_queued_and_marks_the_pending_ambiguous,
+], ids=lambda fn: fn.__name__.strip("_"))
+def test_worker_wakes_on_events(scenario):
+    scenario()

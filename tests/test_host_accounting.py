@@ -59,7 +59,8 @@ ENGINE_KEYS = PHASES + (
 )
 GATEWAY_KEYS = (
     "proposed", "t_queue_wait_ms", "t_ack_lag_ms", "poll_checks",
-    "poll_passes", "read_fallback_not_leader",
+    "poll_passes", "wakes", "wakes_timed", "t_worker_cpu_ms",
+    "read_fallback_not_leader",
     "read_fallback_no_commit_in_term", "read_fallback_apply_lag",
     "read_fallback_lease_expiring", "reroutes",
 )
@@ -253,6 +254,8 @@ def test_gateway_counts_queue_wait_ack_lag_and_polls(cluster):
     assert d["t_queue_wait_ms"] >= 0 and d["t_ack_lag_ms"] >= 0
     assert d["poll_checks"] >= d["committed"]
     assert 0 < d["poll_passes"] <= d["poll_checks"]
+    assert 0 <= d["wakes_timed"] < d["wakes"]
+    assert d["t_worker_cpu_ms"] > 0.0
     for f in futs:
         assert f.t_done > 0.0
 
