@@ -8,6 +8,9 @@ reference: engine.go [U].  The shape is the reference's exactly:
     for all their Updates (the single-fsync-per-iteration trick), then
     ``node.process_update`` per shard (send + schedule apply);
   * **apply workers** drain ``rsm.TaskQueue``s;
+  * **snapshot workers** carry out the saves that apply workers and
+    callers ask for (``Node.save_snapshot``), so that a save's file
+    writes and syncs never hold a step;
   * ``WorkReady`` is the per-partition ready-set + condition pair so idle
     shards cost nothing.
 
@@ -74,7 +77,12 @@ class WorkReady:
                 self._sets[p].update(ids)
                 self._conds[p].notify()
 
-    def wait(self, p: int, timeout: float, stop: threading.Event) -> List[int]:
+    def wait(
+        self, p: int, timeout: Optional[float], stop: threading.Event
+    ) -> List[int]:
+        """``timeout`` None waits for a notify alone: ``wake`` after the
+        stop event is set reaches a waiter that checked it, because both
+        hold the condition's lock."""
         with self._conds[p]:
             if not self._sets[p] and not stop.is_set():
                 self._conds[p].wait(timeout)
@@ -152,6 +160,7 @@ class ExecEngine:
         logdb,
         step_workers: int = 16,
         apply_workers: int = 16,
+        snapshot_workers: int = 48,
         step_engine: Optional[IStepEngine] = None,
         metrics=None,
     ):
@@ -188,6 +197,7 @@ class ExecEngine:
         ]
         self.step_ready = WorkReady(step_workers)
         self.apply_ready = WorkReady(apply_workers)
+        self.snapshot_ready = WorkReady(snapshot_workers)
         self.step_engine = step_engine or HostStepEngine(logdb)
         self._nodes: Dict[int, "Node"] = {}  # shard_id -> node
         self._nodes_lock = threading.RLock()
@@ -201,6 +211,9 @@ class ExecEngine:
         ] + [
             (self._apply_worker_main, f"tpu-raft-apply-{i}")
             for i in range(apply_workers)
+        ] + [
+            (self._snapshot_worker_main, f"tpu-raft-snapsave-{i}")
+            for i in range(snapshot_workers)
         ]
 
     def start(self) -> None:
@@ -213,6 +226,7 @@ class ExecEngine:
         self._stop.set()
         self.step_ready.wake()
         self.apply_ready.wake()
+        self.snapshot_ready.wake()
         # the join must outlast one worst-case step iteration: in
         # colocated mode a worker can be blocked on the shared core lock
         # behind another member's full-width launch (multi-second at 64k
@@ -230,6 +244,7 @@ class ExecEngine:
         # a stale workReady entry for this shard id can step it immediately
         node.notify_work = lambda s=node.shard_id: self.step_ready.notify(s)
         node.engine_apply_ready = lambda s: self.apply_ready.notify(s)
+        node.engine_snapshot_ready = self.snapshot_ready.notify
         # the WorkReady itself, for the batched per-SM-worker commit
         # handoff (ops/engine._apply_lane_commits): one notify_all per
         # partition per generation instead of one lock take per row
@@ -326,3 +341,15 @@ class ExecEngine:
                 # applying may have unblocked step work (e.g. config change)
                 if node.has_work():
                     self.step_ready.notify(node.shard_id)
+
+    def _snapshot_worker_main(self, worker_id: int) -> None:
+        # no timed wait: an idle worker costs nothing, and there are
+        # many (reference: EngineConfig.SnapshotShards [U])
+        while not self._stop.is_set():
+            ready = self.snapshot_ready.wait(worker_id, None, self._stop)
+            if self._stop.is_set():
+                return
+            with self._nodes_lock:
+                nodes = [self._nodes[s] for s in ready if s in self._nodes]
+            for node in nodes:
+                node.save_snapshot()  # counts its own failures

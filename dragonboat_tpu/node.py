@@ -60,7 +60,8 @@ from .rsm.statemachine import (
     Task,
     TaskType,
 )
-from .statemachine import Result
+from .profiling import annotate
+from .statemachine import Result, SnapshotStopped
 from .storage.logdb import LogDBLogReader
 from .storage.snapshotio import SnapshotReader, _try_snappy
 
@@ -87,7 +88,6 @@ class StepInputs:
         "config_changes",
         "cc_results",
         "transfers",
-        "snapshot_reqs",
         "ticks",
         "gc_ticks",
     )
@@ -100,7 +100,6 @@ class StepInputs:
         config_changes=(),
         cc_results=(),
         transfers=(),
-        snapshot_reqs=(),
         ticks=0,
         gc_ticks=0,
     ):
@@ -113,7 +112,6 @@ class StepInputs:
         self.config_changes = list(config_changes) if config_changes else ()
         self.cc_results = list(cc_results) if cc_results else ()
         self.transfers = list(transfers) if transfers else ()
-        self.snapshot_reqs = list(snapshot_reqs) if snapshot_reqs else ()
         self.ticks = ticks
         # ticks DROPPED by the add_tick backlog cap: they advance the
         # logical clock (future deadlines are measured on it, so client
@@ -132,7 +130,7 @@ class Node:
         "transport", "on_leader_updated", "events", "registry",
         "host_totals",
         "_qlock", "_received", "_proposals", "_read_indexes",
-        "_config_changes", "_cc_to_apply", "_snapshot_reqs",
+        "_config_changes", "_cc_to_apply", "_snapshot_req",
         "_leader_transfers", "_doomed", "_pending_ticks",
         "_ticks_in", "_ticks_taken",
         "pending_proposal", "pending_read_index", "pending_config_change",
@@ -143,6 +141,7 @@ class Node:
         "_snapshotting",
         "_applied_since_snapshot", "_retired_snapshots", "_apply_lock",
         "_sm_close_lock", "notify_work", "engine_apply_ready",
+        "engine_snapshot_ready",
         "apply_work_ready", "step_work_ready",
         "log_reader", "sm", "_stop_event", "peer", "quiesce",
         "wake", "parked_at_tick", "tracer", "_trace_spans",
@@ -199,7 +198,6 @@ class Node:
         self._read_indexes: list = []  # SystemCtx; guarded-by: _qlock
         self._config_changes: list = []  # (key, ConfigChange); guarded-by: _qlock
         self._cc_to_apply: list = []  # (ConfigChange|None, accepted); guarded-by: _qlock
-        self._snapshot_reqs: list = []  # (key, overhead); guarded-by: _qlock
         self._leader_transfers: list = []  # target; guarded-by: _qlock
         # (conflict index, old term there, [(table, key)]): proposals
         # made here whose entries another leader's replaced, waiting for
@@ -297,14 +295,20 @@ class Node:
         # sets it on every node before unregistering; a half-closed
         # cluster otherwise keeps electing rows whose hosts are gone)
         self.stopping = False
-        self._snapshotting = False
-        self._applied_since_snapshot = 0
+        # one save a replica at a time: True from the request that a
+        # snapshot worker will take until that save has ended, whatever
+        # its end; a request that meets it is counted snapshots_skipped
+        self._snapshotting = False  # guarded-by: _qlock
+        # (key, overhead, perf_counter() at the request) handed to the
+        # snapshot workers and not yet taken by one
+        self._snapshot_req: Optional[tuple] = None  # guarded-by: _qlock
+        self._applied_since_snapshot = 0  # apply worker only
         # superseded snapshot files are kept for one extra generation: an
-        # InstallSnapshot message produced earlier in the SAME step() can
-        # still reference the previous file at transport-send time (the
-        # payload is read synchronously on this worker; see
-        # Transport.send_snapshot)
-        self._retired_snapshots: List[str] = []
+        # InstallSnapshot message the step worker made from the reader's
+        # newest record can still name the previous file when the stream
+        # job opens it (Transport.send_snapshot).  Written by the
+        # snapshot worker inside a save and by stop()
+        self._retired_snapshots: List[str] = []  # guarded-by: _sm_close_lock
         # serializes apply() against stop() so the user SM is never closed
         # mid-update
         self._apply_lock = threading.Lock()
@@ -315,6 +319,9 @@ class Node:
         # set by the engine at registration; wakes the owning step worker
         self.notify_work: Optional[Callable[[], None]] = None
         self.engine_apply_ready: Optional[Callable[[int], None]] = None
+        # wakes a snapshot worker for this shard (set at registration
+        # too); a replica no engine knows has none, and saves nothing
+        self.engine_snapshot_ready: Optional[Callable[[int], None]] = None
         # the apply workers' WorkReady itself (also set at registration):
         # the batched per-SM-worker commit handoff groups wakeups by
         # partition through it (engine._apply_lane_commits) instead of
@@ -444,7 +451,6 @@ class Node:
             and not self._read_indexes
             and not self._config_changes
             and not self._cc_to_apply
-            and not self._snapshot_reqs
             and not self._leader_transfers
             and not self.pending_proposal._pending
             and not self.pending_read_index._pending
@@ -562,9 +568,8 @@ class Node:
 
     def request_snapshot(self, overhead: int, timeout_ticks: int) -> RequestState:
         rs = self.pending_snapshot.request(self.tick_count + timeout_ticks)
-        with self._qlock:
-            self._snapshot_reqs.append((rs.key, overhead))
-        self._wake()
+        self._wake()  # a parked clock would never time the request out
+        self._request_save(rs.key, overhead)
         if self.stopped:
             self.pending_snapshot.seal(rs)
         return rs
@@ -611,7 +616,6 @@ class Node:
             + len(self._read_indexes)
             + len(self._config_changes)
             + len(self._cc_to_apply)
-            + len(self._snapshot_reqs)
             + len(self._leader_transfers)
         )
 
@@ -635,7 +639,6 @@ class Node:
             or self._read_indexes
             or self._config_changes
             or self._cc_to_apply
-            or self._snapshot_reqs
             or self._leader_transfers
             or self._pending_ticks
             or self._ticks_in != self._ticks_taken
@@ -688,9 +691,6 @@ class Node:
             if self._leader_transfers:
                 si.transfers = self._leader_transfers
                 self._leader_transfers = []
-            if self._snapshot_reqs:
-                si.snapshot_reqs = self._snapshot_reqs
-                self._snapshot_reqs = []
             self._pending_ticks = 0
         return si
 
@@ -781,7 +781,6 @@ class Node:
         config_changes = si.config_changes
         cc_results = si.cc_results
         transfers = si.transfers
-        snapshot_reqs = si.snapshot_reqs
         ticks = si.ticks
         # cap ticks per step at half an election window: the reference's
         # ticker delivers ticks ONE at a time interleaved with message
@@ -863,8 +862,6 @@ class Node:
             self.peer.read_index(ctx)
         for target in transfers:
             self.peer.request_leader_transfer(target)
-        for key, overhead in snapshot_reqs:
-            self._save_snapshot_request(key, overhead)
 
         for _ in range(ticks):
             self.tick_count += 1
@@ -1193,6 +1190,8 @@ class Node:
             )
             self.log_reader.append(u.entries_to_save)
         for m in u.messages:
+            if m.type == MessageType.INSTALL_SNAPSHOT:
+                self.host_totals.add("snapshots_streamed")
             self.transport.send(m)
         if u.ready_to_reads:
             for rtr in u.ready_to_reads:
@@ -1250,13 +1249,16 @@ class Node:
                 self._recover_from_snapshot(task.snapshot)
         self.pending_read_index.applied(self.sm.last_applied)
         self.peer.notify_raft_last_applied(self.sm.last_applied)
-        if (
-            self.config.snapshot_entries > 0
-            and self._applied_since_snapshot >= self.config.snapshot_entries
-        ):
-            self._applied_since_snapshot = 0
-            with self._qlock:
-                self._snapshot_reqs.append((0, self.config.compaction_overhead))
+        every = self.config.snapshot_entries
+        if every > 0 and self._applied_since_snapshot >= every:
+            # one request per snapshot_entries applied, so that requests
+            # = saved + skipped + failed counts the entries gone by; the
+            # second of one drain meets the first in flight
+            due, self._applied_since_snapshot = divmod(
+                self._applied_since_snapshot, every
+            )
+            for _ in range(due):
+                self._request_save(0, self.config.compaction_overhead)
         wal_now = managed.wal_counts()
         return {
             "apply_batches": batches, "apply_entries": entries,
@@ -1372,6 +1374,7 @@ class Node:
             self.sm.recover_from_snapshot_stream(reader, files)
         finally:
             f.close()
+        self.host_totals.add("snapshots_recovered")
 
     def _recover_from_snapshot(self, ss: Snapshot) -> None:
         if ss.dummy or self.config.is_witness:
@@ -1379,7 +1382,8 @@ class Node:
             self.sm.members.restore(ss.membership)
             return
         try:
-            self._recover_sm_from_storage(ss)
+            with annotate("raft-snapshot-recover"):
+                self._recover_sm_from_storage(ss)
         except Exception as e:  # noqa: BLE001 — any load/decode failure
             # the raft log was already reset to ss.index; applying anything
             # past it without this state would silently diverge — halt the
@@ -1401,7 +1405,29 @@ class Node:
             )
 
     # ------------------------------------------------------------------
-    # snapshotting (step-worker context for now; dedicated workers later)
+    # snapshotting.  A save is ASKED FOR on any thread (_request_save: the
+    # apply worker once per snapshot_entries, NodeHost.sync_request_
+    # snapshot) and CARRIED OUT on one of the exec engine's snapshot
+    # workers (save_snapshot; reference: engine.go snapshot worker pool,
+    # EngineConfig.SnapshotShards [U]) — never on a step worker, whose
+    # thread is the whole cluster's launch loop in colocated mode, and
+    # never as a step input: raft's state does not change when a replica
+    # saves, so the row stays where it is.  What the save shares, and
+    # with whom:
+    #   * _snapshotting, _snapshot_req — requesters: _qlock;
+    #   * the user SM — the apply worker: rsm's _mu orders the capture
+    #     against updates (a regular SM serializes under it); stop():
+    #     _sm_close_lock, held for the WHOLE save, so the SM is not
+    #     closed under a stream nor the LogDB under the record;
+    #   * log_reader — the step worker (append, apply_snapshot, every
+    #     read of the scalar replica): LogDBLogReader's own lock orders
+    #     the mutators, its readers are lock-free and meet a compaction
+    #     as LogCompactedError, which every reader of the log handles;
+    #   * logdb — the step worker's save_raft_state: ILogDB mutators
+    #     lock themselves, and a snapshot record or a removal (entries
+    #     at or below the applied index) commutes with the appends
+    #     (entries above the commit index) that it may overtake;
+    #   * _retired_snapshots — stop(): _sm_close_lock.
     # ------------------------------------------------------------------
     def _snapshot_compression(self):
         """The per-block codec recorded in the container AND in the
@@ -1414,62 +1440,105 @@ class Node:
             return CompressionType.ZLIB  # meta records what is actually used
         return want
 
-    def _save_snapshot_request(self, key: int, overhead: int) -> None:
-        """Save a snapshot of the current applied state and compact the log
-        (reference: rsm.SaveSnapshot + snapshotter [U])."""
-        if self._snapshotting:
+    def _request_save(self, key: int, overhead: int) -> None:
+        """Hand one save to the snapshot workers (any thread).  One a
+        replica at a time: a request that meets one queued or running is
+        counted ``snapshots_skipped`` and its future, if it has one,
+        told so."""
+        ready = self.engine_snapshot_ready
+        with self._qlock:
+            taken = not (
+                self._snapshotting or self.stopped or ready is None
+            )
+            if taken:
+                self._snapshotting = True
+                self._snapshot_req = (key, overhead, time.perf_counter())
+        if taken:
+            self.host_totals.add("snapshots_requested")
+            ready(self.shard_id)
+            return
+        self.host_totals.add_many(
+            {"snapshots_requested": 1, "snapshots_skipped": 1}
+        )
+        if key:
+            self.pending_snapshot.done(key, 0, failed=True)
+
+    def save_snapshot(self) -> None:
+        """Carry out the request handed over by ``_request_save``
+        (snapshot worker only)."""
+        with self._qlock:
+            req, self._snapshot_req = self._snapshot_req, None
+        if req is None:
+            return
+        key, overhead, t_req = req
+        t0 = time.perf_counter()
+        counts = {"t_snapshot_wait_s": t0 - t_req}
+        try:
+            with annotate("raft-snapshot-save"):
+                saved = self._save_snapshot(key, overhead, counts)
+            counts["snapshots_saved" if saved else "snapshots_skipped"] = 1
+        except Exception as e:  # noqa: BLE001 — counted, never a dead worker
+            if isinstance(e, SnapshotStopped) or self.stopped:
+                counts["snapshots_skipped"] = 1  # the replica is going away
+            else:
+                counts["snapshot_failures"] = 1
+                _log.exception(
+                    "[%d:%d] snapshot save failed",
+                    self.shard_id, self.replica_id,
+                )
             if key:
                 self.pending_snapshot.done(key, 0, failed=True)
-            return
-        self._snapshotting = True
-        try:
-            with self._apply_lock:
-                if self.stopped:
-                    if key:
-                        self.pending_snapshot.done(key, 0, failed=True)
-                    return
-                index = self.sm.last_applied
-                prev = self.logdb.get_snapshot(self.shard_id, self.replica_id)
-                if index == 0 or prev.index >= index:
-                    if key:
-                        self.pending_snapshot.done(key, 0, failed=True)
-                    return
-                compression = self._snapshot_compression()
+        finally:
+            counts["t_snapshot_save_s"] = time.perf_counter() - t0
+            with self._qlock:
+                self._snapshotting = False
+            self.host_totals.add_many(counts)
+
+    def _save_snapshot(self, key: int, overhead: int, counts: dict) -> bool:
+        """Save a snapshot of the current applied state and compact the log
+        (reference: rsm.SaveSnapshot + snapshotter [U]).  False when
+        there was nothing to save.  Nothing is dropped from the log
+        before ``save_snapshots`` has made the snapshot's record durable,
+        and then only entries at or below ``index - overhead``."""
+        with self._sm_close_lock:
+            if self.stopped:
+                raise SnapshotStopped()
+            index = self.sm.last_applied
+            prev = self.logdb.get_snapshot(self.shard_id, self.replica_id)
+            if index == 0 or prev.index >= index:
+                if key:
+                    self.pending_snapshot.done(key, 0, failed=True)
+                return False
+            compression = self._snapshot_compression()
 
             def build(fileobj, copy_fn):
                 coll = SnapshotFileCollection(copy_fn)
                 # the SM streams through the v2 block writer with
                 # bounded memory (storage/snapshotio.py); external
-                # files are staged beside the container by copy_fn
+                # files are staged beside the container by copy_fn.
+                # Applies are held only while rsm._mu is: regular SMs
+                # serialize under it, concurrent/on-disk SMs prepare
+                # under it and stream outside (reference: rsm
+                # concurrent snapshot [U]).  The container's index is
+                # captured under rsm._mu inside; the dir is named from
+                # that result, so name and content agree even when
+                # applies advance past the pre-check index.
                 return self.sm.save_snapshot_stream(
                     fileobj,
                     coll,
+                    self._stop_event,
                     compression=int(compression),
                 )
 
-            # the streamed save runs OUTSIDE _apply_lock so a long
-            # disk write never stalls the apply pipeline: regular SMs
-            # serialize under rsm._mu anyway, concurrent/on-disk SMs
-            # prepare under it and stream concurrently (reference: rsm
-            # concurrent snapshot [U]).  _sm_close_lock only excludes
-            # stop() closing the user SM mid-save.  The container's
-            # index is captured under rsm._mu inside build; the dir is
-            # named from that result, so name and content agree even
-            # when applies advance past the pre-check index.
-            with self._sm_close_lock:
-                if self.stopped:
-                    if key:
-                        self.pending_snapshot.done(key, 0, failed=True)
-                    return
-                filepath, (index, term, _files) = (
-                    self.snapshot_storage.save_stream(
-                        self.shard_id,
-                        self.replica_id,
-                        index,
-                        build,
-                        index_from_result=lambda res: res[0],
-                    )
+            filepath, (index, term, _files) = (
+                self.snapshot_storage.save_stream(
+                    self.shard_id,
+                    self.replica_id,
+                    index,
+                    build,
+                    index_from_result=lambda res: res[0],
                 )
+            )
             ss = Snapshot(
                 filepath=filepath,
                 file_size=self.snapshot_storage.file_size(filepath),
@@ -1484,34 +1553,37 @@ class Node:
                 shard_id=self.shard_id, replica_id=self.replica_id, snapshot=ss
             )
             self.logdb.save_snapshots([u])
+            counts["snapshot_bytes"] = ss.file_size
             # the reader must know the snapshot so the leader can stream it
             # to followers that fall behind the compaction point
             self.log_reader.create_snapshot(ss)
             compact_to = max(0, index - max(overhead, 0))
             if compact_to > 0:
-                # compact the reader first: it snapshots the boundary term
-                # while the entry is still readable in the logdb
-                self.log_reader.compact(compact_to)
-                self.logdb.remove_entries_to(
-                    self.shard_id, self.replica_id, compact_to
-                )
+                with annotate("raft-log-compact"):
+                    # compact the reader first: it keeps the boundary
+                    # term while the entry is still readable in the logdb
+                    counts["log_entries_compacted"] = (
+                        self.log_reader.compact(compact_to)
+                    )
+                    self.logdb.remove_entries_to(
+                        self.shard_id, self.replica_id, compact_to
+                    )
             if not prev.is_empty():
                 self._retired_snapshots.append(prev.filepath)
                 self._gc_retired_snapshots()
-            if key:
-                self.pending_snapshot.done(key, index)
-            if self.events is not None:
-                self.events.snapshot_created(
-                    SnapshotInfo(self.shard_id, self.replica_id, 0, index)
+        if key:
+            self.pending_snapshot.done(key, index)
+        if self.events is not None:
+            self.events.snapshot_created(
+                SnapshotInfo(self.shard_id, self.replica_id, 0, index)
+            )
+            if compact_to > 0:
+                self.events.log_compacted(
+                    EntryInfo(self.shard_id, self.replica_id, compact_to)
                 )
-                if compact_to > 0:
-                    self.events.log_compacted(
-                        EntryInfo(self.shard_id, self.replica_id, compact_to)
-                    )
-        finally:
-            self._snapshotting = False
+        return True
 
-    def _gc_retired_snapshots(self) -> None:
+    def _gc_retired_snapshots(self) -> None:  # guarded-by: _sm_close_lock
         """Delete superseded snapshot files, keeping the newest retiree one
         generation longer (see the field comment)."""
         for p in self._retired_snapshots[:-1]:
@@ -1666,6 +1738,13 @@ class Node:
     def stale_read(self, query):
         return self.sm.lookup(query)
 
+    def announce_stop(self) -> None:
+        """Shutdown is coming (NodeHost.close, before the engine's
+        workers are joined): stop participating, and tell a save that is
+        streaming to give up, or the join would wait for it."""
+        self.stopping = True
+        self._stop_event.set()
+
     def stop(self) -> None:
         self.stopping = True
         self.stopped = True
@@ -1675,12 +1754,20 @@ class Node:
         self.pending_config_change.drop_all()
         self.pending_snapshot.drop_all()
         self.pending_leader_transfer.drop_all()
-        # retired files can't be referenced once this replica is down
-        # (receivers own their streamed copies); reclaim them so restarts
-        # don't orphan files
-        for p in self._retired_snapshots:
-            self.snapshot_storage.remove(p)
-        self._retired_snapshots = []
-        # wait for any in-flight apply before closing the user SM
+        # a save asked for and not yet taken never will be: its replica
+        # left the engine's tables first
+        with self._qlock:
+            req, self._snapshot_req = self._snapshot_req, None
+        if req is not None:
+            self.host_totals.add("snapshots_skipped")
+        # wait for any in-flight apply, and for a save to end (a state
+        # machine that honours its ``done`` has just been told to give
+        # up), before closing the user SM
         with self._apply_lock, self._sm_close_lock:
+            # retired files can't be referenced once this replica is
+            # down (receivers own their streamed copies); reclaim them
+            # so restarts don't orphan files
+            for p in self._retired_snapshots:
+                self.snapshot_storage.remove(p)
+            self._retired_snapshots = []
             self.sm.managed.close()

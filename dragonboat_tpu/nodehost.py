@@ -320,6 +320,7 @@ class NodeHost:
                 self.logdb,
                 step_workers=expert.engine.exec_shards,
                 apply_workers=expert.engine.apply_shards,
+                snapshot_workers=expert.engine.snapshot_shards,
                 step_engine=step_engine,
                 metrics=self.metrics,
             )
@@ -389,7 +390,7 @@ class NodeHost:
         # still-participating row of a closing host strands routed
         # payloads and fail-stops healthy peers
         for n in nodes:
-            n.stopping = True
+            n.announce_stop()
         self.engine.unregister_many([n.shard_id for n in nodes])
         # join worker threads before closing the user SMs: an apply worker
         # may still be inside sm.handle
@@ -1017,7 +1018,6 @@ class NodeHost:
             compaction_overhead or node.config.compaction_overhead,
             self._timeout_ticks(timeout),
         )
-        self.engine.notify(shard_id)
         return _check(rs.wait(timeout), rs).value
 
     # -- disaster recovery (bigstate/dr.py; docs/BIGSTATE.md) -----------

@@ -734,6 +734,17 @@ class ColocatedVectorEngine(VectorStepEngine):
             leader_transfers_requested=0, leader_transfers_done=0,
             leader_transfers_aborted=0, t_transfer_ms=0.0,
             proposals_dropped_truncated=0,
+            # and what snapshots cost them (node.py "snapshotting"):
+            # saves asked for and how each ended, from the request to
+            # the save's start on a snapshot worker and inside the save
+            # (compaction included), the containers' bytes, log entries
+            # compacted away, InstallSnapshot messages sent, snapshots
+            # recovered from
+            snapshots_requested=0, snapshots_saved=0, snapshots_skipped=0,
+            snapshot_failures=0, t_snapshot_wait_ms=0.0,
+            t_snapshot_save_ms=0.0, snapshot_bytes=0,
+            log_entries_compacted=0, snapshots_streamed=0,
+            snapshots_recovered=0,
             # pipeline observability: host work overlapped with an
             # in-flight readback request (the double-buffering win),
             # fences (drains to depth 0 forced by membership mutation),
@@ -1487,9 +1498,10 @@ class ColocatedVectorEngine(VectorStepEngine):
         mark the scalar remotes SNAPSHOT — after the materialize, which
         would otherwise overwrite them and re-fire duplicate full
         snapshot streams on every re-upload."""
-        self._evict_rows_to_host(
-            sorted({t[0] for t in below}), "snapshot_below"
-        )
+        gs = sorted({t[0] for t in below})
+        for g in gs:
+            self._count_snapshot_eviction(g)
+        self._evict_rows_to_host(gs, "snapshot_below")
         for g, p, _, pid, ss_index in below:
             meta = self._meta.get(g)
             if meta is None or meta.node.stopped:
@@ -1867,16 +1879,11 @@ class ColocatedVectorEngine(VectorStepEngine):
                     or node._read_indexes
                     or node._config_changes
                     or node._cc_to_apply
-                    or node._snapshot_reqs
                     or node._leader_transfers
                 )
             ):
                 r = node.peer.raft
-                if not (
-                    r.snapshotting
-                    or r.read_index.pending
-                    or r.read_index.queue
-                ):
+                if not (r.read_index.pending or r.read_index.queue):
                     # ONE shared definition of the tick drain/cap/defer
                     # arithmetic (node.drain_ticks_only) — see its
                     # locking contract: this worker holds the core lock
@@ -3690,19 +3697,12 @@ class ColocatedVectorEngine(VectorStepEngine):
             node._check_leader_change()
         self._leave(tok)
 
-        lanes = [t for t in snapshot_sends if t[2] is not None]
-        if lanes:
-            # applied to the CURRENT state handle — possibly one
-            # generation past the one that flagged the need.  Benign:
-            # the need flag re-fires while the condition persists, the
-            # lane write is idempotent, and at most one extra probe
-            # volley reaches a peer already being streamed to
-            self._state = _set_remote_snapshot(
-                self._state,
-                self._put(jnp.asarray(_pad_idx([t[0] for t in lanes]))),
-                self._put(jnp.asarray(_pad_idx([t[1] for t in lanes]))),
-                self._put(jnp.asarray(_pad_idx([t[2] for t in lanes]))),
-            )
+        # applied to the CURRENT state handle — possibly one generation
+        # past the one that flagged the need.  Benign: the need flag
+        # re-fires while the condition persists, the lane write is
+        # idempotent, and at most one extra probe volley reaches a peer
+        # already being streamed to
+        self._mark_remote_snapshots(snapshot_sends)
         below = [t for t in snapshot_sends if t[2] is None]
         if below:
             # the durable snapshot sits below the shard base (see

@@ -93,6 +93,11 @@ from .types import (
 _log = get_logger("engine")
 
 _HOT_SET = frozenset(HOT_TYPES)
+# a snapshot on its way in, or the word on one sent: the scalar path's
+_SNAPSHOT_TYPES = frozenset((
+    int(MessageType.INSTALL_SNAPSHOT), int(MessageType.SNAPSHOT_STATUS),
+    int(MessageType.SNAPSHOT_RECEIVED),
+))
 
 # readback row indices of the per-row VALUES block (_gather_detail's
 # idx_sum part); 0-5 double as the [6, G] host mirror's row indices
@@ -818,6 +823,11 @@ class VectorStepEngine(IStepEngine):
             # rows whose inputs outran their M host slots: the rest were
             # put back for the next launch (_defer_past_room)
             "deferred_inputs": 0,
+            # resident rows that left the device for a snapshot's sake:
+            # one arriving or reported on (_plan_device), a follower
+            # behind the leader's compaction point (_attach_messages,
+            # _replicate_payload), a stream from below the row's base
+            "snapshot_rows_evicted": 0,
         }
         self._warm()
 
@@ -1039,7 +1049,7 @@ class VectorStepEngine(IStepEngine):
         "millions of idle groups cost nothing".  Exiting quiesce needs
         the scalar poke path (LEADER_HEARTBEAT), so that step goes host.
         """
-        if si.config_changes or si.cc_results or si.snapshot_reqs:
+        if si.config_changes or si.cc_results:
             return None
         if si.transfers and not mirror_leader:
             # a replica that does not lead forwards the request over
@@ -1120,8 +1130,6 @@ class VectorStepEngine(IStepEngine):
             node.requeue_inputs(read_indexes=si.read_indexes)
             si.read_indexes = ()
             return None
-        if r.snapshotting:
-            return None
         lim = 2**31 - 1
         # index lanes are REBASED per row (see _compute_base), so log
         # growth never ages a row off the device; the remaining int32
@@ -1158,10 +1166,13 @@ class VectorStepEngine(IStepEngine):
                     # SnapshotStatus/Received resolves the transfer —
                     # otherwise re-uploads would re-fire need_snapshot
                     # and stream duplicate full snapshots every cycle
+                    self._count_snapshot_eviction(g)
                     return None
         slots: List[Tuple] = []
         for m in si.received:
             if int(m.type) not in _HOT_SET:
+                if int(m.type) in _SNAPSHOT_TYPES:
+                    self._count_snapshot_eviction(g)
                 return None
             if int(m.type) == int(MessageType.LEADER_TRANSFER):
                 # a follower-FORWARDED transfer request: hot only as the
@@ -1655,6 +1666,11 @@ class VectorStepEngine(IStepEngine):
             if u is not None:
                 node.dispatch_dropped(u)
                 updates.append((node, u))
+
+    def _count_snapshot_eviction(self, g) -> None:
+        meta = self._meta.get(g)
+        if meta is not None and not meta.dirty:
+            self.stats["snapshot_rows_evicted"] += 1
 
     def _demote_row_to_host(self, node) -> None:
         """Pull a resident row back to scalar authority with a short
@@ -2326,17 +2342,11 @@ class VectorStepEngine(IStepEngine):
                     app_by_db.get(d, ()),
                 ))
 
-        lanes = [t for t in snapshot_sends if t[2] is not None]
-        if lanes:
-            self._state = _set_remote_snapshot(
-                self._state,
-                self._put(jnp.asarray(_pad_idx([t[0] for t in lanes]))),
-                self._put(jnp.asarray(_pad_idx([t[1] for t in lanes]))),
-                self._put(jnp.asarray(_pad_idx([t[2] for t in lanes]))),
-            )
+        self._mark_remote_snapshots(snapshot_sends)
         below = [t for t in snapshot_sends if t[2] is None]
         if below:
             # see _send_snapshots: these rows continue on the scalar path
+            self.stats["snapshot_rows_evicted"] += len({t[0] for t in below})
             gs = sorted(
                 {t[0] for t in below if self._meta.get(t[0]) is not None}
             )
@@ -2354,6 +2364,19 @@ class VectorStepEngine(IStepEngine):
                 if rm is not None:
                     rm.become_snapshot(ss_index)
         return updates
+
+    def _mark_remote_snapshots(self, snapshot_sends) -> None:
+        """Set the device's snapshot lane of every remote a stream went
+        to.  One remote a call: the warm set holds that one shape, and a
+        stream is rare."""
+        for g, p, lane, _pid, _ss_index in snapshot_sends:
+            if lane is not None:
+                self._state = _set_remote_snapshot(
+                    self._state,
+                    self._put(jnp.asarray([g], jnp.int32)),
+                    self._put(jnp.asarray([p], jnp.int32)),
+                    self._put(jnp.asarray([lane], jnp.int32)),
+                )
 
     # -- append reconstruction -----------------------------------------
     def _merge_appends(
@@ -2499,9 +2522,9 @@ class VectorStepEngine(IStepEngine):
                         # SCALAR path (full log + its own snapshot
                         # machinery) drives this follower; silently
                         # dropping starves it (review finding)
-                        self._demote_row_to_host(node)
+                        self._demote_for_compaction(node)
                         continue
-                ents = self._replicate_payload(r, msg, n_ent)
+                ents = self._replicate_payload(r, node, msg, n_ent)
                 if ents is None:
                     continue  # stale vs final log; dropping is raft-safe
                 msg = dataclasses.replace(msg, entries=tuple(ents))
@@ -2517,8 +2540,12 @@ class VectorStepEngine(IStepEngine):
                 msg = dataclasses.replace(msg, entries=tuple(ents))
             r.msgs.append(msg)
 
+    def _demote_for_compaction(self, node) -> None:
+        self._count_snapshot_eviction(self._row_of.get(self._row_key(node)))
+        self._demote_row_to_host(node)
+
     def _replicate_payload(
-        self, r: Raft, msg: Message, n_ent: int
+        self, r: Raft, node, msg: Message, n_ent: int
     ) -> Optional[List[Entry]]:
         from ..raft.log import LogCompactedError, LogUnavailableError
 
@@ -2528,7 +2555,15 @@ class VectorStepEngine(IStepEngine):
             ents = r.log._get_entries(
                 msg.log_index + 1, msg.log_index + 1 + n_ent, 2**62
             )
-        except (LogCompactedError, LogUnavailableError):
+        except LogCompactedError:
+            # a snapshot worker compacted the log past this follower's
+            # next while the row was resident (its first_index lane is
+            # the upload's, so the kernel raised no need_snapshot): the
+            # scalar path streams the snapshot, as for a below-ring
+            # send, and the upload after it carries the new first index
+            self._demote_for_compaction(node)
+            return None
+        except LogUnavailableError:
             return None
         if len(ents) != n_ent:
             return None

@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..pb import Message, MessageType
+from ..raft.log import LogCompactedError
 from ..raft.raft import Raft, RaftRole
 from .types import (
     DeviceOut,
@@ -158,13 +159,18 @@ def _fill_row(cols, g, r: Raft, P, W):
     cols["role"][g] = int(r.role)
     cols["committed"][g] = r.log.committed
     last = r.log.last_index()
-    first = r.log.first_index()
     cols["last_index"][g] = last
-    cols["first_index"][g] = first
-    try:
-        cols["base_term"][g] = r.log.term(first - 1) if first > 1 else 0
-    except Exception:
-        cols["base_term"][g] = 0
+    while True:
+        # a snapshot worker may compact the log under this read
+        # (node.py "snapshotting"): first only ever grows, so take it
+        # again until the window was read whole
+        first = r.log.first_index()
+        try:
+            _fill_log_window(cols, g, r, first, last, W)
+            break
+        except LogCompactedError:
+            if r.log.first_index() == first:
+                raise
     cols["election_tick"][g] = r.election_tick
     cols["heartbeat_tick"][g] = r.heartbeat_tick
     cols["rand_timeout"][g] = r.randomized_election_timeout
@@ -180,8 +186,17 @@ def _fill_row(cols, g, r: Raft, P, W):
         cols["active"][g, s] = int(rm.active)
         if pid in r.votes:
             cols["granted"][g, s] = 1 if r.votes[pid] else 2
-    win_lo = max(first, last - W + 1)
-    for idx in range(win_lo, last + 1):
+
+
+def _fill_log_window(cols, g, r: Raft, first: int, last: int, W: int) -> None:
+    cols["first_index"][g] = first
+    try:
+        cols["base_term"][g] = r.log.term(first - 1) if first > 1 else 0
+    except LogCompactedError:
+        raise  # the boundary moved under the read: _fill_row takes it again
+    except Exception:
+        cols["base_term"][g] = 0
+    for idx in range(max(first, last - W + 1), last + 1):
         t = r.log.term(idx)
         cols["ring_term"][g, idx % W] = t
         ents = r.log._get_entries(idx, idx + 1, 2**62)

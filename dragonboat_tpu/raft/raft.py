@@ -100,7 +100,7 @@ class Raft:
         "forwarded_reads", "leader_commit_hint",
         "election_tick", "heartbeat_tick", "randomized_election_timeout",
         "_timeout_seq", "leader_transfer_target", "pending_config_change",
-        "is_leader_transfer_target", "snapshotting", "tick_count",
+        "is_leader_transfer_target", "tick_count",
         "applied", "launched_non_voting", "launched_witness",
         "_cq_grace_at", "_term_lim_warned", "_campaign_sent_tick",
         "_boot_lease_grace",
@@ -185,7 +185,6 @@ class Raft:
         self.leader_transfer_target = NO_NODE
         self.pending_config_change = False
         self.is_leader_transfer_target = False
-        self.snapshotting = False
         self.tick_count = 0
         # applied index as reported by the RSM; used to gate config change
         self.applied = 0

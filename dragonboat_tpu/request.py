@@ -49,11 +49,20 @@ class RequestError(Exception):
 # what a NodeHost's replicas count on it, always on (docs/OBSERVABILITY.md
 # "Counters"): leader transfers by how they ended, the host-clock
 # seconds from a request to the target's leading, and proposals told
-# DROPPED because a newer leader's entries replaced theirs
+# DROPPED because a newer leader's entries replaced theirs; and what
+# snapshots cost (node.py "snapshotting"): saves asked for and how each
+# ended (requested = saved + skipped + failures once none is in flight),
+# the seconds from a request to its save's start and inside the save
+# (compaction included), the containers' bytes, log entries compacted
+# away, InstallSnapshot messages sent and snapshots recovered from
 HOST_TOTALS = (
     "leader_transfers_requested", "leader_transfers_done",
     "leader_transfers_aborted", "t_transfer_s",
     "proposals_dropped_truncated",
+    "snapshots_requested", "snapshots_saved", "snapshots_skipped",
+    "snapshot_failures", "t_snapshot_wait_s", "t_snapshot_save_s",
+    "snapshot_bytes", "log_entries_compacted", "snapshots_streamed",
+    "snapshots_recovered",
 )
 
 
@@ -71,6 +80,11 @@ class HostTotals:
     def add(self, key: str, n=1) -> None:
         with self._lock:
             self.values[key] += n
+
+    def add_many(self, counts: dict) -> None:
+        with self._lock:
+            for key, n in counts.items():
+                self.values[key] += n
 
     def snapshot(self) -> dict:
         # an add replaces one value of a dict whose keys never change,

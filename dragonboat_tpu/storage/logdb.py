@@ -288,19 +288,29 @@ class LogDBLogReader:
     reference: internal/logdb/logreader.go [U].  The node must call
     ``append``/``apply_snapshot``/``compact`` as it persists so the range
     stays accurate (terms/entries themselves always come from the DB).
+
+    Threads: the step worker appends, applies received snapshots and
+    reads; a snapshot worker records created snapshots and compacts
+    (node.py "snapshotting").  The mutators take ``_mu``.  Readers take
+    no lock: the range lives in ONE tuple ``(marker, length, term of
+    marker - 1)`` that a mutator replaces whole, so a reader sees the
+    range before a compaction or after it, never a mix, and a read that
+    a compaction overtakes between the range check and the DB ends as
+    ``LogCompactedError``, as one that came after it would.
     """
 
     def __init__(self, shard_id: int, replica_id: int, logdb: ILogDB):
         self.shard_id = shard_id
         self.replica_id = replica_id
         self.logdb = logdb
-        self._snapshot: Snapshot = EMPTY_SNAPSHOT
-        self._marker = 1
-        self._length = 0
-        # term of the entry at marker-1 (the compaction boundary), kept so
-        # prev-log-term checks right at the boundary still resolve — the
-        # etcd-storage "dummy entry" trick (reference: logreader [U])
-        self._marker_term: Optional[int] = None
+        self._mu = threading.Lock()
+        self._snapshot: Snapshot = EMPTY_SNAPSHOT  # guarded-by: _mu
+        # (marker, length, marker_term): the first index held, how many
+        # entries from there, and the term of the entry at marker-1 (the
+        # compaction boundary), kept so prev-log-term checks right at the
+        # boundary still resolve — the etcd-storage "dummy entry" trick
+        # (reference: logreader [U])
+        self._range: Tuple[int, int, Optional[int]] = (1, 0, None)  # guarded-by: _mu
 
     @classmethod
     def from_existing(
@@ -310,31 +320,33 @@ class LogDBLogReader:
         nodehost loadState path [U])."""
         lr = cls(shard_id, replica_id, logdb)
         ss = logdb.get_snapshot(shard_id, replica_id)
-        if not ss.is_empty():
-            lr._snapshot = ss
-            lr._marker = ss.index + 1
-        rs = logdb.read_raft_state(shard_id, replica_id, 0)
-        if rs is None:
-            return lr, None
-        if rs.entry_count > 0:
-            lr._marker = rs.first_index
-            lr._length = rs.entry_count
-        elif not ss.is_empty():
-            lr._marker = ss.index + 1
-            lr._length = 0
+        with lr._mu:  # nobody else holds it yet: for the lint's sake
+            if not ss.is_empty():
+                lr._snapshot = ss
+                lr._range = (ss.index + 1, 0, None)
+            rs = logdb.read_raft_state(shard_id, replica_id, 0)
+            if rs is None:
+                return lr, None
+            if rs.entry_count > 0:
+                lr._range = (rs.first_index, rs.entry_count, None)
         return lr, rs.state
 
-    # -- ILogReader ------------------------------------------------------
+    # -- ILogReader (lock-free: see the class docstring) -----------------
     def log_range(self) -> Tuple[int, int]:
-        if self._length > 0:
+        # raftlint: ignore[guarded-by] lock-free reader: one GIL-atomic load (class docstring)
+        marker, length, _ = self._range
+        if length > 0:
             # a locally created snapshot never hides live entries
-            return self._marker, self._marker + self._length - 1
-        first = max(self._marker, self._snapshot.index + 1)
+            return marker, marker + length - 1
+        # raftlint: ignore[guarded-by] lock-free reader: one GIL-atomic load (class docstring)
+        first = max(marker, self._snapshot.index + 1)
         return first, first - 1
 
     def term(self, index: int) -> int:
-        if index == self._snapshot.index and index > 0:
-            return self._snapshot.term
+        # raftlint: ignore[guarded-by] lock-free reader: one GIL-atomic load (class docstring)
+        ss = self._snapshot
+        if index == ss.index and index > 0:
+            return ss.term
         first, last = self.log_range()
         if index < first - 1:
             raise LogCompactedError(f"index {index} < first {first}")
@@ -344,8 +356,14 @@ class LogDBLogReader:
             return 0
         t = self.logdb.term(self.shard_id, self.replica_id, index)
         if t is None:
-            if index == self._marker - 1 and self._marker_term is not None:
-                return self._marker_term
+            # not in the DB: the boundary entry, or one that a
+            # compaction removed since the range was read
+            # raftlint: ignore[guarded-by] lock-free reader: one GIL-atomic load (class docstring)
+            marker, _, marker_term = self._range
+            if index == marker - 1 and marker_term is not None:
+                return marker_term
+            if index < marker - 1:
+                raise LogCompactedError(f"index {index} < first {marker}")
             raise LogUnavailableError(f"term missing at {index}")
         return t
 
@@ -355,11 +373,16 @@ class LogDBLogReader:
             raise LogCompactedError(f"low {low} < first {first}")
         if high > last + 1:
             raise LogUnavailableError(f"high {high} > last+1 {last+1}")
-        return self.logdb.iterate_entries(
+        out = self.logdb.iterate_entries(
             self.shard_id, self.replica_id, low, high, max_size
         )
+        first = self.log_range()[0]
+        if low < first:  # compacted while the DB was read
+            raise LogCompactedError(f"low {low} < first {first}")
+        return out
 
     def snapshot(self) -> Snapshot:
+        # raftlint: ignore[guarded-by] lock-free reader: one GIL-atomic load (class docstring)
         return self._snapshot
 
     # -- mutating half ----------------------------------------------------
@@ -367,37 +390,46 @@ class LogDBLogReader:
         if not entries:
             return
         first_new = entries[0].index
-        last_cur = self._marker + self._length - 1
-        if first_new > last_cur + 1:
-            raise ValueError(f"log gap: {first_new} after {last_cur}")
-        if first_new < self._marker:
-            self._marker = first_new
-            self._length = len(entries)
-        else:
-            self._length = first_new - self._marker + len(entries)
+        with self._mu:
+            marker, length, marker_term = self._range
+            last_cur = marker + length - 1
+            if first_new > last_cur + 1:
+                raise ValueError(f"log gap: {first_new} after {last_cur}")
+            if first_new < marker:
+                self._range = (first_new, len(entries), marker_term)
+            else:
+                self._range = (
+                    marker, first_new - marker + len(entries), marker_term
+                )
 
     def apply_snapshot(self, ss: Snapshot) -> None:
         """Restore: the log is reset to the snapshot point."""
-        self._snapshot = ss
-        self._marker = ss.index + 1
-        self._length = 0
-        self._marker_term = ss.term
+        with self._mu:
+            self._snapshot = ss
+            self._range = (ss.index + 1, 0, ss.term)
 
     def create_snapshot(self, ss: Snapshot) -> None:
         """Record a locally created snapshot WITHOUT resetting the range —
         the log still holds entries past the snapshot (reference:
         logReader.CreateSnapshot vs ApplySnapshot [U])."""
-        if ss.index > self._snapshot.index:
-            self._snapshot = ss
+        with self._mu:
+            if ss.index > self._snapshot.index:
+                self._snapshot = ss
 
-    def compact(self, to_index: int) -> None:
-        first, last = self.log_range()
-        if to_index < self._marker:
-            return
-        keep_from = min(to_index + 1, last + 1)
-        try:
-            self._marker_term = self.term(keep_from - 1)
-        except (LogCompactedError, LogUnavailableError):
-            pass
-        self._length -= keep_from - self._marker
-        self._marker = keep_from
+    def compact(self, to_index: int) -> int:
+        """Forget the entries at or below ``to_index``; how many that
+        was.  Call BEFORE the DB removes them: the boundary's term is
+        read from it here."""
+        with self._mu:
+            marker, length, marker_term = self._range
+            if to_index < marker:
+                return 0
+            keep_from = min(to_index + 1, marker + max(length, 0))
+            try:
+                marker_term = self.term(keep_from - 1)
+            except (LogCompactedError, LogUnavailableError):
+                pass
+            self._range = (
+                keep_from, length - (keep_from - marker), marker_term
+            )
+            return keep_from - marker

@@ -264,15 +264,21 @@ class FileSnapshotStorage(_LeaseMixin):
         final = self._dir(shard_id, replica_id, index, suffix)
         tmp = self._fresh_tmp(final)
         fpath = os.path.join(tmp, "snapshot.bin")
-        with open(fpath, "wb") as f:
-            result = build(f, _make_copy_fn(tmp))
-            f.flush()
-            os.fsync(f.fileno())
-        if index_from_result is not None:
-            final = self._dir(
-                shard_id, replica_id, index_from_result(result), suffix
-            )
-        self._finalize(tmp, final)
+        try:
+            with open(fpath, "wb") as f:
+                result = build(f, _make_copy_fn(tmp))
+                f.flush()
+                os.fsync(f.fileno())
+            if index_from_result is not None:
+                final = self._dir(
+                    shard_id, replica_id, index_from_result(result), suffix
+                )
+            self._finalize(tmp, final)
+        except BaseException:
+            # a save given up (its replica stopping, the state machine
+            # raising) leaves no half-written dir behind
+            shutil.rmtree(tmp, ignore_errors=True)
+            raise
         return os.path.join(final, "snapshot.bin"), result
 
     def load(self, filepath: str) -> bytes:

@@ -177,6 +177,10 @@ class TanLogDB(ILogDB):
         self._inflight = 0  # native appends running outside the lock
         self._idle = threading.Condition(self._lock)  # inflight == 0
         self._rotate_pending = False  # gate: new appends wait, inflight drains
+        # advisory records (log removals) waiting for the next append of
+        # any kind, which they then lead: the mirror has them already,
+        # and a replay that never sees them just keeps more
+        self._lazy: List[tuple] = []  # guarded-by: _lock
         # what the WAL cost so far: appends (one write each, one fsync
         # unless advisory), framed bytes, records.  Written where the
         # lock is already held after an append; see wal_counts
@@ -317,6 +321,12 @@ class TanLogDB(ILogDB):
         while self._inflight:
             self._idle.wait()
 
+    def _with_lazy(self, recs: List[tuple]) -> List[tuple]:  # guarded-by: _lock
+        """``recs`` led by the advisory records that waited for them."""
+        if self._lazy:
+            recs, self._lazy = self._lazy + recs, []
+        return recs
+
     def _append_records(self, recs: List[tuple], sync: bool = True) -> None:
         """recs = [(kind, body)]; one write + one fsync for the batch.
 
@@ -326,6 +336,7 @@ class TanLogDB(ILogDB):
         acked batch: the checkpoint lacked it and GC deleted the segment
         holding its only durable copy — caught by the power-loss fuzz.)
         """
+        recs = self._with_lazy(recs)
         raw = self._frame(recs)
         if self.fault_hook is not None:
             self.fault_hook(raw)
@@ -414,6 +425,8 @@ class TanLogDB(ILogDB):
     def close(self) -> None:
         with self._lock:
             self._quiesce_appends_locked()
+            if self._lazy and (self._writer or self._fh) is not None:
+                self._append_records([], sync=False)
             self._close_active()
 
     def list_node_info(self) -> List[NodeInfo]:
@@ -435,22 +448,29 @@ class TanLogDB(ILogDB):
         recs = [
             (K_STATE_ENTRIES, _encode_state_entries(u)) for u in updates
         ]
+        # ONE fsync for the whole batch
+        self._append_durable(
+            recs, lambda: self._mirror.save_raft_state(updates, worker_id)
+        )
+
+    def _append_durable(self, recs: List[tuple], publish) -> None:
+        """Append ``recs`` with one fsync, then ``publish()`` them to the
+        mirror — the order readers rely on: nothing is visible before
+        it is durable."""
         if self._writer is None:
             with self._lock:
-                self._append_records(recs)  # ONE fsync for the whole batch
-                self._mirror.save_raft_state(updates, worker_id)
+                self._append_records(recs)
+                publish()
                 self._maybe_rotate()  # AFTER the mirror has the batch
             return
         # native path: the blocking (durable) append runs OUTSIDE the
-        # lock so concurrent workers' batches group-commit into shared
-        # fsyncs.  Per-shard record order is preserved (each shard is
-        # stepped by exactly one worker); locked mutators for the same
-        # shard quiesce in-flight appends first.
-        raw = self._frame(recs)
-        if self.fault_hook is not None:
-            self.fault_hook(raw)
-        if self.fault_injector is not None:
-            self.fault_injector.on_fs_op("wal_append", self.dir)
+        # lock so concurrent callers' batches group-commit into shared
+        # fsyncs: step workers' batches, and the snapshot workers'
+        # records, which then never hold a step worker's save behind
+        # their fsync.  Per-shard record order is preserved where it
+        # matters (each shard is stepped by exactly one worker; a
+        # snapshot record commutes with the shard's appends); locked
+        # mutators for the same shard quiesce in-flight appends first.
         with self._lock:
             # a pending rotation blocks NEW appends so inflight can drain
             # — otherwise sustained load starves rotation (and GC) forever
@@ -460,8 +480,14 @@ class TanLogDB(ILogDB):
             if w is None:
                 raise OSError("logdb is closed")
             self._inflight += 1
+            recs = self._with_lazy(recs)
         ok = False
         try:
+            raw = self._frame(recs)
+            if self.fault_hook is not None:
+                self.fault_hook(raw)
+            if self.fault_injector is not None:
+                self.fault_injector.on_fs_op("wal_append", self.dir)
             w.append(raw, sync=True)
             ok = True
         finally:
@@ -475,7 +501,7 @@ class TanLogDB(ILogDB):
                     self._wal_appends += 1
                     self._wal_bytes += len(raw)
                     self._wal_records += len(recs)
-                    self._mirror.save_raft_state(updates, worker_id)
+                    publish()
                     if (
                         self._active_bytes >= self.max_segment_bytes
                         and not self._rotate_pending
@@ -500,14 +526,17 @@ class TanLogDB(ILogDB):
         return self._mirror.term(shard_id, replica_id, index)
 
     def remove_entries_to(self, shard_id, replica_id, index) -> None:
+        # compaction is advisory (a replay without the record just keeps
+        # more), so the record costs no write of its own: it rides at
+        # the head of the next append, in file order before whatever a
+        # later mutator of the same node writes.  No wait for appends in
+        # flight either: a removal (entries at or below an applied
+        # index) commutes with them (entries above the commit index)
         with self._lock:
-            self._quiesce_appends_locked()
-            self._append_records(
-                [(K_REMOVE_TO, _encode_pair_index(shard_id, replica_id, index))],
-                sync=False,  # compaction is advisory; replay just keeps more
+            self._lazy.append(
+                (K_REMOVE_TO, _encode_pair_index(shard_id, replica_id, index))
             )
             self._mirror.remove_entries_to(shard_id, replica_id, index)
-            self._maybe_rotate()
 
     def compact_entries_to(self, shard_id, replica_id, index) -> None:
         self.remove_entries_to(shard_id, replica_id, index)
@@ -520,11 +549,9 @@ class TanLogDB(ILogDB):
         ]
         if not recs:
             return
-        with self._lock:
-            self._quiesce_appends_locked()
-            self._append_records(recs)
-            self._mirror.save_snapshots(updates)
-            self._maybe_rotate()
+        self._append_durable(
+            recs, lambda: self._mirror.save_snapshots(updates)
+        )
 
     def get_snapshot(self, shard_id, replica_id) -> Snapshot:
         return self._mirror.get_snapshot(shard_id, replica_id)

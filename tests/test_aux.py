@@ -22,6 +22,7 @@ from dragonboat_tpu.metrics import MetricsRegistry
 from dragonboat_tpu.transport.gossip import GossipManager, GossipRegistry
 from dragonboat_tpu.transport.tcp import tcp_transport_factory
 
+from test_nodehost import nh_dir  # noqa: F401
 from test_nodehost import (
     ADDRS,
     KVStore,
@@ -61,12 +62,12 @@ class TestMetrics:
     def test_nodehost_health_metrics(self):
         reset_inproc_network()
         for rid in ADDRS:
-            shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+            shutil.rmtree(nh_dir(rid), ignore_errors=True)
         nhs = {}
         try:
             for rid in ADDRS:
                 cfg = NodeHostConfig(
-                    nodehost_dir=f"/tmp/nh-{rid}",
+                    nodehost_dir=nh_dir(rid),
                     rtt_millisecond=2,
                     raft_address=ADDRS[rid],
                     enable_metrics=True,
@@ -314,7 +315,7 @@ class TestExportImport:
     def test_export_then_import_new_membership(self, tmp_path):
         reset_inproc_network()
         for rid in ADDRS:
-            shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+            shutil.rmtree(nh_dir(rid), ignore_errors=True)
         nhs = {rid: make_nodehost(rid) for rid in ADDRS}
         export_dir = str(tmp_path / "export")
         try:
@@ -382,7 +383,7 @@ class TestSnapshotCompression:
 
         reset_inproc_network()
         for rid in ADDRS:
-            shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+            shutil.rmtree(nh_dir(rid), ignore_errors=True)
         nhs = {rid: make_nodehost(rid) for rid in ADDRS}
 
         def comp_config(rid):
@@ -495,7 +496,7 @@ class TestRateLimits:
 
         reset_inproc_network()
         for rid in ADDRS:
-            shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+            shutil.rmtree(nh_dir(rid), ignore_errors=True)
         nhs = {rid: make_nodehost(rid) for rid in ADDRS}
         try:
             for rid, nh in nhs.items():
@@ -621,7 +622,7 @@ class TestRateLimits:
 
         reset_inproc_network()
         for rid in (1,):
-            _sh.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+            _sh.rmtree(nh_dir(rid), ignore_errors=True)
         nh = make_nodehost(1)
         try:
             # two-member shard with only ONE member started: quorum is

@@ -12,6 +12,7 @@ All faults flow through the unified seeded nemesis
 plane, fsync faults on the storage plane, plus real NodeHost
 close/reopen over tan WAL dirs (kills) via the crash handlers.
 """
+import os
 import pickle
 import random
 import shutil
@@ -39,9 +40,16 @@ from test_nodehost import KVStore, set_cmd, shard_config, wait_for_leader
 ADDRS = {1: "cnh-1", 2: "cnh-2", 3: "cnh-3"}
 
 
+def chaos_dir(replica_id):
+    # a directory a process: test_chaos_extended.py and test_faults.py
+    # build this cluster too, and under xdist the files can run at the
+    # same time (a restart then met another worker's flock)
+    return f"/tmp/nh-chaos-{os.getpid()}-{replica_id}"
+
+
 def make_chaos_nodehost(replica_id):
     cfg = NodeHostConfig(
-        nodehost_dir=f"/tmp/nh-chaos-{replica_id}",
+        nodehost_dir=chaos_dir(replica_id),
         rtt_millisecond=2,
         raft_address=ADDRS[replica_id],
         expert=ExpertConfig(
@@ -71,7 +79,7 @@ class Cluster:
         return shard_config(rid)
 
     def _dir(self, rid):
-        return f"/tmp/nh-chaos-{rid}"
+        return chaos_dir(rid)
 
     def start(self, rid):
         self.nhs[rid] = self.make_nodehost(rid)
@@ -371,7 +379,7 @@ class TestPendingKeyIncarnations:
         false ack for proposals that never committed.  Key ranges must be
         random per incarnation (reference: random key generator seed [U])."""
         reset_inproc_network()
-        shutil.rmtree("/tmp/nh-chaos-1", ignore_errors=True)
+        shutil.rmtree(chaos_dir(1), ignore_errors=True)
         keys = set()
         for _ in range(3):
             nh = make_chaos_nodehost(1)

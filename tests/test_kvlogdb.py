@@ -13,6 +13,8 @@ import shutil
 
 import pytest
 
+from test_nodehost import nh_dir
+
 from dragonboat_tpu.pb import Bootstrap, Snapshot, State, Update
 from dragonboat_tpu.storage.kvlogdb import ShardedKVLogDB, kv_logdb_factory
 from dragonboat_tpu.storage.kvstore import KVStore, WriteBatch
@@ -259,7 +261,7 @@ def test_nodehost_cluster_on_kv_backend():
 
     reset_inproc_network()
     for rid in ADDRS:
-        shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+        shutil.rmtree(nh_dir(rid), ignore_errors=True)
     nhs = {
         rid: make_nodehost(rid, logdb_factory=kv_logdb_factory)
         for rid in ADDRS

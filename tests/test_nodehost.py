@@ -4,6 +4,7 @@ the in-proc transport — the reference's nodehost_test.go pattern [U]
 
 This is BASELINE config 1: 3-replica single-group in-mem KV, host engine.
 """
+import os
 import pickle
 import threading
 import time
@@ -66,9 +67,17 @@ def set_cmd(k, v):
 ADDRS = {1: "nh-1", 2: "nh-2", 3: "nh-3"}
 
 
+def nh_dir(replica_id):
+    # a directory a process: test_aux.py, test_kvlogdb.py,
+    # test_ondisk_witness.py and test_snapshotio.py build these hosts
+    # too, and under xdist the files run at the same time (one worker's
+    # rmtree then took another's snapshot files, or met its flock)
+    return f"/tmp/nh-{os.getpid()}-{replica_id}"
+
+
 def make_nodehost(replica_id, rtt_ms=2, workers=2, logdb_factory=None):
     cfg = NodeHostConfig(
-        nodehost_dir=f"/tmp/nh-{replica_id}",
+        nodehost_dir=nh_dir(replica_id),
         rtt_millisecond=rtt_ms,
         raft_address=ADDRS[replica_id],
         expert=ExpertConfig(
@@ -92,7 +101,7 @@ def cluster():
     import shutil
 
     for rid in ADDRS:
-        shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+        shutil.rmtree(nh_dir(rid), ignore_errors=True)
     nhs = {rid: make_nodehost(rid) for rid in ADDRS}
     for rid, nh in nhs.items():
         nh.start_replica(ADDRS, False, KVStore, shard_config(rid))
@@ -310,7 +319,7 @@ class TestSnapshotAndRestart:
         propose_r(nh, s, set_cmd("while-down", b"v"))
         # restart replica 3 on the same dir: the WAL replays
         cfg = NodeHostConfig(
-            nodehost_dir="/tmp/nh-3",
+            nodehost_dir=nh_dir(3),
             rtt_millisecond=2,
             raft_address=ADDRS[3],
             expert=ExpertConfig(
@@ -341,7 +350,7 @@ class TestSnapshotAndRestart:
         cluster[1].close()
         # restart on the same dir: default tan WAL + snapshot dir recover
         cfg = NodeHostConfig(
-            nodehost_dir="/tmp/nh-1",
+            nodehost_dir=nh_dir(1),
             rtt_millisecond=2,
             raft_address=ADDRS[1],
             expert=ExpertConfig(
@@ -380,7 +389,7 @@ class TestSnapshotCatchUp:
             propose_r(nh, s, set_cmd(f"post{i}", b"v"))
         # restart the follower on a FRESH logdb: it must need the snapshot
         cfg = NodeHostConfig(
-            nodehost_dir=f"/tmp/nh-{fid}",
+            nodehost_dir=nh_dir(fid),
             rtt_millisecond=2,
             raft_address=ADDRS[fid],
             expert=ExpertConfig(
@@ -500,7 +509,7 @@ class TestQuiesceTickParking:
         import shutil
 
         for rid in ADDRS:
-            shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+            shutil.rmtree(nh_dir(rid), ignore_errors=True)
         nhs = {rid: make_nodehost(rid) for rid in ADDRS}
         try:
             for rid, nh in nhs.items():
@@ -540,7 +549,7 @@ class TestQuiesceTickParking:
         import shutil
 
         for rid in ADDRS:
-            shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+            shutil.rmtree(nh_dir(rid), ignore_errors=True)
         nhs = {rid: make_nodehost(rid) for rid in ADDRS}
         try:
             for rid, nh in nhs.items():

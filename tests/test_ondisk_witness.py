@@ -25,6 +25,7 @@ from dragonboat_tpu import (
 from dragonboat_tpu.storage.tan import tan_logdb_factory
 from dragonboat_tpu.transport.inproc import reset_inproc_network
 
+from test_nodehost import nh_dir  # noqa: F401
 from test_nodehost import KVStore, propose_r, set_cmd, wait_for_leader
 
 ADDRS = {1: "od-1", 2: "od-2", 3: "od-3"}
@@ -346,7 +347,7 @@ class TestConcurrentSM:
 
         reset_inproc_network()
         for rid in NADDRS:
-            shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+            shutil.rmtree(nh_dir(rid), ignore_errors=True)
         nhs = {rid: make_nodehost(rid) for rid in NADDRS}
         try:
             for rid, nh in nhs.items():

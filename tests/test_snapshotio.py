@@ -11,6 +11,8 @@ import struct
 
 import pytest
 
+from test_nodehost import nh_dir
+
 from dragonboat_tpu.pb import CompressionType, Membership, SnapshotFile
 from dragonboat_tpu.storage.snapshotio import (
     SnapshotCorruptError,
@@ -215,7 +217,7 @@ def test_external_files_roundtrip_through_nodehost():
 
     reset_inproc_network()
     for rid in ADDRS:
-        shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+        shutil.rmtree(nh_dir(rid), ignore_errors=True)
     nhs = {rid: make_nodehost(rid) for rid in ADDRS}
     sms = {}
 
@@ -314,7 +316,7 @@ def test_external_files_stream_across_hosts():
 
     reset_inproc_network()
     for rid in ADDRS:
-        shutil.rmtree(f"/tmp/nh-{rid}", ignore_errors=True)
+        shutil.rmtree(nh_dir(rid), ignore_errors=True)
     nhs = {rid: make_nodehost(rid) for rid in ADDRS}
     sms = {}
 
