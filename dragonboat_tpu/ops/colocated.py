@@ -155,9 +155,12 @@ _FUSED_ROUNDS_DEFAULT = 3
 _LIM_SOFT = 2**31 - 2**24
 
 
-# per-launch [G, 4] host-upload lane assignments: every per-launch [G]
-# host input rides ONE device_put (each H2D put costs ~10-20 ms of
-# link latency; four separate puts were a fifth of the launch budget)
+# per-launch [G, 4] lane assignments of the launch's [G] host inputs.
+# They cross as part of the launch's ONE upload (see _pack_launch): on
+# the local chip a transfer costs no link round trip, but every device
+# call gives the interpreter lock away, and with ~90 threads in the
+# process the launch thread waits up to a switch interval to run again
+# (PERF.md section 6) — the cost is a call's, not a byte's
 _C_ALIVE, _C_BATCH, _C_PROP, _C_TICKS = range(4)
 
 
@@ -170,6 +173,38 @@ def _combo_np(tick_counts, alive_np, batch_gs, prop_gs) -> np.ndarray:
     combo[batch_gs, _C_BATCH] = 1
     combo[prop_gs, _C_PROP] = 1
     return combo
+
+
+def _pack_launch(G: int, M: int, E: int, tick_counts, alive_np, batch_gs,
+                 prop_gs, sparse) -> Tuple[np.ndarray, int]:
+    """Everything the host tells the device about one launch, as ONE
+    flat int32 vector (and the bucket ``NSB`` it is packed at):
+
+      [0:4G]            the [G, 4] combo (_combo_np), row-major
+      [4G:5G]           pos: row g takes dense row pos[g], -1 = none
+      [5G:5G+NSB*R]     the dense rows' inboxes (S.encode_inbox_np),
+                        R = S.inbox_row_ints(M, E) ints a row
+
+    ``sparse`` is the ``(g, Message list)`` rows that upload a dense
+    inbox; NSB = _bucket(len(sparse)), 0 with none.  Rows past
+    len(sparse) stay zero: no pos points at them."""
+    nsb = _bucket(len(sparse)) if sparse else 0
+    R = S.inbox_row_ints(M, E)
+    flat = np.zeros((5 * G + nsb * R,), np.int32)
+    flat[:4 * G] = _combo_np(
+        tick_counts, alive_np, batch_gs, prop_gs
+    ).reshape(-1)
+    flat[4 * G:5 * G] = _pos_map(G, [g for g, _ in sparse])
+    if sparse:
+        _, overflow = S.encode_inbox_np(
+            [m for _, m in sparse], M, E,
+            out=flat[5 * G:].reshape(nsb, R),
+        )
+        assert not overflow, (
+            "planner let oversized rows through: "
+            f"{[sparse[i][0] for i in overflow]}"
+        )
+    return flat, nsb
 
 
 @jax.jit
@@ -196,19 +231,21 @@ def _assemble_inbox(host: Inbox, pending: Inbox, alive: jnp.ndarray) -> Inbox:
 
 
 @functools.partial(jax.jit, static_argnames=("out_capacity",),
-                   donate_argnums=(1, 2))
+                   donate_argnums=(2,))
 def _assemble_and_step(state, host: Inbox, pending: Inbox, combo,
                        *, out_capacity: int):
-    """Fused inbox assembly + kernel step in ONE program, with the host
-    and pending inboxes declared DONATED: a fast launch cadence at
-    65k-row geometry once out-allocated the device (r5 finding —
+    """Fused inbox assembly + kernel step in ONE program, with the
+    pending inbox declared DONATED: a fast launch cadence at 65k-row
+    geometry once out-allocated the device (r5 finding —
     RESOURCE_EXHAUSTED mid-election); fusing avoids materializing the
     assembled inbox as a host-held buffer, and the donation invites the
-    runtime to reuse the inbox allocations.  No output has an inbox's
-    shape, so jax 0.9.0 reports these donations "not usable" and leaves
-    the buffers alone, on the TPU as on the CPU (chip run, PR 21): the
-    callers still treat both inboxes as consumed.  ``combo`` is the
-    [G, 4] fused host-upload (see _C_*); the alive lane masks rows."""
+    runtime to reuse the allocation.  No output has an inbox's shape, so
+    jax 0.9.0 reports the donation "not usable" and leaves the buffer
+    alone, on the TPU as on the CPU (chip run, PR 21): the callers
+    still treat ``pending`` as consumed.  ``host`` is NOT donated:
+    rounds 2..K of a fused wave all read one resident all-zero host
+    inbox.  ``combo`` is the [G, 4] lane block of the launch's upload
+    (see _C_*); the alive lane masks rows."""
     full = _assemble_inbox(host, pending, combo[:, _C_ALIVE] != 0)
     return K.step(state, full, out_capacity=out_capacity)
 
@@ -409,21 +446,25 @@ def _zero_inbox_rows(inbox: Inbox, mask) -> Inbox:
     return Inbox(*(z(getattr(inbox, f)) for f in Inbox._fields))
 
 
-@functools.partial(jax.jit, static_argnames=("M", "E"))
-def _host_inbox_from_ticks(combo, *, M: int, E: int) -> Inbox:
-    """Build the host inbox region ON DEVICE from a [G] fused-tick-count
-    vector.  At scale, nearly every row's host region is exactly one
+@functools.partial(jax.jit, static_argnames=("G", "M", "E", "NSB"))
+def _host_inbox(flat, *, G: int, M: int, E: int, NSB: int):
+    """Unpack one launch's upload (_pack_launch's layout) ON DEVICE into
+    ``(combo, host inbox)``: the one program of the encode phase.
+
+    At scale, nearly every row's host region is exactly one
     count-carrying LOCAL_TICK slot — uploading the dense [G, M(, E)]
-    inbox arrays is ~28 MB per launch at 65k rows; the tick vector is
-    256 KB.  Rows with real
-    host slots (wire messages, proposals, reads, tick-with-read-hint)
-    are scattered over this base by _scatter_inbox_rows."""
+    inbox arrays is ~28 MB per launch at 65k rows; the tick column of
+    the combo is 256 KB.  So the base is built here from the tick
+    counts, and the ``NSB`` rows with real host slots (wire messages,
+    proposals, reads, tick-with-read-hint) are placed over it by the
+    shared pos-map gather-select (see engine._place_rows).  NSB = 0 is
+    the launch with no such row."""
+    combo = flat[:4 * G].reshape(G, 4)
     tick_counts = combo[:, _C_TICKS]
-    G = tick_counts.shape[0]
     z = jnp.zeros((G, M), I32)
     ze = jnp.zeros((G, M, E), I32)
     has = tick_counts > 0
-    return Inbox(
+    host = Inbox(
         mtype=z.at[:, 0].set(jnp.where(has, MT_TICK, 0)),
         from_id=z,
         term=z,
@@ -437,16 +478,11 @@ def _host_inbox_from_ticks(combo, *, M: int, E: int) -> Inbox:
         ent_term=ze,
         ent_cc=ze,
     )
-
-
-@jax.jit
-def _scatter_inbox_rows(host: Inbox, pos, sub: Inbox) -> Inbox:
-    """Place sub's rows at pos (a [G] position map, -1 = keep) — the
-    shared pos-map gather-select (see engine._place_rows)."""
-    return Inbox(*(
-        _place_rows(getattr(host, f), getattr(sub, f), pos)
-        for f in Inbox._fields
-    ))
+    if NSB:
+        pos = flat[4 * G:5 * G]
+        sub = S.unpack_inbox(flat[5 * G:].reshape(NSB, -1), M, E)
+        host = Inbox(*(_place_rows(h, s, pos) for h, s in zip(host, sub)))
+    return combo, host
 
 
 def _lane_inputs(lane, i: int) -> StepInputs:
@@ -1141,8 +1177,8 @@ class ColocatedVectorEngine(VectorStepEngine):
                 part[np.clip(dest, 0, len(part) - 1)] != part[:, None]
             )
             dest = np.where(cut, -1, dest)
-        self._dest_dev = self._put_rows(jnp.asarray(dest))
-        self._rank_dev = self._put_rows(jnp.asarray(rank))
+        self._dest_dev = self._put_rows(dest)
+        self._rank_dev = self._put_rows(rank)
         self._tables_dirty = False
 
     def set_partition(self, fn) -> None:
@@ -1182,27 +1218,27 @@ class ColocatedVectorEngine(VectorStepEngine):
     # -- warm -----------------------------------------------------------
     def _warm(self) -> None:
         G, P, B, E, O = self.capacity, self.P, self.budget, self.E, self.O
+        M, R = self.M, S.inbox_row_ints(self.M, self.E)
         self._pending = self._put_rows(make_inbox(G, P * B, E))
         st = self._state
-        host = self._put_rows(make_inbox(G, self.M, E))
-        combo = self._put_rows(jnp.zeros((G, 4), jnp.int32))
-        # persistent all-zero combo: rounds >= 2 of a fused wave build
-        # their (empty) host inbox region from it ON DEVICE — ticks and
-        # host slots are fed exactly once, in round 1 (never donated,
-        # so one handle serves every wave)
-        self._zero_combo = combo
+        # warm the REAL launch signature: the combo and the host inbox
+        # both come out of _host_inbox over the launch's one committed
+        # upload — warming with host-side arrays would key different
+        # executables (committed-ness / sharding) and the first
+        # production launch would recompile
+        combo, host = _host_inbox(
+            self._put(np.zeros((5 * G,), np.int32)), G=G, M=M, E=E, NSB=0
+        )
+        # the resident all-zero host inbox: rounds >= 2 of a fused wave
+        # all read it — ticks and host slots are fed exactly once, in
+        # round 1 (never donated, so one handle serves every wave)
+        self._zero_host = host
         dest = self._put_rows(jnp.full((G, P), -1, I32))
         rank = self._put_rows(jnp.zeros((G, P), I32))
-        # warm the REAL launch signature: host inbox built on device
-        # from the (row-sharded) fused combo upload — warming with a
-        # host-side make_inbox would key different executables
-        # (committed-ness / sharding) and the first production launch
-        # would recompile
-        host2 = _host_inbox_from_ticks(combo, M=self.M, E=E)
-        # warm the PRODUCTION fused executable; it donates host2 and
-        # _pending, so rebuild _pending afterwards
+        # warm the PRODUCTION fused executable; it donates _pending, so
+        # rebuild it afterwards
         new_st, out = _assemble_and_step(
-            st, host2, self._pending, combo, out_capacity=O
+            st, host, self._pending, combo, out_capacity=O
         )
         self._pending = self._put_rows(make_inbox(G, P * B, E))
         merged_w, _regions_w, stats_w, packed_w, flags_w = _route_step(
@@ -1224,11 +1260,6 @@ class ColocatedVectorEngine(VectorStepEngine):
         pos0 = self._put_rows(jnp.full((G,), -1, jnp.int32))
         mask0 = self._put_rows(jnp.zeros((G,), bool))
         _zero_inbox_rows(self._pending, mask0)
-        # host2 was DONATED into _assemble_and_step above; warm the
-        # scatter against a fresh host inbox of the same signature
-        host3 = _host_inbox_from_ticks(
-            self._put_rows(jnp.zeros((G, 4), jnp.int32)), M=self.M, E=E
-        )
         b = 1
         while b <= G:
             idx = self._put(jnp.zeros((b,), jnp.int32))
@@ -1242,19 +1273,19 @@ class ColocatedVectorEngine(VectorStepEngine):
             # first post-warm eviction paid a fresh compile mid-run
             # (found by the analysis/jitcheck recompile sentry)
             _gather_rows(self._pending, idx)
-            host_sc = _scatter_inbox_rows(
-                host3, pos0,
-                self._put(Inbox(*(jnp.zeros((b,) + f.shape[1:], I32)
-                                  for f in host3))),
+            # the launch's one program at every bucket of dense rows
+            combo_sc, host_sc = _host_inbox(
+                self._put(np.zeros((5 * G + b * R,), np.int32)),
+                G=G, M=M, E=E, NSB=b,
             )
             b <<= 1
-        # a host inbox that went through _scatter_inbox_rows (the first
-        # launch carrying a proposal) is a SECOND step signature under a
-        # mesh: its entry lanes come out row-sharded where the
-        # tick-built inbox leaves them replicated.  Unwarmed, it was a
-        # 17 s compile inside the first write (chip_smoke.py --chips 4,
-        # PR 21); on one device this call hits the executable above.
-        _assemble_and_step(st, host_sc, self._pending, combo,
+        # under a mesh a program's outputs take the shardings the
+        # compiler chose for THAT program, so the step over a launch
+        # that carried dense rows may be a second signature: unwarmed,
+        # it was a 17 s compile inside the first write (chip_smoke.py
+        # --chips 4, PR 21); on one device this call hits the
+        # executable above.
+        _assemble_and_step(st, host_sc, self._pending, combo_sc,
                            out_capacity=O)
         self._pending = self._put_rows(make_inbox(G, P * B, E))
         one = self._put(jnp.zeros((1,), jnp.int32))
@@ -1323,8 +1354,10 @@ class ColocatedVectorEngine(VectorStepEngine):
 
         if self._pending is None or not pairs:
             return
-        idx = self._put(jnp.asarray(_pad_idx([g for _, g in pairs])))
-        sub = jax.tree.map(np.asarray, _gather_rows(self._pending, idx))
+        idx = self._put(_pad_idx([g for _, g in pairs]))
+        sub = jax.tree.map(
+            np.asarray, self._run(_gather_rows, self._pending, idx)
+        )
         for k, (node, g) in enumerate(pairs):
             r = node.peer.raft
             base = int(self._base[g])  # routed lanes are shard-rebased
@@ -1376,8 +1409,8 @@ class ColocatedVectorEngine(VectorStepEngine):
         # drained rows stayed dirty through the next launch's alive mask.
         mask = np.zeros((self.capacity,), bool)
         mask[[g for _, g in pairs]] = True
-        self._pending = _zero_inbox_rows(
-            self._pending, self._put_rows(jnp.asarray(mask))
+        self._pending = self._run(
+            _zero_inbox_rows, self._pending, self._put_rows(mask)
         )
 
     # -- the launch pipeline -------------------------------------------
@@ -2639,16 +2672,6 @@ class ColocatedVectorEngine(VectorStepEngine):
         fed = np.zeros((len(batch_gs),), np.int64)
         fed[:n_act] = [tick_fed.get(g, 0) for _, g, _, _ in batch]
         fed[n_act:] = lane.fed_np
-        if hostplane.PARITY:
-            n_reads = self.stats["device_reads"]
-            n_xfer = self.stats["device_transfers"]
-            whole_batch = batch + self._lane_as_batch(lane)
-            whole = self._encode_generation(whole_batch, (), ())
-            self.stats["device_reads"] = n_reads
-            self.stats["device_transfers"] = n_xfer
-            hostplane.check_encode_parity(
-                whole_batch, batch_gs, enc, whole, lane
-            )
         if self._tables_dirty:
             self._rebuild_tables()
         # alive straight off the SoA lanes (attached & clean) — the old
@@ -2705,35 +2728,30 @@ class ColocatedVectorEngine(VectorStepEngine):
                 self.stats["fused_rounds_stepped"] += rounds
             else:
                 self.stats["fused_fences"] += 1
-        # ONE fused [G, 4] host upload for every per-launch [G] input
-        # (alive, batch membership, proposal rows, fused tick counts):
-        # each separate device_put pays ~10-20 ms of link latency
-        combo = self._put_rows(jnp.asarray(
-            _combo_np(tick_counts, alive_np, batch_gs, prop_gs)
-        ))
-        host_inbox = _host_inbox_from_ticks(combo, M=M, E=E)
-        if sparse:
-            nsb = _bucket(len(sparse))
-            # pad with COPIES of the last real row: _pad_idx repeats its
-            # g, and duplicate .at[idx].set() is only benign when every
-            # duplicate writes identical data (an empty pad row would
-            # race the real one and could zero its messages)
-            batches = (
-                [m for _, m in sparse]
-                + [sparse[-1][1]] * (nsb - len(sparse))
+        # ONE upload and ONE program for everything the host tells the
+        # device about this launch (see _pack_launch / _host_inbox)
+        flat, nsb = _pack_launch(
+            G, M, E, tick_counts, alive_np, batch_gs, prop_gs, sparse
+        )
+        if hostplane.PARITY:
+            n_reads = self.stats["device_reads"]
+            n_xfer = self.stats["device_transfers"]
+            whole_batch = batch + self._lane_as_batch(lane)
+            whole = self._encode_generation(whole_batch, (), ())
+            self.stats["device_reads"] = n_reads
+            self.stats["device_transfers"] = n_xfer
+            hostplane.check_encode_parity(
+                whole_batch, batch_gs, enc, whole, lane
             )
-            sub, overflow = S.encode_inbox(batches, M, E)
-            assert not overflow, (
-                "planner let oversized rows through: "
-                f"{[sparse[i][0] for i in overflow if i < len(sparse)]}"
-            )
-            host_inbox = _scatter_inbox_rows(
-                host_inbox,
-                self._put_rows(jnp.asarray(
-                    _pos_map(G, [g for g, _ in sparse])
-                )),
-                self._put(sub),
-            )
+            # raftlint: ignore[sync-budget] host-built index array, not a device readback
+            whole_props = np.asarray(whole.prop_rows, np.int64)
+            hostplane.check_upload_parity(flat, _pack_launch(
+                G, M, E, whole.tick_counts, alive_np, batch_gs,
+                whole_props, whole.sparse,
+            )[0])
+        combo, host_inbox = self._run(
+            _host_inbox, self._put(flat), G=G, M=M, E=E, NSB=nsb
+        )
 
         old_state = self._state
         if self._pending is None:
@@ -2746,18 +2764,19 @@ class ColocatedVectorEngine(VectorStepEngine):
         dispatching = self._phase("t_dispatch_ms")
         try:
             with _TraceAnnotation("raft-colocated-step"):
-                # fused assemble+step with host/pending donated, and
+                # fused assemble+step with pending donated, and
                 # new_state donated into route (dead after the merge):
                 # minimizes per-generation device allocations (see
                 # _assemble_and_step)
-                new_state, out = _assemble_and_step(
-                    old_state, host_inbox, self._pending, combo,
-                    out_capacity=self.O,
+                new_state, out = self._run(
+                    _assemble_and_step, old_state, host_inbox,
+                    self._pending, combo, out_capacity=self.O,
                 )
                 merged, regions, stats_dev, packed_dev, flags_dev = (
-                    _route_step(
-                        old_state, new_state, out, self._dest_dev,
-                        self._rank_dev, combo, PB=P * B, E=E, budget=B,
+                    self._run(
+                        _route_step, old_state, new_state, out,
+                        self._dest_dev, self._rank_dev, combo,
+                        PB=P * B, E=E, budget=B,
                     )
                 )
         except BaseException:
@@ -2798,11 +2817,11 @@ class ColocatedVectorEngine(VectorStepEngine):
                 head_l, detail_l = [], []
 
                 def _sel(merged_k, out_k, stats_k, packed_k, flags_k):
-                    head_dev, detail_dev = _select_and_blob(
-                        merged_k, out_k, stats_k, packed_k, flags_k,
-                        combo, CAP_B=caps["b"], CAP_SL=caps["sl"],
-                        CAP_N=caps["n"], CAP_A=caps["a"],
-                        CAP_S=caps["s"], HOST_OFF=P * B,
+                    head_dev, detail_dev = self._run(
+                        _select_and_blob, merged_k, out_k, stats_k,
+                        packed_k, flags_k, combo, CAP_B=caps["b"],
+                        CAP_SL=caps["sl"], CAP_N=caps["n"],
+                        CAP_A=caps["a"], CAP_S=caps["s"], HOST_OFF=P * B,
                     )
                     head_dev.copy_to_host_async()
                     detail_dev.copy_to_host_async()
@@ -2813,26 +2832,24 @@ class ColocatedVectorEngine(VectorStepEngine):
                 # ---- fused wave: rounds 2..K, dispatched back-to-back
                 # with NO host sync between rounds.  Each round is the
                 # exact single-round program chain (assemble over the
-                # previous round's routed regions with an EMPTY host
-                # inbox — ticks and proposals fed once, in round 1 —
-                # then step, route, select), so a K-round wave is
-                # bit-exact with K serial launches by construction and
-                # reuses the warmed executables: no new XLA programs,
-                # no tier recompiles (the r5 compile-time finding rules
-                # out a monolithic K-round mega-program here).
+                # previous round's routed regions with the resident
+                # EMPTY host inbox — ticks and proposals fed once, in
+                # round 1 — then step, route, select), so a K-round
+                # wave is bit-exact with K serial launches by
+                # construction and reuses the warmed executables: no new
+                # XLA programs, no tier recompiles (the r5 compile-time
+                # finding rules out a monolithic K-round mega-program
+                # here).
                 for _k in range(1, rounds):
-                    host_k = _host_inbox_from_ticks(
-                        self._zero_combo, M=M, E=E
-                    )
-                    new_k, out_k = _assemble_and_step(
-                        self._state, host_k, self._pending, combo,
-                        out_capacity=self.O,
+                    new_k, out_k = self._run(
+                        _assemble_and_step, self._state, self._zero_host,
+                        self._pending, combo, out_capacity=self.O,
                     )
                     merged_k, regions_k, stats_k, packed_k, flags_k = (
-                        _route_step(
-                            self._state, new_k, out_k, self._dest_dev,
-                            self._rank_dev, combo, PB=P * B, E=E,
-                            budget=B,
+                        self._run(
+                            _route_step, self._state, new_k, out_k,
+                            self._dest_dev, self._rank_dev, combo,
+                            PB=P * B, E=E, budget=B,
                         )
                     )
                     self._pending = regions_k
@@ -3001,8 +3018,8 @@ class ColocatedVectorEngine(VectorStepEngine):
             )
             _tq = _time.monotonic()
             detail, vals_np = _fetch_detail_vals(
-                rec.merged[rnd], rec.out[rnd], idx4,
-                sets.sum_rows.tolist(), self._put, self.O,
+                self, rec.merged[rnd], rec.out[rnd], idx4,
+                sets.sum_rows.tolist(), self.O,
                 self.M + self.P * self.budget, self.E, self.P, self.W,
                 allow_fused=False,
             )
@@ -3443,8 +3460,7 @@ class ColocatedVectorEngine(VectorStepEngine):
             # the kernel ran on the ASSEMBLED inbox (host slots + routed
             # regions), so the out slot arrays are M + P*B wide
             detail, vals_np = _fetch_detail_vals(
-                rec.merged[rnd], rec.out[rnd], idx4, sum_rows.tolist(),
-                self._put,
+                self, rec.merged[rnd], rec.out[rnd], idx4, sum_rows.tolist(),
                 self.O, M + P * B, E, P, self.W, allow_fused=False,
             )
             self._floor_wait(_tq)

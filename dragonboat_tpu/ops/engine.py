@@ -186,7 +186,7 @@ def _scatter_rows(state: DeviceState, pos, sub: DeviceState) -> DeviceState:
 
 
 def _pos_map(G: int, gs) -> np.ndarray:
-    """Host-built [G] position map for _scatter_rows/_scatter_inbox_rows:
+    """Host-built [G] position map for _scatter_rows/colocated._host_inbox:
     pos[g] = index into the sub batch, -1 elsewhere.  ONE definition —
     delegates to hostplane.pos_of, the same map the merge tail's
     index-array machinery uses (review finding: two byte-equivalent
@@ -365,7 +365,7 @@ def _build_idx4(buf_rows, slot_rows, need_rows, append_rows):
     return idx4
 
 
-def _fetch_detail_vals(state, out, idx4, sum_rows, put, O, M, E, P, W,
+def _fetch_detail_vals(eng, state, out, idx4, sum_rows, O, M, E, P, W,
                        allow_fused: bool = True):
     """Gather post-step detail and/or per-row values with the MINIMUM
     number of sync round trips: one fused dispatch+readback when both
@@ -381,6 +381,7 @@ def _fetch_detail_vals(state, out, idx4, sum_rows, put, O, M, E, P, W,
     ``allow_fused=False`` forces the separate gathers — the colocated
     fallback path uses it because only the separate per-bucket programs
     are in its warm set (a fused compile mid-run stalls the pipeline).
+    ``eng`` is the engine whose ``_put`` / ``_run`` carry the calls.
     """
     detail = vals_np = None
     if allow_fused and idx4 is not None and sum_rows:
@@ -395,12 +396,10 @@ def _fetch_detail_vals(state, out, idx4, sum_rows, put, O, M, E, P, W,
             )
             b = bs
         if b == bs:
-            flat = np.asarray(
-                _gather_detail_vals(
-                    state, out, put(jnp.asarray(idx4)),
-                    put(jnp.asarray(_pad_idx(sum_rows, bs))),
-                )
-            )
+            flat = np.asarray(eng._run(
+                _gather_detail_vals, state, out, eng._put(idx4),
+                eng._put(_pad_idx(sum_rows, bs)),
+            ))
             detail = _split_detail(
                 flat[: b * K].reshape(b, K), O, M, E, P, W
             )
@@ -408,13 +407,13 @@ def _fetch_detail_vals(state, out, idx4, sum_rows, put, O, M, E, P, W,
             return detail, vals_np
     if idx4 is not None:
         detail = _split_detail(
-            np.asarray(_gather_detail(state, out, put(jnp.asarray(idx4)))),
+            np.asarray(eng._run(_gather_detail, state, out, eng._put(idx4))),
             O, M, E, P, W,
         )
     if sum_rows:
-        vals_np = np.asarray(
-            _gather_vals(state, out, put(jnp.asarray(_pad_idx(sum_rows))))
-        )
+        vals_np = np.asarray(eng._run(
+            _gather_vals, state, out, eng._put(_pad_idx(sum_rows))
+        ))
     return detail, vals_np
 
 
@@ -716,10 +715,6 @@ class VectorStepEngine(IStepEngine):
                 device if device is not None
                 else placement.default_device(jax)
             )
-        # inert rows: no peers, empty inbox -> the kernel never touches them
-        self._state = self._put_rows(
-            make_state(capacity, P, W, replica_ids=np.zeros(capacity))
-        )
         self._row_of: Dict[int, int] = {}  # shard_id -> g
         self._meta: Dict[int, _RowMeta] = {}  # g -> meta
         # SoA truth store behind every _RowMeta (ops/hostplane.py): the
@@ -828,7 +823,17 @@ class VectorStepEngine(IStepEngine):
             # behind the leader's compaction point (_attach_messages,
             # _replicate_payload), a stream from below the row's base
             "snapshot_rows_evicted": 0,
+            # device calls of the engine's thread: host-to-device
+            # transfers (one jax.device_put each: _put, _put_rows) and
+            # jitted programs enqueued (_run).  Each gives the
+            # interpreter lock away once (PERF.md section 6)
+            "device_puts": 0,
+            "device_programs": 0,
         }
+        # inert rows: no peers, empty inbox -> the kernel never touches them
+        self._state = self._put_rows(
+            make_state(capacity, P, W, replica_ids=np.zeros(capacity))
+        )
         self._warm()
 
     def _put(self, x):
@@ -839,6 +844,7 @@ class VectorStepEngine(IStepEngine):
         executables on argument committed-ness/sharding, so mixing
         committed and uncommitted calls silently doubles every compile
         (~60s each for the step kernel)."""
+        self.stats["device_puts"] += 1
         if self._mesh is not None:
             return jax.device_put(x, self._rep_sharding)
         return jax.device_put(x, self._device)
@@ -846,9 +852,17 @@ class VectorStepEngine(IStepEngine):
     def _put_rows(self, x):
         """Commit a full-capacity row pytree (state, inboxes, [G] masks)
         — sharded over the groups axis in mesh mode."""
+        self.stats["device_puts"] += 1
         if self._mesh is not None:
             return jax.device_put(x, self._row_sharding)
         return jax.device_put(x, self._device)
+
+    def _run(self, prog, *args, **kwargs):
+        """Enqueue the jitted program ``prog``.  EVERY program the
+        engine runs after ``_warm()`` goes through this, as every array
+        goes through ``_put``: it is where ``device_programs`` counts."""
+        self.stats["device_programs"] += 1
+        return prog(*args, **kwargs)
 
     @staticmethod
     def _cq_grace(r) -> None:
@@ -1383,10 +1397,10 @@ class VectorStepEngine(IStepEngine):
             "t_up_pack_ms", 0
         ) + (_time.perf_counter() - _t0) * 1000.0
         _t0 = _time.perf_counter()
-        pos = self._put_rows(jnp.asarray(
-            _pos_map(self.capacity, [g for g, _ in rows])
-        ))
-        self._state = _scatter_rows(self._state, pos, self._put(sub))
+        pos = self._put_rows(_pos_map(self.capacity, [g for g, _ in rows]))
+        self._state = self._run(
+            _scatter_rows, self._state, pos, self._put(sub)
+        )
         self.stats["t_up_scatter_ms"] = self.stats.get(
             "t_up_scatter_ms", 0
         ) + (_time.perf_counter() - _t0) * 1000.0
@@ -1467,8 +1481,8 @@ class VectorStepEngine(IStepEngine):
         if not gs:
             return
         st = state if state is not None else self._state
-        idx = self._put(jnp.asarray(_pad_idx(gs)))
-        sub = jax.tree.map(np.asarray, _gather_rows(st, idx))
+        idx = self._put(_pad_idx(gs))
+        sub = jax.tree.map(np.asarray, self._run(_gather_rows, st, idx))
         for k, g in enumerate(gs):
             self._lease.disarm(g)  # scalar path re-arms at next upload
             node = self._meta[g].node
@@ -1981,8 +1995,12 @@ class VectorStepEngine(IStepEngine):
         from ..profiling import annotate
 
         with annotate("raft-device-step"):
-            new_state, out = K.step(old_state, inbox, out_capacity=self.O)
-            flags = np.asarray(_summarize_flags(old_state, new_state, out))
+            new_state, out = self._run(
+                K.step, old_state, inbox, out_capacity=self.O
+            )
+            flags = np.asarray(
+                self._run(_summarize_flags, old_state, new_state, out)
+            )
         inj = self.fault_injector
         if (
             inj is not None
@@ -2016,8 +2034,8 @@ class VectorStepEngine(IStepEngine):
             keep_new = np.ones((G,), bool)
             for _, g, _ in esc_rows:
                 keep_new[g] = False
-            new_state = _select_rows(
-                self._put_rows(jnp.asarray(keep_new)), old_state, new_state
+            new_state = self._run(
+                _select_rows, self._put_rows(keep_new), old_state, new_state
             )
             self._materialize_rows([g for _, g, _ in esc_rows], old_state)
             for node, g, si in esc_rows:
@@ -2055,7 +2073,7 @@ class VectorStepEngine(IStepEngine):
         ]
         idx4 = _build_idx4(buf_rows, slot_rows, need_rows, append_rows)
         detail, vals_np = _fetch_detail_vals(
-            new_state, out, idx4, sum_rows, self._put,
+            self, new_state, out, idx4, sum_rows,
             self.O, self.M, self.E, self.P, self.W,
         )
         if detail is not None:
@@ -2371,11 +2389,11 @@ class VectorStepEngine(IStepEngine):
         stream is rare."""
         for g, p, lane, _pid, _ss_index in snapshot_sends:
             if lane is not None:
-                self._state = _set_remote_snapshot(
-                    self._state,
-                    self._put(jnp.asarray([g], jnp.int32)),
-                    self._put(jnp.asarray([p], jnp.int32)),
-                    self._put(jnp.asarray([lane], jnp.int32)),
+                self._state = self._run(
+                    _set_remote_snapshot, self._state,
+                    self._put(np.asarray([g], np.int32)),
+                    self._put(np.asarray([p], np.int32)),
+                    self._put(np.asarray([lane], np.int32)),
                 )
 
     # -- append reconstruction -----------------------------------------

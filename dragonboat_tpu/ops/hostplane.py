@@ -842,7 +842,7 @@ def encode_tick_lane(  # hostplane-hot
     put on the :class:`TickLane` (sole plan ``[("tick", n)]``, no
     pending device-read ctx, so the tick would carry no hint): ~98 % of
     a launch at 1,000 groups x 3.  One numpy store puts them into the
-    ``[G]`` count vector ``_host_inbox_from_ticks`` expands on the
+    ``[G]`` count vector ``colocated._host_inbox`` expands on the
     device.  No ``Message``, no dict, no per-row Python: the per-row
     twin (:func:`split_lone_ticks` over ``_encode_rows``' output) is
     what every batch row took before, still takes when it carries
@@ -1150,6 +1150,16 @@ def check_encode_parity(batch, batch_gs, lane, ref, tick_lane=None) -> None:
         assert_encode_parity(batch, batch_gs, lane, ref, tick_lane)
     except HostPlaneParityError as e:  # pragma: no cover - bug path
         _record_failure(e)
+
+
+def check_upload_parity(flat: np.ndarray, ref: np.ndarray) -> None:
+    """The launch's one upload (``colocated._pack_launch``) packed from
+    the lane encode against the same packed from the whole-batch
+    encode: combo, position map and dense rows, int for int."""
+    if not np.array_equal(flat, ref):  # pragma: no cover - bug path
+        _record_failure(HostPlaneParityError(
+            _diff("launch upload", flat, ref)
+        ))
 
 
 class CompletionTrace(NamedTuple):

@@ -46,6 +46,7 @@ from . import colocated as C
 from . import engine as E
 from . import kernel as K
 from . import route as R
+from . import sync as S
 from .types import I32, make_inbox, make_out, make_state
 
 # canonical audit geometry — sizes chosen pairwise-distinct (see module
@@ -84,8 +85,8 @@ def _state(rows: Optional[int] = None):
     return make_state(rows or _g(), CANON["P"], CANON["W"])
 
 
-def _inbox(M: int, rows: Optional[int] = None):
-    return make_inbox(rows or _g(), M, CANON["E"])
+def _inbox(M: int):
+    return make_inbox(_g(), M, CANON["E"])
 
 
 def _out(M: int):
@@ -190,13 +191,10 @@ def _b_zero_inbox_rows():
     return (_inbox(CANON["M_ASM"]), jnp.zeros((_g(),), bool)), {}
 
 
-def _b_host_inbox_from_ticks():
-    return (_combo(),), dict(M=CANON["M"], E=CANON["E"])
-
-
-def _b_scatter_inbox_rows():
-    pos = jnp.full((_g(),), -1, I32)
-    return (_inbox(CANON["M"]), pos, _inbox(CANON["M"], 4)), {}
+def _b_host_inbox():
+    G, M, E, NSB = _g(), CANON["M"], CANON["E"], 4
+    flat = jnp.zeros((5 * G + NSB * S.inbox_row_ints(M, E),), I32)
+    return (flat,), dict(G=G, M=M, E=E, NSB=NSB)
 
 
 # audit-only jit of the consensus round: route() itself is a pure
@@ -283,7 +281,7 @@ ENTRY_POINTS: Tuple[EntryPoint, ...] = (
         "colocated._assemble_and_step",
         C._assemble_and_step,
         _b_assemble_and_step,
-        donate=(1, 2),
+        donate=(2,),
     ),
     EntryPoint(
         "colocated._route_step", C._route_step, _b_route_step, donate=(1,)
@@ -294,16 +292,7 @@ ENTRY_POINTS: Tuple[EntryPoint, ...] = (
     EntryPoint(
         "colocated._zero_inbox_rows", C._zero_inbox_rows, _b_zero_inbox_rows
     ),
-    EntryPoint(
-        "colocated._host_inbox_from_ticks",
-        C._host_inbox_from_ticks,
-        _b_host_inbox_from_ticks,
-    ),
-    EntryPoint(
-        "colocated._scatter_inbox_rows",
-        C._scatter_inbox_rows,
-        _b_scatter_inbox_rows,
-    ),
+    EntryPoint("colocated._host_inbox", C._host_inbox, _b_host_inbox),
     # route (audit-only jit wrappers)
     EntryPoint(
         "route.routed_round", _routed_round_audit, _b_routed_round,

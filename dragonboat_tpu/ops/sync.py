@@ -286,48 +286,88 @@ INBOX_FIELDS = (
 )
 
 
-def encode_inbox(
-    batches: Sequence[Sequence[Message]], M: int, E: int
-) -> Tuple[Inbox, List[int]]:
-    """Pack per-row ordered Message lists into an Inbox.
+def inbox_row_ints(M: int, E: int) -> int:
+    """Ints one inbox row packs into: the ten ``[M]`` fields, then
+    ``ent_term`` and ``ent_cc`` (``[M, E]`` each), in ``Inbox`` order."""
+    return M * (len(INBOX_FIELDS) + 2 * E)
 
-    Returns (inbox, overflow_rows): rows whose batch exceeds M slots or
+
+_I32_MIN, _I32_MAX = -2**31, 2**31 - 1
+
+
+def _fits_i32(vals):
+    """``vals`` (a non-empty sequence of ints bound for an int32 slice),
+    refused where one is outside int32: numpy casts a sequence stored
+    into a slice unsafely, so the value would wrap and the device read
+    another message."""
+    if min(vals) < _I32_MIN or max(vals) > _I32_MAX:
+        raise OverflowError(f"inbox field outside int32: {vals}")
+    return vals
+
+
+def encode_inbox_np(
+    batches: Sequence[Sequence[Message]], M: int, E: int,
+    out: Optional[np.ndarray] = None,
+) -> Tuple[np.ndarray, List[int]]:
+    """Pack per-row ordered Message lists into a ``[G, inbox_row_ints]``
+    int32 block, one row each, row-major: field f's slot i at column
+    ``f*M + i``, then ``ent_term[i, j]`` at ``10*M + i*E + j`` and
+    ``ent_cc`` ``M*E`` further.  ``out`` (all zero, at least
+    ``len(batches)`` rows) is filled in place.
+
+    Returns (block, overflow_rows): rows whose batch exceeds M slots or
     whose REPLICATE carries more than E entries must be host-stepped.
+    A field outside int32 raises ``OverflowError``.
     """
     G = len(batches)
-    cols = {k: np.zeros((G, M), np.int32) for k in INBOX_FIELDS}
-    ent_term = np.zeros((G, M, E), np.int32)
-    ent_cc = np.zeros((G, M, E), np.int32)
+    NF = len(INBOX_FIELDS)
+    if out is None:
+        out = np.zeros((G, inbox_row_ints(M, E)), np.int32)
+    ent_term = NF * M
+    ent_cc = ent_term + M * E
     overflow: List[int] = []
     for g, msgs in enumerate(batches):
         if len(msgs) > M:
             overflow.append(g)
             continue
+        row = out[g]
         for i, m in enumerate(msgs):
-            if len(m.entries) > E:
+            n = len(m.entries)
+            if n > E:
                 overflow.append(g)
                 break
-            cols["mtype"][g, i] = int(m.type)
-            cols["from_id"][g, i] = m.from_
-            cols["term"][g, i] = m.term
-            cols["log_term"][g, i] = m.log_term
-            cols["log_index"][g, i] = m.log_index
-            cols["commit"][g, i] = m.commit
-            cols["reject"][g, i] = int(m.reject)
-            cols["hint"][g, i] = m.hint
-            cols["hint_high"][g, i] = m.hint_high
-            cols["n_entries"][g, i] = len(m.entries)
-            for j, e in enumerate(m.entries):
-                ent_term[g, i, j] = e.term
-                ent_cc[g, i, j] = int(e.is_config_change())
-    return (
-        Inbox(
-            **{k: jnp.asarray(v) for k, v in cols.items()},
-            ent_term=jnp.asarray(ent_term),
-            ent_cc=jnp.asarray(ent_cc),
-        ),
-        overflow,
-    )
+            # INBOX_FIELDS order, one strided store a message
+            row[i:ent_term:M] = _fits_i32((
+                int(m.type), m.from_, m.term, m.log_term, m.log_index,
+                m.commit, int(m.reject), m.hint, m.hint_high, n,
+            ))
+            if n:
+                at = i * E
+                row[ent_term + at:ent_term + at + n] = _fits_i32(
+                    [e.term for e in m.entries]
+                )
+                row[ent_cc + at:ent_cc + at + n] = [
+                    int(e.is_config_change()) for e in m.entries
+                ]
+    return out, overflow
+
+
+def unpack_inbox(block, M: int, E: int) -> Inbox:
+    """The ``Inbox`` view of an ``encode_inbox_np`` block (numpy or
+    traced): ``[G, M]`` fields and ``[G, M, E]`` entry lanes."""
+    G = block.shape[0]
+    NF = len(INBOX_FIELDS)
+    cols = [block[:, f * M:(f + 1) * M] for f in range(NF)]
+    ents = block[:, NF * M:].reshape(G, 2, M, E)
+    return Inbox(*cols, ent_term=ents[:, 0], ent_cc=ents[:, 1])
+
+
+def encode_inbox(
+    batches: Sequence[Sequence[Message]], M: int, E: int
+) -> Tuple[Inbox, List[int]]:
+    """``encode_inbox_np`` as a device ``Inbox`` (one array a field)."""
+    block, overflow = encode_inbox_np(batches, M, E)
+    return Inbox(*map(jnp.asarray, unpack_inbox(block, M, E))), overflow
 
 
 def decode_out_row(

@@ -506,29 +506,36 @@ def test_mesh_tables_and_xbudget():
 
 
 def test_engine_warm_set_covers_proposal_launch_on_mesh():
-    """The product engine's ``mesh=`` path: a host inbox that went
-    through ``_scatter_inbox_rows`` (the first launch carrying a
-    proposal) keys its own step executable under a mesh, because its
-    entry lanes come out row-sharded where the tick-built inbox leaves
-    them replicated.  ``chip_smoke.py --chips 4`` met it on the v5e as
-    a 17 s compile inside the first write (PR 21); ``_warm()`` must
-    have compiled it."""
+    """The product engine's ``mesh=`` path: the launch's one program
+    (``_host_inbox``) runs on a replicated upload, and its outputs take
+    the shardings the compiler chose for that bucket's program, so the
+    step over a launch that carried dense rows (the first launch with a
+    proposal) may key its own executable under a mesh.  ``chip_smoke.py
+    --chips 4`` met it on the v5e as a 17 s compile inside the first
+    write (PR 21), when two programs built that inbox; ``_warm()`` must
+    have compiled every bucket's, and the step over each."""
+    from dragonboat_tpu.analysis import jitcheck
     from dragonboat_tpu.ops import colocated as C
-    from dragonboat_tpu.ops.types import I32, Inbox
+    from dragonboat_tpu.ops import sync as S
 
     G, M, E, O = 16, 8, 2, 16
     core = C.ColocatedVectorEngine(
         capacity=G, P=3, W=16, M=M, E=E, O=O, budget=4, mesh=_mesh(4)
     )
-    warmed = C._assemble_and_step._cache_size()
-    combo = core._put_rows(jnp.zeros((G, 4), jnp.int32))
-    host = C._host_inbox_from_ticks(combo, M=M, E=E)
-    host = C._scatter_inbox_rows(
-        host,
-        core._put_rows(jnp.full((G,), -1, jnp.int32)),
-        core._put(Inbox(*(jnp.zeros((1,) + f.shape[1:], I32)
-                          for f in host))),
-    )
-    C._assemble_and_step(core._state, host, core._pending, combo,
-                         out_capacity=O)
-    assert C._assemble_and_step._cache_size() == warmed
+    sentry = jitcheck.Sentry()
+    sentry.mark()
+    for nsb in (0, 1, 2, 4, 8, 16):
+        flat = np.zeros((5 * G + nsb * S.inbox_row_ints(M, E),), np.int32)
+        combo, host = C._host_inbox(core._put(flat), G=G, M=M, E=E, NSB=nsb)
+        new_state, out = C._assemble_and_step(
+            core._state, host, core._pending, combo, out_capacity=O)
+        C._route_step(
+            core._state, new_state, out,
+            core._put_rows(np.full((G, 3), -1, np.int32)),
+            core._put_rows(np.zeros((G, 3), np.int32)),
+            combo, PB=3 * 4, E=E, budget=4,
+        )
+        # rounds 2..K of a wave read the resident empty inbox
+        C._assemble_and_step(core._state, core._zero_host, core._pending,
+                             combo, out_capacity=O)
+    assert sentry.retraces() == []
