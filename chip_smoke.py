@@ -284,7 +284,7 @@ def main(argv=None) -> int:
         mesh_checks = {}
         if mesh is not None:
             # the two checks of __graft_entry__'s engine-on-mesh dry run
-            span = len(group.core._state.term.sharding.device_set)
+            span = len(group.core._state.rows.sharding.device_set)
             blocks = {nhs[1].engine.device_coordinate(s) for s in shards}
             mesh_checks = {
                 "state_spans_all_devices": span == args.chips,

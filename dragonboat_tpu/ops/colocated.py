@@ -98,6 +98,8 @@ from .engine import (
     _gather_detail,
     _gather_detail_vals,
     _gather_vals,
+    _row_mask,
+    _vals_of,
     _split_detail,
     N_FIELDS_BUF,
     N_VALS,
@@ -121,8 +123,17 @@ from .types import (
     MT_TICK,
     SLOT_UNUSED as SLOT_UNUSED_I,
     Inbox,
-    make_inbox,
-    make_state,
+    PackedInbox,
+    PackedOut,
+    PackedState,
+    make_inbox_packed,
+    make_state_packed,
+    pack_inbox,
+    pack_out,
+    pack_state,
+    unpack_inbox,
+    unpack_out,
+    unpack_state,
 )
 
 _log = get_logger("engine")
@@ -252,8 +263,8 @@ def _assemble_inbox(host: Inbox, pending: Inbox, alive: jnp.ndarray) -> Inbox:
 
 @functools.partial(jax.jit, static_argnames=("out_capacity",),
                    donate_argnums=(2,))
-def _assemble_and_step(state, host: Inbox, pending: Inbox, combo,
-                       *, out_capacity: int):
+def _assemble_and_step(state: PackedState, host: PackedInbox,
+                       pending: PackedInbox, combo, *, out_capacity: int):
     """Fused inbox assembly + kernel step in ONE program, with the
     pending inbox declared DONATED: a fast launch cadence at 65k-row
     geometry once out-allocated the device (r5 finding —
@@ -265,15 +276,28 @@ def _assemble_and_step(state, host: Inbox, pending: Inbox, combo,
     still treat ``pending`` as consumed.  ``host`` is NOT donated:
     rounds 2..K of a fused wave all read one resident all-zero host
     inbox.  ``combo`` is the [G, 4] lane block of the launch's upload
-    (see _C_*); the alive lane masks rows."""
-    full = _assemble_inbox(host, pending, combo[:, _C_ALIVE] != 0)
-    return K.step(state, full, out_capacity=out_capacity)
+    (see _C_*); the alive lane masks rows.  State, inboxes and outputs
+    cross in the packed forms (types.PackedState)."""
+    full = _assemble_inbox(unpack_inbox(host), unpack_inbox(pending),
+                           combo[:, _C_ALIVE] != 0)
+    new, out = K.step(unpack_state(state), full, out_capacity=out_capacity)
+    return pack_state(new), pack_out(out)
 
 
 @functools.partial(jax.jit, static_argnames=("PB", "E", "budget"),
                    donate_argnums=(1,))
-def _route_step(old_state, new_state, out, dest, rank, combo,
-                *, PB: int, E: int, budget: int):
+def _route_step(old: PackedState, new: PackedState, out: PackedOut, dest,
+                rank, combo, *, PB: int, E: int, budget: int):
+    """_route_step_body over the packed forms (types.PackedState)."""
+    merged, regions, stats, packed, flags = _route_step_body(
+        unpack_state(old), unpack_state(new), unpack_out(out), dest, rank,
+        combo, PB=PB, E=E, budget=budget,
+    )
+    return pack_state(merged), pack_inbox(regions), stats, packed, flags
+
+
+def _route_step_body(old_state, new_state, out, dest, rank, combo,
+                     *, PB: int, E: int, budget: int):
     """Post-launch tail: discard escalated rows' effects, route the
     outboxes into the next launch's pending regions (width P*budget,
     base=0 — host slots are prepended at the next assemble), and compute
@@ -281,12 +305,9 @@ def _route_step(old_state, new_state, out, dest, rank, combo,
     back O(1)-width arrays instead of the full summary/delivered
     matrices (multi-MB per launch)."""
     esc = out.escalate != 0
-
-    def sel(a, b):
-        m = (~esc).reshape((-1,) + (1,) * (a.ndim - 1))
-        return jnp.where(m, b, a)
-
-    merged = jax.tree.map(sel, old_state, new_state)
+    merged = jax.tree.map(
+        lambda a, b: jnp.where(_row_mask(~esc, a), b, a), old_state, new_state
+    )
     regions, stats, delivered = route(
         merged, out, dest, rank,
         M=PB, E=E, budget=budget, base=0,
@@ -341,9 +362,18 @@ _SEL_TIERS = (
     static_argnames=("CAP_B", "CAP_SL", "CAP_N", "CAP_A", "CAP_S",
                      "HOST_OFF"),
 )
-def _select_and_blob(merged, out, stats, packed, flags, combo,
-                     *, CAP_B: int, CAP_SL: int, CAP_N: int, CAP_A: int,
-                     CAP_S: int, HOST_OFF: int):
+def _select_and_blob(merged: PackedState, out: PackedOut, stats, packed,
+                     flags, combo, **caps):
+    """_select_and_blob_body over the packed forms (types.PackedState)."""
+    return _select_and_blob_body(
+        unpack_state(merged), unpack_out(out), stats, packed, flags, combo,
+        **caps,
+    )
+
+
+def _select_and_blob_body(merged, out, stats, packed, flags, combo,
+                          *, CAP_B: int, CAP_SL: int, CAP_N: int,
+                          CAP_A: int, CAP_S: int, HOST_OFF: int):
     """Device-side row selection + detail/vals gather + split-blob
     packing — the launch's one commit-proving readback, as a (head,
     detail) pair of int32 vectors whose D2H copies ride in parallel.
@@ -429,7 +459,7 @@ def _select_and_blob(merged, out, stats, packed, flags, combo,
     rows_need, n_need = pick(need_sel, CAP_N)
     rows_append, n_append = pick(append_sel, CAP_A)
     rows_sum, n_sum = pick(sum_sel, CAP_S)
-    vals = _gather_vals(merged, out, rows_sum)      # [CAP_S, N_VALS]
+    vals = _vals_of(merged, out, rows_sum)          # [CAP_S, N_VALS]
     head = jnp.concatenate([
         flags,
         jax.lax.bitcast_convert_type(packed, jnp.int32).reshape(-1),
@@ -455,15 +485,12 @@ def _select_and_blob(merged, out, stats, packed, flags, combo,
 
 
 @jax.jit
-def _zero_inbox_rows(inbox: Inbox, mask) -> Inbox:
+def _zero_inbox_rows(inbox: PackedInbox, mask) -> PackedInbox:
     """Zero the inbox rows where ``mask`` ([G] bool) — mask-select, not
     a data-dependent scatter (serial on TPU; see _scatter_rows)."""
-
-    def z(a):
-        m = mask.reshape((-1,) + (1,) * (a.ndim - 1))
-        return jnp.where(m, 0, a)
-
-    return Inbox(*(z(getattr(inbox, f)) for f in Inbox._fields))
+    return jax.tree.map(
+        lambda a: jnp.where(_row_mask(mask, a, 1), 0, a), inbox
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("G", "M", "E", "NSB"))
@@ -478,7 +505,8 @@ def _host_inbox(flat, *, G: int, M: int, E: int, NSB: int):
     counts, and the ``NSB`` rows with real host slots (wire messages,
     proposals, reads, tick-with-read-hint) are placed over it by the
     shared pos-map gather-select (see engine._place_rows).  NSB = 0 is
-    the launch with no such row."""
+    the launch with no such row.  The host inbox leaves packed
+    (types.PackedInbox)."""
     combo = flat[:4 * G].reshape(G, 4)
     tick_counts = combo[:, _C_TICKS]
     z = jnp.zeros((G, M), I32)
@@ -502,7 +530,7 @@ def _host_inbox(flat, *, G: int, M: int, E: int, NSB: int):
         pos = flat[4 * G:5 * G]
         sub = S.unpack_inbox(flat[5 * G:].reshape(NSB, -1), M, E)
         host = Inbox(*(_place_rows(h, s, pos) for h, s in zip(host, sub)))
-    return combo, host
+    return combo, pack_inbox(host)
 
 
 def _lane_inputs(lane, i: int) -> StepInputs:
@@ -645,7 +673,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                  sync_floor_ms: Optional[float] = None,
                  fused_rounds: Optional[int] = None):
         self.budget = budget
-        self._pending: Optional[Inbox] = None
+        self._pending: Optional[PackedInbox] = None
         self._pending_live = False  # last route delivered > 0 messages
         self._host_shard = np.zeros((capacity,), np.int64)
         self._host_replica = np.zeros((capacity,), np.int64)
@@ -1328,7 +1356,7 @@ class ColocatedVectorEngine(VectorStepEngine):
     def _warm(self) -> None:
         G, P, B, E, O = self.capacity, self.P, self.budget, self.E, self.O
         M, R = self.M, S.inbox_row_ints(self.M, self.E)
-        self._pending = self._put_rows(make_inbox(G, P * B, E))
+        self._pending = self._put_rows(make_inbox_packed(G, P * B, E))
         st = self._state
         # warm the REAL launch signature: the combo and the host inbox
         # both come out of _host_inbox over the launch's one committed
@@ -1349,7 +1377,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         new_st, out = _assemble_and_step(
             st, host, self._pending, combo, out_capacity=O
         )
-        self._pending = self._put_rows(make_inbox(G, P * B, E))
+        self._pending = self._put_rows(make_inbox_packed(G, P * B, E))
         merged_w, _regions_w, stats_w, packed_w, flags_w = _route_step(
             st, new_st, out, dest, rank, combo, PB=P * B, E=E, budget=B
         )
@@ -1396,7 +1424,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         # executable above.
         _assemble_and_step(st, host_sc, self._pending, combo_sc,
                            out_capacity=O)
-        self._pending = self._put_rows(make_inbox(G, P * B, E))
+        self._pending = self._put_rows(make_inbox_packed(G, P * B, E))
         one = self._put(jnp.zeros((1,), jnp.int32))
         _set_remote_snapshot(st, one, one, one)
         jax.block_until_ready(self._state)
@@ -1464,9 +1492,9 @@ class ColocatedVectorEngine(VectorStepEngine):
         if self._pending is None or not pairs:
             return
         idx = self._put(_pad_idx([g for _, g in pairs]))
-        sub = jax.tree.map(
+        sub = unpack_inbox(jax.tree.map(
             np.asarray, self._run(_gather_rows, self._pending, idx)
-        )
+        ))
         for k, (node, g) in enumerate(pairs):
             r = node.peer.raft
             base = int(self._base[g])  # routed lanes are shard-rebased
@@ -1704,12 +1732,12 @@ class ColocatedVectorEngine(VectorStepEngine):
                     meta.node.drop_device_reads()
         try:
             self._state = self._put_rows(
-                make_state(self.capacity, self.P, self.W,
-                           replica_ids=np.zeros(self.capacity))
+                make_state_packed(self.capacity, self.P, self.W,
+                                  replica_ids=np.zeros(self.capacity))
             )
-            self._pending = self._put_rows(
-                make_inbox(self.capacity, self.P * self.budget, self.E)
-            )
+            self._pending = self._put_rows(make_inbox_packed(
+                self.capacity, self.P * self.budget, self.E
+            ))
         except Exception:  # noqa: BLE001 — rebuilt lazily next launch
             self._pending = None
 
@@ -2863,7 +2891,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         if self._pending is None:
             # a prior launch failure consumed the donated pending inbox
             # and could not rebuild it (see the handler below)
-            self._pending = self._put_rows(make_inbox(G, P * B, E))
+            self._pending = self._put_rows(make_inbox_packed(G, P * B, E))
         self._leave(tok)
         # dispatch: host time to enqueue the wave's programs (the device
         # runs them behind the host; its own time is in the trace)
@@ -2898,7 +2926,7 @@ class ColocatedVectorEngine(VectorStepEngine):
             self._pending = None
             self._pending_live = False
             try:
-                self._pending = self._put_rows(make_inbox(G, P * B, E))
+                self._pending = self._put_rows(make_inbox_packed(G, P * B, E))
             except Exception:  # noqa: BLE001 — next launch rebuilds
                 pass
             self._clock_unmerged(batch, lane)

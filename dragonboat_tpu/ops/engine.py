@@ -40,6 +40,7 @@ become-leader noop barriers.  The merged entries flow out through
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -86,8 +87,19 @@ from .types import (
     KIND_WITNESS,
     RS_SNAPSHOT,
     SLOT_DROPPED,
+    DeviceOut,
     DeviceState,
-    make_state,
+    PackedInbox,
+    PackedOut,
+    PackedState,
+    STATE_BLOCKS,
+    make_state_packed,
+    pack_inbox,
+    pack_out,
+    pack_state,
+    unpack_inbox,
+    unpack_out,
+    unpack_state,
 )
 
 _log = get_logger("engine")
@@ -148,6 +160,11 @@ from .types import (  # noqa: E402 — alias block, not a new dependency
 )
 
 
+def _n_arrays(tree) -> int:
+    """The device arrays in ``tree`` (static ints and None are not)."""
+    return sum(isinstance(a, jax.Array) for a in jax.tree.leaves(tree))
+
+
 def _bucket(n: int) -> int:
     """Next power of two ≥ n (bounds jit recompiles for dynamic row sets)."""
     b = 1
@@ -165,24 +182,29 @@ def _pad_idx(idx: Sequence[int], pad: Optional[int] = None) -> np.ndarray:
     return out
 
 
-def _place_rows(a, b, pos):
+def _row_mask(mask, a, axis: int = 0):
+    """A [G] ``mask`` shaped to broadcast over ``a``'s row axis."""
+    return mask.reshape((1,) * axis + (-1,) + (1,) * (a.ndim - axis - 1))
+
+
+def _place_rows(a, b, pos, axis: int = 0):
     """a's row g := b[pos[g]] where pos[g] >= 0, else unchanged — the
     pos-map gather-select shared by every row placement (NOT
     a.at[idx].set(): a scatter with data-dependent row indices lowers
     to a serial per-row loop on TPU, the same pathology as
-    kernel._set_col; row uploads were ~seconds per launch)."""
-    take = jnp.clip(pos, 0, b.shape[0] - 1)
-    picked = b[take]
-    m = (pos >= 0).reshape((-1,) + (1,) * (a.ndim - 1))
-    return jnp.where(m, picked, a)
+    kernel._set_col; row uploads were ~seconds per launch).  ``axis``
+    is the row axis: 1 in a packed block (types.PackedState)."""
+    take = jnp.clip(pos, 0, b.shape[axis] - 1)
+    picked = b[(slice(None),) * axis + (take,)]
+    return jnp.where(_row_mask(pos >= 0, a, axis), picked, a)
 
 
 @jax.jit
-def _scatter_rows(state: DeviceState, pos, sub: DeviceState) -> DeviceState:
+def _scatter_rows(state: PackedState, pos, sub: PackedState) -> PackedState:
     """Place sub's rows into state at the rows marked by ``pos`` — a
     [G] int32 position map (pos[g] = row of ``sub`` to take, -1 = keep
     state's row)."""
-    return jax.tree.map(lambda a, b: _place_rows(a, b, pos), state, sub)
+    return jax.tree.map(lambda a, b: _place_rows(a, b, pos, 1), state, sub)
 
 
 def _pos_map(G: int, gs) -> np.ndarray:
@@ -195,17 +217,26 @@ def _pos_map(G: int, gs) -> np.ndarray:
 
 
 @jax.jit
-def _select_rows(keep_new, old: DeviceState, new: DeviceState) -> DeviceState:
-    def sel(a, b):
-        m = keep_new.reshape((-1,) + (1,) * (a.ndim - 1))
-        return jnp.where(m, b, a)
-
-    return jax.tree.map(sel, old, new)
+def _select_rows(keep_new, old: PackedState, new: PackedState) -> PackedState:
+    return jax.tree.map(
+        lambda a, b: jnp.where(_row_mask(keep_new, a, 1), b, a), old, new
+    )
 
 
 @jax.jit
-def _gather_rows(state: DeviceState, idx) -> DeviceState:
-    return jax.tree.map(lambda a: a[idx], state)
+def _gather_rows(x, idx):
+    """Rows ``idx`` of a packed state or inbox (rows: each block's
+    second axis), packed."""
+    return jax.tree.map(lambda a: a[:, idx], x)
+
+
+@functools.partial(jax.jit, static_argnames=("out_capacity",))
+def _step_rows(state: PackedState, inbox: PackedInbox, *, out_capacity: int):
+    """The base engine's step: the kernel and its flags word
+    (_summarize_flags) in one program, over the packed forms."""
+    old = unpack_state(state)
+    new, out = K.step(old, unpack_inbox(inbox), out_capacity=out_capacity)
+    return pack_state(new), pack_out(out), _summarize_flags(old, new, out)
 
 
 @jax.jit
@@ -266,8 +297,7 @@ def _summarize_flags(old: DeviceState, new: DeviceState, out) -> jnp.ndarray:
     return f.astype(I32)
 
 
-@jax.jit
-def _gather_vals(state, out, idx):
+def _vals_of(state: DeviceState, out: DeviceOut, idx):
     """Per-row VALUES block (_R_* order) for flagged rows — replaces the
     old full-width summary readback.  Split from _gather_detail because
     their cardinalities differ wildly: during an election storm most
@@ -292,7 +322,11 @@ def _gather_vals(state, out, idx):
 
 
 @jax.jit
-def _gather_detail(state, out, idx4):
+def _gather_vals(state: PackedState, out: PackedOut, idx):
+    return _vals_of(unpack_state(state), unpack_out(out), idx)
+
+
+def _detail_of(state: DeviceState, out: DeviceOut, idx4):
     """All heavy post-step detail reads in ONE dispatch and ONE [b, K]
     readback array: the four equal-length index sets travel as a stacked
     [4, b] transfer, and the flattened results concatenate on axis 1 so
@@ -310,6 +344,11 @@ def _gather_detail(state, out, idx4):
         state.ring_cc[idx_ring],
     )
     return jnp.concatenate([p.reshape(b, -1) for p in parts], axis=1)
+
+
+@jax.jit
+def _gather_detail(state: PackedState, out: PackedOut, idx4):
+    return _detail_of(unpack_state(state), unpack_out(out), idx4)
 
 
 def _detail_width(O: int, M: int, E: int, P: int, W: int) -> int:
@@ -334,14 +373,15 @@ def _split_detail(flat: np.ndarray, O: int, M: int, E: int, P: int, W: int):
 
 
 @jax.jit
-def _gather_detail_vals(state, out, idx4, idx_sum):
+def _gather_detail_vals(state: PackedState, out: PackedOut, idx4, idx_sum):
     """_gather_detail + _gather_vals in ONE dispatch and ONE flat 1-D
     readback.  A device->host sync on a remote-device link costs ~100 ms
     of round-trip latency regardless of size (measured r5); issuing the
     detail and values gathers as two programs with two np.asarray calls
     was two of the launch's ~5 round trips."""
-    detail = _gather_detail(state, out, idx4)
-    vals = _gather_vals(state, out, idx_sum)
+    state, out = unpack_state(state), unpack_out(out)
+    detail = _detail_of(state, out, idx4)
+    vals = _vals_of(state, out, idx_sum)
     return jnp.concatenate([detail.reshape(-1), vals.reshape(-1)])
 
 
@@ -417,12 +457,15 @@ def _fetch_detail_vals(eng, state, out, idx4, sum_rows, O, M, E, P, W,
     return detail, vals_np
 
 
+_PEER_FIELD = {f: i for i, f in enumerate(STATE_BLOCKS[1])}
+
+
 @jax.jit
-def _set_remote_snapshot(state: DeviceState, g_idx, p_idx, snap_idx):
-    return state._replace(
-        rstate=state.rstate.at[g_idx, p_idx].set(RS_SNAPSHOT),
-        snap_index=state.snap_index.at[g_idx, p_idx].set(snap_idx),
-    )
+def _set_remote_snapshot(state: PackedState, g_idx, p_idx, snap_idx):
+    peers = state.peers.at[_PEER_FIELD["rstate"], g_idx, p_idx].set(
+        RS_SNAPSHOT
+    ).at[_PEER_FIELD["snap_index"], g_idx, p_idx].set(snap_idx)
+    return state._replace(peers=peers)
 
 
 def _shift_msg_indexes(msg: Message, delta: int) -> Message:
@@ -703,6 +746,10 @@ class VectorStepEngine(IStepEngine):
             self._row_sharding = NamedSharding(
                 mesh, PartitionSpec(mesh.axis_names[0])
             )
+            # a packed block's rows are its SECOND axis (types.py)
+            self._block_sharding = NamedSharding(
+                mesh, PartitionSpec(None, mesh.axis_names[0])
+            )
             self._rep_sharding = NamedSharding(mesh, PartitionSpec())
             self._device = None
         else:
@@ -829,10 +876,17 @@ class VectorStepEngine(IStepEngine):
             # interpreter lock away once (PERF.md section 6)
             "device_puts": 0,
             "device_programs": 0,
+            # the arrays those calls hand over: one a put commits, and
+            # each one a program takes or returns (a signature check or
+            # an output object on the host, a buffer hold or an
+            # allocation in the runtime: PERF.md section 5, PR 38)
+            "device_arrays": 0,
         }
-        # inert rows: no peers, empty inbox -> the kernel never touches them
+        # inert rows: no peers, empty inbox -> the kernel never touches
+        # them.  Held packed (types.PackedState), like every value the
+        # programs chain from launch to launch
         self._state = self._put_rows(
-            make_state(capacity, P, W, replica_ids=np.zeros(capacity))
+            make_state_packed(capacity, P, W, replica_ids=np.zeros(capacity))
         )
         self._warm()
 
@@ -845,24 +899,33 @@ class VectorStepEngine(IStepEngine):
         committed and uncommitted calls silently doubles every compile
         (~60s each for the step kernel)."""
         self.stats["device_puts"] += 1
+        self.stats["device_arrays"] += len(jax.tree.leaves(x))
         if self._mesh is not None:
             return jax.device_put(x, self._rep_sharding)
         return jax.device_put(x, self._device)
 
     def _put_rows(self, x):
-        """Commit a full-capacity row pytree (state, inboxes, [G] masks)
-        — sharded over the groups axis in mesh mode."""
+        """Commit a full-capacity row value (a packed state or inbox,
+        [G] masks, [G, P] tables) — sharded over the groups axis in mesh
+        mode, which is a packed block's second axis."""
         self.stats["device_puts"] += 1
+        self.stats["device_arrays"] += len(jax.tree.leaves(x))
         if self._mesh is not None:
-            return jax.device_put(x, self._row_sharding)
+            packed = isinstance(x, (PackedState, PackedInbox))
+            return jax.device_put(
+                x, self._block_sharding if packed else self._row_sharding
+            )
         return jax.device_put(x, self._device)
 
     def _run(self, prog, *args, **kwargs):
         """Enqueue the jitted program ``prog``.  EVERY program the
         engine runs after ``_warm()`` goes through this, as every array
-        goes through ``_put``: it is where ``device_programs`` counts."""
+        goes through ``_put``: it is where ``device_programs`` and the
+        arrays a program takes and returns (``device_arrays``) count."""
         self.stats["device_programs"] += 1
-        return prog(*args, **kwargs)
+        out = prog(*args, **kwargs)
+        self.stats["device_arrays"] += _n_arrays((args, kwargs, out))
+        return out
 
     @staticmethod
     def _cq_grace(r) -> None:
@@ -897,12 +960,13 @@ class VectorStepEngine(IStepEngine):
         first real step doesn't stall the step worker for seconds (the
         persistent compilation cache makes this nearly free after the
         first process on a machine)."""
-        from .types import make_inbox
+        from .types import make_inbox_packed
 
         st = self._state
-        inbox = self._put_rows(make_inbox(self.capacity, self.M, self.E))
-        _, out = K.step(st, inbox, out_capacity=self.O)
-        _summarize_flags(st, st, out)
+        inbox = self._put_rows(
+            make_inbox_packed(self.capacity, self.M, self.E)
+        )
+        _, out, _ = _step_rows(st, inbox, out_capacity=self.O)
         _select_rows(self._put_rows(jnp.ones((self.capacity,), bool)), st, st)
         pos0 = self._put_rows(
             jnp.full((self.capacity,), -1, jnp.int32)
@@ -1383,7 +1447,7 @@ class VectorStepEngine(IStepEngine):
         # padding happens in numpy INSIDE state_from_rafts: the old
         # eager jnp slice/repeat/concat per field compiled ~93 tiny
         # programs per new bucket shape on the remote TPU link
-        sub = S.state_from_rafts(
+        sub = S.packed_state_from_rafts(
             [r for _, r in rows], self.P, self.W, bases=bases,
             pad_to=_bucket(len(rows)),
         )
@@ -1470,7 +1534,7 @@ class VectorStepEngine(IStepEngine):
         self._lease.arm(g, r.election_timeout, r.election_tick)
 
     def _materialize_rows(
-        self, gs: List[int], state: Optional[DeviceState] = None
+        self, gs: List[int], state: Optional[PackedState] = None
     ) -> None:
         """Device -> scalar for rows leaving the device (batched gather).
 
@@ -1482,7 +1546,9 @@ class VectorStepEngine(IStepEngine):
             return
         st = state if state is not None else self._state
         idx = self._put(_pad_idx(gs))
-        sub = jax.tree.map(np.asarray, self._run(_gather_rows, st, idx))
+        sub = unpack_state(
+            jax.tree.map(np.asarray, self._run(_gather_rows, st, idx))
+        )
         for k, g in enumerate(gs):
             self._lease.disarm(g)  # scalar path re-arms at next upload
             node = self._meta[g].node
@@ -1987,20 +2053,18 @@ class VectorStepEngine(IStepEngine):
         msg_rows: List[List[Message]] = [[] for _ in range(G)]
         for (_node, g, _si, _plan), msgs in zip(batch, row_msgs_of):
             msg_rows[g] = msgs
-        inbox, overflow = S.encode_inbox(msg_rows, M, E)
+        block, overflow = S.encode_inbox_np(msg_rows, M, E)
         assert not overflow, f"planner let oversized rows through: {overflow}"
-        inbox = self._put_rows(inbox)
+        inbox = self._put_rows(pack_inbox(S.unpack_inbox(block, M, E), np))
 
         old_state = self._state
         from ..profiling import annotate
 
         with annotate("raft-device-step"):
-            new_state, out = self._run(
-                K.step, old_state, inbox, out_capacity=self.O
+            new_state, out, flags = self._run(
+                _step_rows, old_state, inbox, out_capacity=self.O
             )
-            flags = np.asarray(
-                self._run(_summarize_flags, old_state, new_state, out)
-            )
+            flags = np.asarray(flags)
         inj = self.fault_injector
         if (
             inj is not None
@@ -2600,7 +2664,8 @@ class VectorStepEngine(IStepEngine):
         # snapshot_sends entries are (g, p, lane, pid, ss_index); lane is
         # None when the durable snapshot sits below the row's base (the
         # host-excursion path)
-        peer_ids = np.asarray(self._state.peer_id[g])  # small row fetch
+        # small row fetch
+        peer_ids = np.asarray(self._state.peers[_PEER_FIELD["peer_id"], g])
         ss = r.log.logdb.snapshot()
         for p in range(self.P):
             if not need_row[p]:

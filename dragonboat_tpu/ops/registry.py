@@ -47,7 +47,8 @@ from . import engine as E
 from . import kernel as K
 from . import route as R
 from . import sync as S
-from .types import I32, make_inbox, make_out, make_state
+from .types import (I32, make_inbox, make_out, make_state, pack_inbox,
+                    pack_out, pack_state)
 
 # canonical audit geometry — sizes chosen pairwise-distinct (see module
 # docstring); PB = P*budget is the colocated routed-region width and
@@ -93,6 +94,19 @@ def _out(M: int):
     return make_out(_g(), CANON["P"], M, CANON["E"], CANON["O"])
 
 
+# the engine's programs take and return the packed forms (types.py)
+def _pstate(rows: Optional[int] = None):
+    return pack_state(_state(rows))
+
+
+def _pinbox(M: int):
+    return pack_inbox(_inbox(M))
+
+
+def _pout(M: int):
+    return pack_out(_out(M))
+
+
 def _combo():
     return jnp.zeros((_g(), 4), I32)
 
@@ -116,17 +130,21 @@ def _b_step_internal():
     return (st, ib), dict(out_capacity=CANON["O"])
 
 
+def _b_step_rows():
+    return (_pstate(), _pinbox(CANON["M"])), dict(out_capacity=CANON["O"])
+
+
 def _b_scatter_rows():
     pos = jnp.full((_g(),), -1, I32)
-    return (_state(), pos, _state(4)), {}
+    return (_pstate(), pos, _pstate(4)), {}
 
 
 def _b_select_rows():
-    return (jnp.zeros((_g(),), bool), _state(), _state()), {}
+    return (jnp.zeros((_g(),), bool), _pstate(), _pstate()), {}
 
 
 def _b_gather_rows():
-    return (_state(), _idx(4)), {}
+    return (_pstate(), _idx(4)), {}
 
 
 def _b_summarize_flags():
@@ -134,19 +152,19 @@ def _b_summarize_flags():
 
 
 def _b_gather_vals():
-    return (_state(), _out(CANON["M"]), _idx(4)), {}
+    return (_pstate(), _pout(CANON["M"]), _idx(4)), {}
 
 
 def _b_gather_detail():
-    return (_state(), _out(CANON["M"]), _idx4(4)), {}
+    return (_pstate(), _pout(CANON["M"]), _idx4(4)), {}
 
 
 def _b_gather_detail_vals():
-    return (_state(), _out(CANON["M"]), _idx4(4), _idx(4)), {}
+    return (_pstate(), _pout(CANON["M"]), _idx4(4), _idx(4)), {}
 
 
 def _b_set_remote_snapshot():
-    return (_state(), _idx(1), _idx(1), _idx(1)), {}
+    return (_pstate(), _idx(1), _idx(1), _idx(1)), {}
 
 
 def _b_assemble_inbox():
@@ -159,7 +177,7 @@ def _b_assemble_inbox():
 
 def _b_assemble_and_step():
     return (
-        _state(), _inbox(CANON["M"]), _inbox(CANON["PB"]), _combo(),
+        _pstate(), _pinbox(CANON["M"]), _pinbox(CANON["PB"]), _combo(),
     ), dict(out_capacity=CANON["O"])
 
 
@@ -167,7 +185,7 @@ def _b_route_step():
     dest = jnp.full((_g(), CANON["P"]), -1, I32)
     rank = jnp.zeros((_g(), CANON["P"]), I32)
     return (
-        _state(), _state(), _out(CANON["M_ASM"]), dest, rank, _combo(),
+        _pstate(), _pstate(), _pout(CANON["M_ASM"]), dest, rank, _combo(),
     ), dict(PB=CANON["PB"], E=CANON["E"], budget=CANON["budget"])
 
 
@@ -175,8 +193,8 @@ def _b_select_and_blob():
     G = _g()
     nwords = (CANON["O"] + 31) // 32
     return (
-        _state(),
-        _out(CANON["M_ASM"]),
+        _pstate(),
+        _pout(CANON["M_ASM"]),
         jnp.zeros((6,), I32),
         jnp.zeros((G, nwords), jnp.uint32),
         jnp.zeros((G,), I32),
@@ -188,7 +206,7 @@ def _b_select_and_blob():
 
 
 def _b_zero_inbox_rows():
-    return (_inbox(CANON["M_ASM"]), jnp.zeros((_g(),), bool)), {}
+    return (_pinbox(CANON["PB"]), jnp.zeros((_g(),), bool)), {}
 
 
 def _b_host_inbox():
@@ -255,6 +273,7 @@ ENTRY_POINTS: Tuple[EntryPoint, ...] = (
         g_last=True,
     ),
     # engine helpers (the per-launch gather/scatter plumbing)
+    EntryPoint("engine._step_rows", E._step_rows, _b_step_rows),
     EntryPoint("engine._scatter_rows", E._scatter_rows, _b_scatter_rows),
     EntryPoint("engine._select_rows", E._select_rows, _b_select_rows),
     EntryPoint("engine._gather_rows", E._gather_rows, _b_gather_rows),

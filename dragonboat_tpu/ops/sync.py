@@ -40,7 +40,9 @@ from .types import (
     KIND_VOTER,
     KIND_WITNESS,
     Inbox,
+    PackedState,
     make_state_np,
+    pack_state,
 )
 
 import jax.numpy as jnp
@@ -80,6 +82,23 @@ def state_from_rafts(
     eager op is a fresh tiny compile (~31 fields x 3 ops x ~0.4 s ate
     46% of the r4 10k-shard election as "upload" time).
     """
+    cols = _state_cols(rafts, P, W, bases, pad_to)
+    return DeviceState(**{k: jnp.asarray(v) for k, v in cols.items()})
+
+
+def packed_state_from_rafts(
+    rafts: Sequence[Raft], P: int, W: int,
+    bases: Optional[Sequence[int]] = None,
+    pad_to: int = 0,
+) -> PackedState:
+    """``state_from_rafts`` as host (numpy) blocks: the engine uploads
+    its rows in the packed form (types.PackedState) in one transfer."""
+    return pack_state(
+        DeviceState(**_state_cols(rafts, P, W, bases, pad_to)), np
+    )
+
+
+def _state_cols(rafts, P, W, bases, pad_to) -> Dict[str, np.ndarray]:
     G = len(rafts)
     # pure-NUMPY staging end to end: make_state_np never touches the
     # device, so packing costs no device->host readbacks (31 per batch
@@ -124,7 +143,7 @@ def state_from_rafts(
                 [v, np.repeat(v[-1:], pad_to - v.shape[0], axis=0)]
             )
         out[k] = v
-    return DeviceState(**{k: jnp.asarray(v) for k, v in out.items()})
+    return out
 
 
 def _peer_ids(rafts, P):

@@ -308,6 +308,101 @@ class DeviceOut(NamedTuple):
 
 
 # ---------------------------------------------------------------------------
+# packed forms: what crosses a jitted program's boundary
+# ---------------------------------------------------------------------------
+# Every array a program takes or returns costs the host a signature check
+# or an output object, and the runtime a buffer hold or an allocation:
+# ~12 us an array on the chip machine, against ~0.6 ms a program (PERF.md
+# §5, PR 38).  So the values the engine chains from launch to launch (the
+# state, the inboxes, the step's outputs) cross as a few int32 blocks: the
+# fields of one shape class stacked along a new LEADING axis, so each
+# field's slice keeps today's layout with G where it is now.  Programs
+# unpack at their first line and pack at their return, inside the jit;
+# the kernel, route() and their tests keep the NamedTuples.  A block is a
+# tuple of field names (stacked) or one name (the field as it is).
+STATE_BLOCKS = (DeviceState._fields[:21], DeviceState._fields[21:29],
+                DeviceState._fields[29:])
+INBOX_BLOCKS = (Inbox._fields[:10], Inbox._fields[10:])
+OUT_BLOCKS = ("buf",
+              ("count", "escalate", "append_lo", "barrier_idx",
+               "barrier_term"),
+              "need_snapshot", ("slot_base", "slot_term"), "ent_drop")
+
+
+class PackedState(NamedTuple):
+    """``DeviceState`` as three blocks (STATE_BLOCKS order)."""
+
+    rows: jnp.ndarray    # [21, G]: shard_id .. transfer_target
+    peers: jnp.ndarray   # [8, G, P]: peer_id .. granted
+    ring: jnp.ndarray    # [2, G, W]: ring_term, ring_cc
+
+    @property
+    def G(self) -> int:
+        return self.rows.shape[1]
+
+
+class PackedInbox(NamedTuple):
+    """``Inbox`` as two blocks (INBOX_BLOCKS order)."""
+
+    slots: jnp.ndarray   # [10, G, M]: mtype .. n_entries
+    ents: jnp.ndarray    # [2, G, M, E]: ent_term, ent_cc
+
+
+class PackedOut(NamedTuple):
+    """``DeviceOut`` as five arrays (OUT_BLOCKS order)."""
+
+    buf: jnp.ndarray            # [G, O, N_FIELDS]
+    rows: jnp.ndarray           # [5, G]: count .. barrier_term
+    need_snapshot: jnp.ndarray  # [G, P]
+    slots: jnp.ndarray          # [2, G, M]: slot_base, slot_term
+    ent_drop: jnp.ndarray       # [G, M, E]
+
+
+def _pack(cls, blocks, nt, xp):
+    return cls(*(
+        getattr(nt, b) if isinstance(b, str)
+        else xp.stack([getattr(nt, f) for f in b])
+        for b in blocks
+    ))
+
+
+def _unpack(cls, blocks, packed):
+    cols = {}
+    for b, a in zip(blocks, packed):
+        if isinstance(b, str):
+            cols[b] = a
+        else:
+            cols.update((f, a[i]) for i, f in enumerate(b))
+    return cls(**cols)
+
+
+def pack_state(s: DeviceState, xp=jnp) -> PackedState:
+    """``xp`` is ``jnp`` inside a program, ``np`` for a host-built
+    value (no device op before its one upload)."""
+    return _pack(PackedState, STATE_BLOCKS, s, xp)
+
+
+def unpack_state(p: PackedState) -> DeviceState:
+    return _unpack(DeviceState, STATE_BLOCKS, p)
+
+
+def pack_inbox(i: Inbox, xp=jnp) -> PackedInbox:
+    return _pack(PackedInbox, INBOX_BLOCKS, i, xp)
+
+
+def unpack_inbox(p: PackedInbox) -> Inbox:
+    return _unpack(Inbox, INBOX_BLOCKS, p)
+
+
+def pack_out(o: DeviceOut, xp=jnp) -> PackedOut:
+    return _pack(PackedOut, OUT_BLOCKS, o, xp)
+
+
+def unpack_out(p: PackedOut) -> DeviceOut:
+    return _unpack(DeviceOut, OUT_BLOCKS, p)
+
+
+# ---------------------------------------------------------------------------
 # constructors
 # ---------------------------------------------------------------------------
 def _splitmix32_np(x: np.ndarray) -> np.ndarray:
@@ -455,6 +550,19 @@ def make_inbox(G: int, M: int, E: int) -> Inbox:
         n_entries=zm,
         ent_term=jnp.zeros((G, M, E), I32),
         ent_cc=jnp.zeros((G, M, E), I32),
+    )
+
+
+def make_state_packed(G: int, P: int, W: int, **kw) -> PackedState:
+    """``make_state`` as host (numpy) blocks, for one upload."""
+    return pack_state(DeviceState(**make_state_np(G, P, W, **kw)), np)
+
+
+def make_inbox_packed(G: int, M: int, E: int) -> PackedInbox:
+    """An empty inbox as host (numpy) blocks, for one upload."""
+    return PackedInbox(
+        np.zeros((len(INBOX_BLOCKS[0]), G, M), np.int32),
+        np.zeros((len(INBOX_BLOCKS[1]), G, M, E), np.int32),
     )
 
 

@@ -12,7 +12,10 @@ the reference:
   (b) a quiet launch hands the runtime exactly 1 array, and 10 programs
       on a K = 3 wave, 4 on a single round, counted by shims over
       ``jax.device_put`` and the registry's programs, and the engine's
-      ``device_puts`` / ``device_programs`` agree with the shims;
+      ``device_puts`` / ``device_programs`` agree with the shims; so
+      does ``device_arrays`` (PR 38), every array a put commits and a
+      program takes or returns: 161 on the wave, 57 on a round, the
+      packed forms of types.py (~790 and ~270 before);
   (d) after ``_warm()`` no bucket of dense rows traces anything new.
 
 (c), a fused wave against K serial launches, is tests/test_pipeline.py's
@@ -33,7 +36,7 @@ from dragonboat_tpu.ops import kernel as K
 from dragonboat_tpu.ops import registry
 from dragonboat_tpu.ops import sync as S
 from dragonboat_tpu.ops.engine import _bucket, _place_rows, _pos_map
-from dragonboat_tpu.ops.types import I32, MT_TICK, Inbox
+from dragonboat_tpu.ops.types import I32, MT_TICK, Inbox, unpack_inbox
 from dragonboat_tpu.pb import Entry, EntryType, Message, MessageType
 
 G, M, E = 64, 8, 4
@@ -176,6 +179,7 @@ def test_one_program_builds_the_inbox_the_two_built(case, seed):
     assert flat.dtype == np.int32
     assert flat.shape == (5 * G + nsb * S.inbox_row_ints(M, E),)
     combo, got = C._host_inbox(jnp.asarray(flat), G=G, M=M, E=E, NSB=nsb)
+    got = unpack_inbox(got)
     assert np.array_equal(combo, want_combo)
     for f in Inbox._fields:
         a, b = np.asarray(getattr(got, f)), np.asarray(getattr(want, f))
@@ -287,18 +291,24 @@ def _count_real_calls(monkeypatch):
     """Counting shims over ``jax.device_put`` and over every jitted
     program of the registry, in the modules that call them: what the
     engine really hands the runtime, whatever its own counters say."""
-    real = {"device_puts": 0, "device_programs": 0}
+    real = {"device_puts": 0, "device_programs": 0, "device_arrays": 0}
     device_put = jax.device_put
 
-    def counted_put(*args, **kwargs):
+    def arrays(tree):
+        return sum(isinstance(a, jax.Array) for a in jax.tree.leaves(tree))
+
+    def counted_put(x, *args, **kwargs):
         real["device_puts"] += 1
-        return device_put(*args, **kwargs)
+        real["device_arrays"] += len(jax.tree.leaves(x))
+        return device_put(x, *args, **kwargs)
 
     monkeypatch.setattr(jax, "device_put", counted_put)
     for name, fn in registry.runtime_entry_points():
         def counted_program(*args, _fn=fn, **kwargs):
             real["device_programs"] += 1
-            return _fn(*args, **kwargs)
+            out = _fn(*args, **kwargs)
+            real["device_arrays"] += arrays((args, kwargs, out))
+            return out
 
         attr = name.split(".", 1)[1]
         callers = [m for m in (C, engine, K) if getattr(m, attr, None) is fn]
@@ -323,7 +333,7 @@ def test_a_quiet_launch_is_one_upload_and_ten_programs_or_four(monkeypatch):
         launch = core._launch_generation
         quiet = ("uploaded_rows", "sel_fallbacks", "host_rows_stepped",
                  "escalations", "pipeline_fences")
-        calls = ("device_puts", "device_programs")
+        calls = ("device_puts", "device_programs", "device_arrays")
 
         def counted(batch, lane):
             st, was = dict(core.stats), dict(real)
@@ -354,9 +364,11 @@ def test_a_quiet_launch_is_one_upload_and_ten_programs_or_four(monkeypatch):
             st = dict(core.stats)
             monkeypatch.undo()
         # counted by the shims, not by the engine: the runtime was handed
-        # exactly one array and ten programs (four on a single round)
+        # exactly one upload and ten programs (four on a single round),
+        # and the arrays of the packed forms: the upload, _host_inbox's
+        # 1 + 3, and a round's 16 + 22 + 14
         kinds = set(seen)
-        assert kinds == {(1, 1, 10), (0, 1, 4)}, kinds
+        assert kinds == {(1, 1, 10, 5 + 3 * 52), (0, 1, 4, 5 + 52)}, kinds
         # and over everything the engine did meanwhile, quiet or not, its
         # counters are what the shims saw
         assert st["evict_host_plan"] > st0.get("evict_host_plan", 0)
