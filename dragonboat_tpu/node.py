@@ -90,6 +90,7 @@ class StepInputs:
         "transfers",
         "ticks",
         "gc_ticks",
+        "input_t0",
     )
 
     def __init__(
@@ -118,6 +119,8 @@ class StepInputs:
         # timeouts stay bounded in wall time during step stalls) but
         # drive no raft ticks
         self.gc_ticks = gc_ticks
+        # the node's input_t0 this drain took (0.0: none stamped)
+        self.input_t0 = 0.0
 
 
 class Node:
@@ -145,6 +148,7 @@ class Node:
         "apply_work_ready", "step_work_ready",
         "log_reader", "sm", "_stop_event", "peer", "quiesce",
         "wake", "parked_at_tick", "tracer", "_trace_spans",
+        "input_hint", "input_t0",
     )
 
     def __init__(
@@ -420,11 +424,26 @@ class Node:
         # rejoin the active tick set and be granted the elapsed ticks
         self.wake: Optional[Callable[[], None]] = None
         self.parked_at_tick = 0
+        # the colocated engine's input hint, handed at its attach: the
+        # append of the queue its launches take (ops/colocated.py
+        # _pull_hinted), so that a launch carries this replica's queued
+        # input whichever member's worker makes it.  input_t0 is the
+        # perf_counter() of the first input queued since the step
+        # drained this replica's queues, 0.0 for none (t_input_wait_ms)
+        self.input_hint: Optional[Callable[["Node"], None]] = None
+        self.input_t0 = 0.0
 
     # ------------------------------------------------------------------
     # public-API-side entry points (any thread)
     # ------------------------------------------------------------------
     def _wake(self) -> None:
+        h = self.input_hint
+        if h is not None:
+            # lock-free, a hint like has_work: a replica missed here is
+            # still drained by its own worker or the coalesce scan
+            if not self.input_t0:
+                self.input_t0 = time.perf_counter()
+            h(self)
         w = self.wake
         if w is not None:
             w()
@@ -644,6 +663,19 @@ class Node:
         # raftlint: ignore[guarded-by] lock-free scrape-time snapshot
         return (self._ticks_in - self._ticks_taken) + self._pending_ticks
 
+    def has_queued_input(self) -> bool:
+        """A non-tick input is queued: what ``_wake`` announces (a lock-
+        free hint, with has_work's benign races)."""
+        # raftlint: ignore[guarded-by] lock-free hint; drain under _qlock linearizes
+        return bool(
+            self._received
+            or self._proposals
+            or self._read_indexes
+            or self.pending_read_index.queued
+            or self._config_changes
+            or self._leader_transfers
+        )
+
     def has_work(self) -> bool:
         # lock-free reads: each container's truthiness/len is atomic
         # under the GIL, and has_work is only ever a HINT (the drain
@@ -653,15 +685,10 @@ class Node:
         # 294 s coalesce bill at 50k rows (round 5)
         # raftlint: ignore[guarded-by] lock-free hint; drain under _qlock linearizes
         if (
-            self._received
-            or self._proposals
-            or self._read_indexes
-            or self._config_changes
-            or self._cc_to_apply
-            or self._leader_transfers
+            self._ticks_in != self._ticks_taken
             or self._pending_ticks
-            or self._ticks_in != self._ticks_taken
-            or self.pending_read_index.queued
+            or self._cc_to_apply
+            or self.has_queued_input()
         ):
             return True
         return self.peer.has_update()
@@ -669,10 +696,13 @@ class Node:
     # ------------------------------------------------------------------
     # step path (owning step worker only)
     # ------------------------------------------------------------------
-    def drain_step_inputs(self) -> "StepInputs":
+    def drain_step_inputs(self, ticks: bool = True) -> "StepInputs":
         """Atomically drain every input queue (the first half of stepNode;
         split out so a vectorized step engine can route drained inputs to
-        the device or replay them on the scalar peer — ops/engine.py)."""
+        the device or replay them on the scalar peer — ops/engine.py).
+        ``ticks`` False leaves the tick lane and the deferred backlog
+        for a later drain: the colocated engine's launch that carries
+        another member's replica for its queued input alone."""
         # consume the lock-free ticker lane first (this step worker is
         # _ticks_taken's only writer).  The raft-clock backlog is capped
         # at one election window: a node stalled past that (e.g. behind
@@ -681,18 +711,22 @@ class Node:
         # between them.  Dropped ticks slow only the RAFT clock
         # (liveness-safe); they still advance the logical clock via
         # gc_ticks so pending-future deadlines don't stretch.
-        lane = self._ticks_in - self._ticks_taken
+        lane = self._ticks_in - self._ticks_taken if ticks else 0
         self._ticks_taken += lane
         with self._qlock:
             # swap, don't copy: non-empty queue lists hand over
             # wholesale and fresh empties replace them; empty inputs
             # stay the shared () from StepInputs.__init__
-            total = self._pending_ticks + lane
+            total = self._pending_ticks + lane if ticks else 0
             cap = self.config.election_rtt
             si = StepInputs(
                 ticks=min(total, cap),
                 gc_ticks=max(0, total - cap),
             )
+            # the wait stamp goes with the queues it dates, taken
+            # before them: an input queued after this stamps afresh
+            si.input_t0 = self.input_t0
+            self.input_t0 = 0.0
             if self._received:
                 si.received = self._received
                 self._received = []
@@ -711,7 +745,8 @@ class Node:
             if self._leader_transfers:
                 si.transfers = self._leader_transfers
                 self._leader_transfers = []
-            self._pending_ticks = 0
+            if ticks:
+                self._pending_ticks = 0
         # the reads queued since the last drain: one ctx (a second for
         # those that asked for leader-or-nothing), behind any ctx a
         # step gave back (requeue_inputs)

@@ -157,6 +157,10 @@ _CPU_OF = {
     )
 }
 _STOPPED = operator.attrgetter("stopped")
+# the coalesce scan's floor: inside it at most one launch goes without a
+# scan (_coalesce); at step-call periods of half of it the input hint
+# pulls nothing (_pull_hinted)
+_SCAN_FLOOR_S = 0.2
 
 # -- double-buffered generations (the launch pipeline) -----------------
 # Generations in flight at once.  2 double-buffers: while generation
@@ -717,6 +721,9 @@ class ColocatedVectorEngine(VectorStepEngine):
         self._last_coalesce_scan = 0.0
         self._scan_cost = 0.0
         self._scan_skipped = False  # the last launch went without one
+        # the step calls' period, smoothed (_pull_hinted)
+        self._pull_at = 0.0
+        self._period_avg = 0.0
         # adaptive device-select capacities for the single-sync launch
         # blob (see _select_and_blob): detail rows are ~2 KB each so
         # CAP_D tracks actual peaks tightly; vals rows are 40 B so
@@ -735,6 +742,10 @@ class ColocatedVectorEngine(VectorStepEngine):
         from collections import deque as _deque
 
         self._inflight: "_deque[_InFlightGen]" = _deque()
+        # the input hint: nodes whose producers queued input since a
+        # launch last took it (node._wake appends, _pull_hinted takes;
+        # both GIL-atomic, so neither side locks)
+        self._input_hint: "_deque" = _deque()
         self._pipeline_depth = max(
             1,
             pipeline_depth
@@ -805,6 +816,14 @@ class ColocatedVectorEngine(VectorStepEngine):
         self.stats.update(
             launches=0, routed_delivered=0, routed_dropped=0,
             coalesced_rows=0, shard_rebases=0,
+            # rows a launch carried for the input hint alone (neither
+            # the caller's nor the coalesce scan's), and for every row
+            # the plan drained non-tick input from on the full path,
+            # the time since the first of that input was queued
+            input_rows_pulled=0, t_input_wait_ms=0.0, input_waits=0,
+            # launches that took the hint and pulled nothing, their
+            # step calls running past half the scan's floor
+            input_hint_closed=0,
             # ---- the account of the core's wall time (ms), always on;
             # docs/OBSERVABILITY.md "Counters".  A step call's time is
             # t_launch_ms and the time between calls t_between_ms: the
@@ -859,8 +878,10 @@ class ColocatedVectorEngine(VectorStepEngine):
             # the lease, renewed every launch (hostplane.LeaseAges):
             # armed leader rows a completion stepped with ticks, and of
             # those the ones a quorum had answered since the feed
-            # (F_QUORUM_FRESH), whose lease age started over
-            lease_rows_armed=0, lease_rows_fresh=0,
+            # (F_QUORUM_FRESH), whose lease age started over; and the
+            # rows of the engine a completion anchored, at its feed or
+            # at an earlier feed whose answers came in this launch
+            lease_rows_armed=0, lease_rows_fresh=0, lease_rows_anchored=0,
             # the apply workers' totals over every member NodeHost,
             # folded in once a step call (_fold_apply): batches and
             # entries applied, time inside node.apply() (wall, and the
@@ -1038,7 +1059,8 @@ class ColocatedVectorEngine(VectorStepEngine):
         self._lease.arm(g, r.election_timeout)
 
     def _lease_pass(  # hostplane-hot
-        self, nodes, gs, clock, fed, n_step, skip, flags, vals_np, pos_sum
+        self, nodes, gs, clock, fed, n_step, skip, flags, vals_np, pos_sum,
+        quiet,
     ) -> int:
         """Per-generation device-lease evidence pass: see
         hostplane.LeaseAges.  Runs before the bulk mirror write (role
@@ -1050,7 +1072,8 @@ class ColocatedVectorEngine(VectorStepEngine):
         ``n_step`` stepped rows, then the other live ones; ``clock`` and
         ``fed`` the clock ticks and the device ticks of the stepped
         rows, ``nodes`` the rows' nodes and ``skip`` the rows to leave
-        alone (escalated, stopped, detached; None = none).  Python
+        alone (escalated, stopped, detached; None = none), ``quiet``
+        the stepped rows whose host slots carried no message.  Python
         touches a row only where its role changed (arm / disarm);
         returns how many rows that was.  ``hostplane.lease_pass_rows``
         is the per-row twin the parity oracle runs beside it."""
@@ -1062,6 +1085,7 @@ class ColocatedVectorEngine(VectorStepEngine):
             gs = gs[keep]
             keep = keep[:n_step]
             gs_step, clock, fed = gs_step[keep], clock[keep], fed[keep]
+            quiet = quiet[keep]
         walked = 0
         if vals_np is not None and len(vals_np):
             k = pos_sum[gs]
@@ -1077,9 +1101,11 @@ class ColocatedVectorEngine(VectorStepEngine):
                     lease.arm(int(g_has[c]), r.election_timeout)
                 else:
                     lease.disarm(int(g_has[c]))
-        armed, fresh = lease.lanes_step(gs_step, clock, fed, flags)
+        armed, fresh, anchored = lease.lanes_step(
+            gs_step, clock, fed, flags, quiet)
         self.stats["lease_rows_armed"] += armed
         self.stats["lease_rows_fresh"] += fresh
+        self.stats["lease_rows_anchored"] += anchored
         return walked
 
     def device_coordinate(self, shard_id: int, replica_id=None):
@@ -1168,6 +1194,7 @@ class ColocatedVectorEngine(VectorStepEngine):
             self._host_peers[g, :] = 0
             self._tables_dirty = True
             self._wake_slot[g] = self._wake_slot_of(node)
+            node.input_hint = self._input_hint.append
         return g
 
     def _wake_slot_of(self, node) -> int:
@@ -1850,7 +1877,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         # lease_read_pct 99 -> 95 at P=5 on the chip)
         since = now - self._last_coalesce_scan
         if since < 10 * self._scan_cost or (
-                since < 0.2 and not self._scan_skipped):
+                since < _SCAN_FLOOR_S and not self._scan_skipped):
             self._scan_skipped = True
             return list(nodes)
         self._scan_skipped = False
@@ -1872,6 +1899,68 @@ class ColocatedVectorEngine(VectorStepEngine):
         if coalesced:
             self.stats["coalesced_rows"] += coalesced
         return out
+
+    def _pull_hinted(self, nodes) -> int:
+        """Append to ``nodes`` (the launch's list, which it owns) every
+        resident node the input hint names that this launch does not
+        carry yet, is not stopping and still holds queued non-tick
+        input; returns where the pulled ones start.  The cost is the
+        rows producers touched since the last launch, not the resident
+        rows the scan walks, so every launch takes it: a queued
+        proposal or read waits for the next launch, not for its own
+        member's or the next scan's.  A pulled row is drained of its
+        queued input alone (drain_step_inputs(ticks=False)): its ticks
+        wait for its own member's launch or the scan, as before, so the
+        tick feed and the lease anchors it drives are the scan's.
+
+        While the step calls' smoothed period is half the scan's floor
+        or more, the hint is taken and nothing pulled: pulled input
+        lengthens the launch that carries it, and at such periods the
+        launch the scan skips leaves the other members' rows unfed past
+        the floor, where their leaders' leases run out (PERF.md section
+        6, PR 41: 115 ms launches read lease_read_pct 92.9)."""
+        hint = self._input_hint
+        n0 = len(nodes)
+        now = _time.monotonic()
+        # an idle gap counts as one floor: it says nothing of how long
+        # a loaded launch runs
+        self._period_avg += (
+            min(now - self._pull_at, _SCAN_FLOOR_S) - self._period_avg
+        ) / 16
+        self._pull_at = now
+        if not hint:
+            return n0
+        if 2 * self._period_avg >= _SCAN_FLOOR_S:
+            hint.clear()
+            self.stats["input_hint_closed"] += 1
+            return n0
+        carried = {id(n) for n in nodes}
+        row_of, meta_of = self._row_of, self._meta
+        for _ in range(len(hint)):
+            n = hint.popleft()
+            if id(n) in carried:
+                continue
+            carried.add(id(n))
+            meta = meta_of.get(row_of.get((n.shard_id, n.replica_id), -1))
+            if (
+                meta is not None
+                and meta.node is n
+                and not n.stopped
+                and not n.stopping
+                and n.has_queued_input()
+            ):
+                nodes.append(n)
+        return n0
+
+    def _note_input_wait(self, si) -> None:
+        """Charge ``t_input_wait_ms`` with a full-path drain's wait: from
+        the first input queued since the last drain (``si.input_t0``,
+        which the drain took off its node) to this one."""
+        t0 = si.input_t0
+        if t0 and (si.received or si.proposals or si.read_indexes
+                   or si.config_changes or si.transfers):
+            self.stats["t_input_wait_ms"] += (_perf() - t0) * 1000.0
+            self.stats["input_waits"] += 1
 
     def _maybe_rebase_shards(self, nodes) -> None:
         """Whole-shard group rebasing (the colocated 64-bit story).
@@ -2001,6 +2090,7 @@ class ColocatedVectorEngine(VectorStepEngine):
         lane_node = lane.nodes.append
         tok = self._enter("t_coalesce_ms", "raft-colocated-coalesce")
         nodes = self._coalesce(nodes)
+        pulled_from = self._pull_hinted(nodes)
         self._maybe_rebase_shards(nodes)
         self._leave(tok)
         tok = self._enter("t_plan_ms", "raft-colocated-plan")
@@ -2053,7 +2143,14 @@ class ColocatedVectorEngine(VectorStepEngine):
             # per-row plan was ~57 us and t_plan was 152 s of a 269 s
             # election (10k-shard TPU run); the fast lane is ~5 us.
             g = gs_list[i]
-            meta = self._meta.get(g) if static_ok[i] else None
+            pulled = i >= pulled_from
+            if pulled:
+                if not node.has_queued_input():
+                    # the hint's row whose input a drain took since: no
+                    # ticks here, so nothing left to step
+                    continue
+                self.stats["input_rows_pulled"] += 1
+            meta = self._meta.get(g) if static_ok[i] and not pulled else None
             if (
                 meta is not None
                 and meta.node is node  # not a stale pre-restart binding
@@ -2108,7 +2205,8 @@ class ColocatedVectorEngine(VectorStepEngine):
                         self._clock_idle(node, g, ticks + gc_t)
                     continue
             # ---- full path ------------------------------------------
-            si = node.drain_step_inputs()
+            si = node.drain_step_inputs(ticks=not pulled)
+            self._note_input_wait(si)
             self._take_read_resps(node, si)
             self._count_carried_reads(node, si)
             if self._static_host_only(node):
@@ -2401,6 +2499,10 @@ class ColocatedVectorEngine(VectorStepEngine):
             si.ticks + si.gc_ticks for _n, _g, si, _plan in batch
         ]
         clock[n_act:] = lane.clock_np
+        # a batch row whose host slots held no message (a lane row is
+        # fed, so it does not matter there)
+        quiet = np.zeros((n_step,), bool)
+        quiet[:n_act] = [not si.received for _n, _g, si, _plan in batch]
         stepped = np.zeros((self.capacity,), bool)
         stepped[gs] = True
         others = [
@@ -2413,10 +2515,11 @@ class ColocatedVectorEngine(VectorStepEngine):
         skip = self._skip_mask(nodes, gs, esc_seen, n_step)
         if whole is not None:
             want = self._completion_reference(
-                rec, whole, clock, flags, vals_np, pos_sum, touched
+                rec, whole, clock, flags, vals_np, pos_sum, touched, quiet
             )
         self.stats["completion_rows_walked"] += self._lease_pass(
-            nodes, gs, clock, fed, n_step, skip, flags, vals_np, pos_sum
+            nodes, gs, clock, fed, n_step, skip, flags, vals_np, pos_sum,
+            quiet,
         )
         self._bookkeeping_pass(live)
         if len(lane):
@@ -2443,7 +2546,8 @@ class ColocatedVectorEngine(VectorStepEngine):
 
     # raftlint: ignore[host-loop] parity oracle: the per-row passes worked out over the whole live list
     def _completion_reference(self, rec, whole, clock, flags, vals_np,
-                              pos_sum, touched) -> "hostplane.CompletionTrace":
+                              pos_sum, touched,
+                              quiet) -> "hostplane.CompletionTrace":
         """What the per-row lease and bookkeeping passes make of
         ``whole``: the lease pass run on a COPY of the lease lanes
         (hostplane.lease_pass_rows) and the clocks each row ends on,
@@ -2456,6 +2560,7 @@ class ColocatedVectorEngine(VectorStepEngine):
                      zip(clock.tolist(), rec.fed.tolist()))),
             flags, vals_np, pos_sum, self._mirror[_R_ROLE],
             lambda node, g: node.stopped or meta_get(g) is None,
+            set(rec.batch_gs[quiet].tolist()),
         )
         clocks = {}
         for node, g, si in whole:

@@ -299,10 +299,14 @@ class LeaseAges:
     sat in a transport queue for a time the device cannot see.  It is
     never counted: in a launch that feeds the row a tick every host
     slot precedes the tick slot, so the clear takes it; a row stepped
-    with host input and NO tick has ``since`` set to ``LEASE_NONE``
+    with a host message and NO tick has ``since`` set to ``LEASE_NONE``
     until its next feed, and a leader whose peers answer through the
-    host reads through ReadIndex.  docs/GATEWAY.md "Lease-read safety"
-    and docs/PARITY.md carry the argument in full.
+    host reads through ReadIndex.  A row stepped with no tick whose
+    host slots held no message (proposals, reads, transfers: nothing
+    a response handler sees) keeps ``since``, as a row not stepped
+    does: the bit it ends with was set by routed answers alone.
+    docs/GATEWAY.md "Lease-read safety" and docs/PARITY.md carry the
+    argument in full.
 
     A FRESH LEADER has no bit 1 (``_become_leader`` fabricates bit 0
     only), so its first anchor is a real quorum of answers.
@@ -425,27 +429,35 @@ class LeaseAges:
 
     def lanes_step(  # hostplane-hot
         self, gs: np.ndarray, clock: np.ndarray, fed: np.ndarray,
-        flags: np.ndarray,
-    ) -> Tuple[int, int]:
+        flags: np.ndarray, quiet: np.ndarray,
+    ) -> Tuple[int, int, int]:
         """One completion's pass: ``gs`` the rows it stepped (distinct),
         ``clock`` the clock ticks each one's bookkeeping is about to
         add, ``fed`` the ticks its device row was fed, ``flags`` the
-        final round's ``[G]`` word.  Reads the peers' clocks for every
-        armed row fed a tick BEFORE any clock of this launch moves,
-        moves the clocks, anchors every armed row of the engine whose
-        flag is up at the feed it belongs to, and works out every
-        anchored row's age afresh.  Returns the armed rows stepped with
-        ticks and how many of them were anchored (``lease_rows_armed``,
-        ``lease_rows_fresh``).  :func:`lease_rows_step` is the per-row
-        twin."""
+        final round's ``[G]`` word, ``quiet`` (bool over ``gs``) the
+        rows whose host slots carried no message.  Reads the peers'
+        clocks for every armed row fed a tick BEFORE any clock of this
+        launch moves, moves the clocks, anchors every armed row of the
+        engine whose flag is up at the feed it belongs to, and works
+        out every anchored row's age afresh.  Returns the armed rows
+        stepped with ticks, how many of them were anchored, and how
+        many rows of the engine were, at this feed or an earlier one
+        (``lease_rows_armed``, ``lease_rows_fresh``,
+        ``lease_rows_anchored``).
+        :func:`lease_rows_step` is the per-row twin."""
         ticked = (self.et[gs] > 0) & (fed > 0)
         tk = gs[ticked]
         self.mark[tk] = self.clk[self.peers[tk]]
         # (a disarmed row holds LEASE_NONE, and stays there)
         self.own[gs] = np.minimum(self.own[gs] + clock, LEASE_NONE)
         # a row stepped WITHOUT a tick took host slots with no clear
-        # behind them: nothing may be anchored until its next feed
-        self.since[gs] = np.where(ticked, clock, LEASE_NONE)
+        # behind them: nothing may be anchored until its next feed --
+        # unless no slot held a message (proposals, reads: nothing that
+        # sets the bit), when it keeps what it had, as an unstepped row
+        dry = np.where(quiet & (fed == 0),
+                       np.minimum(self.since[gs] + clock, LEASE_NONE),
+                       LEASE_NONE)
+        self.since[gs] = np.where(ticked, clock, dry)
         self.clk[gs] += clock
         fresh = (flags & F_QUORUM_FRESH) != 0
         hit = np.nonzero(fresh & (self.since < self.own))[0]
@@ -455,14 +467,16 @@ class LeaseAges:
         return (
             int(np.count_nonzero(ticked)),
             int(np.count_nonzero(ticked & fresh[gs])),
+            len(hit),
         )
 
 
 def lease_rows_step(lease: LeaseAges, stepped: Dict[int, Tuple[int, int]],
-                    flags) -> None:
+                    flags, quiet) -> None:
     """Per-row twin of :meth:`LeaseAges.lanes_step`, a row at a time:
     ``stepped`` is row -> (clock ticks, ticks fed) for the rows the
-    completion stepped."""
+    completion stepped, ``quiet`` the rows of them whose host slots
+    carried no message."""
     P = lease.peers.shape[1]
     for g, (clock, fed) in stepped.items():
         if lease.et[g] > 0 and fed > 0:
@@ -470,9 +484,12 @@ def lease_rows_step(lease: LeaseAges, stepped: Dict[int, Tuple[int, int]],
                 lease.mark[g, p] = lease.clk[lease.peers[g, p]]
     for g, (clock, fed) in stepped.items():
         lease.own[g] = min(int(lease.own[g]) + clock, LEASE_NONE)
-        lease.since[g] = (
-            clock if lease.et[g] > 0 and fed > 0 else LEASE_NONE
-        )
+        if lease.et[g] > 0 and fed > 0:
+            lease.since[g] = clock
+        elif g in quiet and fed == 0:
+            lease.since[g] = min(int(lease.since[g]) + clock, LEASE_NONE)
+        else:
+            lease.since[g] = LEASE_NONE
         lease.clk[g] += clock
     for g in range(len(lease.et)):
         if int(flags[g]) & F_QUORUM_FRESH and lease.since[g] < lease.own[g]:
@@ -487,14 +504,15 @@ def lease_rows_step(lease: LeaseAges, stepped: Dict[int, Tuple[int, int]],
 
 
 def lease_pass_rows(lease: LeaseAges, whole, stepped, flags, vals_np,
-                    pos_sum, mirror_role, gone) -> None:
+                    pos_sum, mirror_role, gone, quiet) -> None:
     """Per-row twin of the colocated completion's lease pass, over
     ``whole`` (one ``(node, g, si)`` a stepped or live row) on a COPY
     of the lanes: a row whose role left or reached leader against
     ``mirror_role`` is disarmed or armed, then :func:`lease_rows_step`
     runs over the rows of ``stepped`` (row -> (clock ticks, ticks
-    fed)) that ``whole`` still holds.  ``gone(node, g)`` says a row is
-    stopped or detached since the launch."""
+    fed)) that ``whole`` still holds, ``quiet`` as there.
+    ``gone(node, g)`` says a row is stopped or detached since the
+    launch."""
     kept = {}
     for node, g, _si in whole:
         if gone(node, g):
@@ -511,7 +529,7 @@ def lease_pass_rows(lease: LeaseAges, whole, stepped, flags, vals_np,
                         lease.disarm(g)
         if g in stepped:
             kept[g] = stepped[g]
-    lease_rows_step(lease, kept, flags)
+    lease_rows_step(lease, kept, flags, quiet)
 
 
 class UpdateLanes:

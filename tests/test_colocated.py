@@ -24,6 +24,7 @@ from dragonboat_tpu import (
     NodeHost,
     NodeHostConfig,
 )
+from dragonboat_tpu.node import Node
 from dragonboat_tpu.ops.colocated import ColocatedEngineGroup
 from dragonboat_tpu.transport.inproc import reset_inproc_network
 
@@ -634,3 +635,267 @@ def test_coalesce_scan_skips_one_launch_inside_its_floor_and_no_second():
     assert [launch() for _ in range(4)] == [0, 0, 0, 0]
     core._last_coalesce_scan = time.monotonic() - 0.6
     assert launch() == 1
+
+
+def test_input_hint_pulls_only_resident_live_rows_with_input_once():
+    """The hint's consumer: of the nodes producers named since the last
+    launch, a launch pulls each resident one it does not carry yet,
+    once, if it is neither stopped nor stopping and still holds queued
+    non-tick input (``input_rows_pulled`` is the plan loop's, which
+    counts those it drains: test_launch_without_a_scan_drains_...).
+    While step calls run half the scan's floor or longer, smoothed, it
+    takes the hint and pulls nothing."""
+    from collections import deque
+    from types import SimpleNamespace
+
+    from dragonboat_tpu.ops.colocated import ColocatedVectorEngine
+
+    def node(rid, queued=True, stopped=False, stopping=False):
+        n = SimpleNamespace(shard_id=1, replica_id=rid, stopped=stopped,
+                            stopping=stopping, queued=queued)
+        n.has_queued_input = lambda: n.queued
+        return n
+
+    caller, wants, emptied, stopped, stopping, gone = (
+        node(1), node(2), node(3, queued=False), node(4, stopped=True),
+        node(5, stopping=True), node(6))
+    resident = [caller, wants, emptied, stopped, stopping]
+    core = SimpleNamespace(
+        _input_hint=deque([wants, caller, emptied, wants, stopped,
+                           stopping, gone, wants]),
+        _row_of={(1, n.replica_id): g for g, n in enumerate(resident)},
+        _meta={g: SimpleNamespace(node=n) for g, n in enumerate(resident)},
+        _pull_at=time.monotonic(), _period_avg=0.0,
+        stats={"input_hint_closed": 0})
+    # `gone` left the device: a restarted replica's new node under the
+    # old key is not the one the hint names either
+    core._row_of[(1, 6)] = 0
+
+    nodes = [caller]
+    assert ColocatedVectorEngine._pull_hinted(core, nodes) == 1
+    assert nodes == [caller, wants]
+    assert not core._input_hint
+    # an empty hint pulls nothing and costs no pass
+    assert ColocatedVectorEngine._pull_hinted(core, nodes) == 2
+    assert nodes == [caller, wants]
+    assert core.stats["input_hint_closed"] == 0
+
+    # step calls 120 ms apart: the average crosses 100 ms, the hint is
+    # taken, and nothing is pulled until the calls are short again
+    def calls(period, n):
+        pulled = []
+        for _ in range(n):
+            core._pull_at = time.monotonic() - period
+            core._input_hint.append(wants)
+            nodes = [caller]
+            ColocatedVectorEngine._pull_hinted(core, nodes)
+            pulled.append(len(nodes) - 1)
+        return pulled
+
+    long_calls = calls(0.12, 40)
+    assert long_calls[0] == 1 and long_calls[-1] == 0
+    assert core._period_avg * 2 >= 0.2 and not core._input_hint
+    assert core.stats["input_hint_closed"] == long_calls.count(0) > 0
+    assert calls(0.02, 40)[-1] == 1
+    # an idle gap (or the first call) counts as one floor, not as its
+    # length
+    core._period_avg, core._pull_at = 0.0, 0.0
+    ColocatedVectorEngine._pull_hinted(core, [caller])
+    assert core._period_avg == pytest.approx(0.2 / 16)
+
+
+def test_input_hint_loses_no_producer_under_contention():
+    """Producers on more threads than cores announce their nodes while
+    a launch thread takes the hint, with the interpreter switching
+    threads every microsecond: every node announced is pulled by some
+    launch, none twice in one, and each keeps the stamp of its first
+    announcement until a drain takes it."""
+    import sys
+    import threading
+    from collections import deque
+    from types import SimpleNamespace
+
+    from dragonboat_tpu.node import Node
+    from dragonboat_tpu.ops.colocated import ColocatedVectorEngine
+
+    hint = deque()
+    nodes = [SimpleNamespace(shard_id=i, replica_id=1, stopped=False,
+                             stopping=False, wake=None, input_t0=0.0,
+                             input_hint=hint.append,
+                             has_queued_input=lambda: True)
+             for i in range(64)]
+    core = SimpleNamespace(
+        _input_hint=hint,
+        _row_of={(n.shard_id, 1): i for i, n in enumerate(nodes)},
+        _meta={i: SimpleNamespace(node=n) for i, n in enumerate(nodes)},
+        _pull_at=0.0, _period_avg=0.0, stats={"input_hint_closed": 0})
+    n_threads = 2 * (os.cpu_count() or 1) + 2
+    go, done = threading.Event(), threading.Event()
+
+    def produce(k):
+        go.wait(10.0)
+        for j in range(400):
+            Node._wake(nodes[(k * 7 + j) % len(nodes)])
+
+    seen, twice = set(), []
+
+    def launches():
+        go.wait(10.0)
+        while True:
+            finished = done.is_set()
+            pulled = []
+            ColocatedVectorEngine._pull_hinted(core, pulled)
+            if len({id(n) for n in pulled}) != len(pulled):
+                twice.append(pulled)
+            seen.update(id(n) for n in pulled)
+            if finished and not hint:
+                return
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        producers = [threading.Thread(target=produce, args=(k,))
+                     for k in range(n_threads)]
+        consumer = threading.Thread(target=launches)
+        for t in (*producers, consumer):
+            t.start()
+        go.set()
+        for t in producers:
+            t.join(30.0)
+            assert not t.is_alive()
+        done.set()
+        consumer.join(30.0)
+        assert not consumer.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert not twice
+    assert seen == {id(n) for n in nodes}
+    assert all(n.input_t0 > 0.0 for n in nodes)
+
+
+def _launch_holding_lock(core, nodes):
+    """One launch whose caller brings ``nodes``, made inside the core
+    lock (which the caller holds) right after a scan and a short launch,
+    so that it goes without one, and no step worker runs between its
+    set-up and it."""
+    core._last_coalesce_scan = time.monotonic()
+    core._scan_skipped = False
+    core._period_avg = 0.0
+    core._phase("t_misc_ms")
+    core._step_colocated(nodes, 0)
+    core._phase("t_between_ms")
+
+
+def test_launch_without_a_scan_drains_other_members_input_and_no_ticks(
+        ccluster, monkeypatch):
+    """A launch that skips the coalesce scan still carries another
+    member's replica with a queued proposal and a third's with a queued
+    read, drains both, and takes none of their ticks (their tick lanes
+    wait for their own members' launches or the scan).  Each drain's
+    wait since its input was queued is counted, and a tick-only row
+    (the fast lane) counts none.  A row the hint names whose queues
+    are empty is neither drained nor fed, nor one the launch pulled
+    whose queues emptied before its plan reached it, and neither
+    counts as pulled.  A drain takes the replica's wait stamp."""
+    from dragonboat_tpu.request import RequestResultCode
+
+    group, nhs = ccluster
+    lead = wait_for_leader(nhs)
+    sess = nhs[lead].get_noop_session(1)
+    propose_r(nhs[lead], sess, set_cmd("warm", b"1"))
+    core = group.core
+    caller_rid, reader_rid = [r for r in ADDRS if r != lead]
+    caller, leader, reader = (nhs[r]._get_node(1)
+                              for r in (caller_rid, lead, reader_rid))
+    st = core.stats
+
+    def ticks(n):
+        return n._ticks_taken, n._pending_ticks
+
+    with core._lock:
+        for n in (caller, leader, reader):
+            n.input_t0 = 0.0
+        wrote = nhs[lead].propose(sess, set_cmd("pulled", b"v"), 10.0)
+        read = nhs[reader_rid].read_index(1, 10.0)
+        assert leader._proposals and reader.pending_read_index.queued
+        time.sleep(0.05)
+        caller.input_t0 = 1.0  # no input queued: the fast lane's row
+        before = ticks(leader), ticks(reader)
+        pulled, waits, wait_ms = (st["input_rows_pulled"],
+                                  st["input_waits"], st["t_input_wait_ms"])
+        _launch_holding_lock(core, [caller])
+        assert not leader._proposals
+        assert not reader.pending_read_index.queued
+        assert (ticks(leader), ticks(reader)) == before
+        assert st["input_rows_pulled"] - pulled == 2
+        assert st["input_waits"] - waits == 2
+        assert st["t_input_wait_ms"] - wait_ms >= 2 * 50.0
+        assert leader.input_t0 == reader.input_t0 == 0.0
+        assert caller.input_t0 == 1.0
+        caller.input_t0 = 0.0
+    assert wrote.wait(10.0) == RequestResultCode.COMPLETED
+    assert read.wait(10.0) == RequestResultCode.COMPLETED
+
+    real = Node.has_queued_input
+    for emptied_after_pull in (False, True):
+        asked = []
+
+        def queued(n):
+            # the leader held input when the launch pulled it, and none
+            # when its plan reached it
+            if emptied_after_pull and n is leader and not asked:
+                asked.append(n)
+                return True
+            return real(n)
+
+        with core._lock:
+            leader._wake()  # named by the hint, nothing queued
+            t0 = leader.input_t0
+            before = ticks(leader)
+            pulled = st["input_rows_pulled"]
+            monkeypatch.setattr(Node, "has_queued_input", queued)
+            _launch_holding_lock(core, [caller])
+            monkeypatch.setattr(Node, "has_queued_input", real)
+            assert bool(asked) == emptied_after_pull
+            assert st["input_rows_pulled"] == pulled
+            assert ticks(leader) == before
+            assert leader.input_t0 == t0 > 0.0
+            # whichever path drains a replica takes its stamp with it
+            si = leader.drain_step_inputs(ticks=False)
+            assert si.input_t0 == t0 and leader.input_t0 == 0.0
+            assert ticks(leader) == before
+    assert read_r(nhs[reader_rid], 1, "pulled") == b"v"
+
+
+def test_one_member_group_pulls_nothing():
+    """A group whose inputs all belong to the caller's member runs the
+    parent's path: the hint pulls no row."""
+    reset_inproc_network()
+    group = ColocatedEngineGroup(**GEOM)
+    shutil.rmtree("/tmp/nh-colo-solo", ignore_errors=True)
+    nh = NodeHost(NodeHostConfig(
+        nodehost_dir="/tmp/nh-colo-solo", rtt_millisecond=5,
+        raft_address=ADDRS[1],
+        expert=ExpertConfig(
+            engine=EngineConfig(exec_shards=1, apply_shards=2),
+            step_engine_factory=group.factory,
+        ),
+    ))
+    try:
+        nh.start_replica({1: ADDRS[1]}, False, KVStore,
+                         colo_shard_config(1))
+        wait_for_leader({1: nh})
+        sess = nh.get_noop_session(1)
+        for i in range(5):
+            propose_r(nh, sess, set_cmd(f"s{i}", b"1"))
+        core = group.core
+        node = nh._get_node(1)
+        with core._lock:
+            nh.propose(sess, set_cmd("own", b"1"), 10.0)
+            _launch_holding_lock(core, [node])
+            assert not node._proposals
+        assert read_r(nh, 1, "own") == b"1"
+        assert core.stats["input_rows_pulled"] == 0
+        assert core.stats["input_waits"] > 0
+    finally:
+        nh.close()

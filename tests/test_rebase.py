@@ -419,10 +419,17 @@ class TestClusterRebasing:
             wait_for_leader(nhs)
             s = nhs[1].get_noop_session(1)
             # push the log well past W (32), with periodic cold
-            # excursions so rows re-upload and recompute bases
+            # excursions so rows re-upload and recompute bases.  A
+            # follower read is no excursion: its forwarded ReadIndex is
+            # stepped on the device at both ends.  A transfer request a
+            # follower forwards over the wire is cold input: its row
+            # leaves the device for the host path and uploads again
             for i in range(80):
                 propose_r(nhs[1], s, set_cmd(f"k{i}", str(i).encode()))
                 if i % 20 == 19:
+                    lead = wait_for_leader(nhs)
+                    follower = next(r for r in ADDRS if r != lead)
+                    nhs[follower].request_leader_transfer(1, lead)
                     assert read_r(nhs[1 + i % 3], 1, f"k{i}") == \
                         str(i).encode()
             rebased = []

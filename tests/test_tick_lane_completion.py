@@ -447,6 +447,10 @@ def _groups_of_four(G):
     return (rows[:, None] // 4) * 4 + np.arange(4)[None, :]
 
 
+# no stepped row's host slots were quiet: every one held a message
+LOUD = np.zeros((64,), bool)
+
+
 def test_the_array_lease_step_is_row_step_over_every_row():
     """``LeaseAges.lanes_step`` against ``lease_rows_step`` a row, over
     seeded launches of 64 rows in shards of four: the same lanes on
@@ -479,13 +483,19 @@ def test_the_array_lease_step_is_row_step_over_every_row():
         before = a.own.copy()
         hostplane.lease_rows_step(
             b, dict(zip(gs.tolist(), zip(clock.tolist(), fed.tolist()))),
-            flags)
-        armed, fresh = a.lanes_step(gs, clock, fed, flags)
+            flags, set())
+        armed, fresh, anchored = a.lanes_step(gs, clock, fed, flags,
+                                              LOUD[:40])
         for name in ("age", "own", "since", "clk", "mark", "base"):
             assert np.array_equal(getattr(a, name), getattr(b, name)), name
         up = (flags & F_QUORUM_FRESH) != 0
         ticked = (a.et[gs] > 0) & (fed > 0)
         assert armed == ticked.sum() and fresh == (ticked & up[gs]).sum()
+        # every row of the engine whose flag is up and whose pending
+        # feed is younger than its anchor, stepped or not
+        grown = before.copy()
+        grown[gs] = np.minimum(before[gs] + clock, NONE)
+        assert anchored == (up & (a.since < grown)).sum()
         # fed with the flag up: the anchor is the clock before the feed
         # -- the row's own, and every peer's: the lane reads what the
         # peer fed most has been fed since
@@ -519,7 +529,7 @@ def test_the_array_lease_step_is_row_step_over_every_row():
     # idle ticks (quiesce, a launch that raised) age a lease, never renew
     a.arm(4, 20)
     a.lanes_step(np.asarray([4]), np.asarray([3]), np.asarray([3]),
-                 np.full((G,), F_QUORUM_FRESH, np.int32))
+                 np.full((G,), F_QUORUM_FRESH, np.int32), LOUD[:1])
     assert a.age[4] == 3
     a.idle(4, 5)
     assert a.age[4] == 8 and a.since[4] == 8
@@ -540,7 +550,8 @@ def _anchored_leader():
     lanes.arm(0, 20)
     lanes.lanes_step(
         np.arange(4), np.asarray([3, 2, 2, 2]), np.asarray([3, 2, 2, 2]),
-        np.where(np.arange(8) == 0, F_QUORUM_FRESH, 0).astype(np.int32))
+        np.where(np.arange(8) == 0, F_QUORUM_FRESH, 0).astype(np.int32),
+        LOUD[:4])
     assert lanes.own[0] == 3 and lanes.age[0] == 3
     return lanes
 
@@ -560,13 +571,13 @@ def test_a_leader_that_is_not_stepped_ages_by_its_peers_clocks(starved):
         else:
             # stepped with host input, its ticker silent: no tick fed
             gs, t = np.asarray([0, 1, 2]), np.asarray([0, 2, 1])
-        lanes.lanes_step(gs, t, t, NO_FLAGS)
+        lanes.lanes_step(gs, t, t, NO_FLAGS, LOUD[:len(gs)])
         assert lanes.own[0] == 3
         assert lanes.age[0] == 2 + 2 * n  # row 1: 2 in the anchor's launch
     assert lanes.age[0] == 18  # ET - margin: no lease read from here on
     # another shard's clocks are nothing to it
     lanes.lanes_step(np.asarray([5, 6]), np.asarray([9, 9]),
-                     np.asarray([9, 9]), NO_FLAGS)
+                     np.asarray([9, 9]), NO_FLAGS, LOUD[:2])
     assert lanes.age[0] == 18
 
 
@@ -598,11 +609,78 @@ def test_a_peer_the_engine_stops_counting_ends_the_lease(how):
         lanes.idle(np.asarray([3]), np.asarray([7]))
         assert lanes.age[0] == 3
         lanes.lanes_step(np.asarray([4]), np.asarray([1]), np.asarray([1]),
-                         NO_FLAGS)
+                         NO_FLAGS, LOUD[:1])
         assert lanes.age[0] == 9
     # a later quorum of answers anchors it again, at clocks read afresh
     gs = np.arange(4)
     lanes.lanes_step(gs, np.full(4, 2), np.full(4, 2),
                      np.where(np.arange(8) == 0, F_QUORUM_FRESH,
-                              0).astype(np.int32))
+                              0).astype(np.int32), LOUD[:4])
     assert lanes.age[0] == 2 and lanes.own[0] == 2
+
+
+@pytest.mark.parametrize("heard", [False, True])
+def test_a_dry_step_keeps_its_pending_anchor_unless_a_message_came(heard):
+    """A row stepped with no tick whose host slots held only proposals
+    or reads keeps the feed its evidence belongs to, as a row not
+    stepped does: the answers that raise its flag afterwards were routed
+    and anchor it at that feed.  With a message in its host slots (a
+    response may have come through the host) it anchors nothing until
+    its next feed.  The per-row twin agrees either way."""
+    from dragonboat_tpu.ops.types import F_QUORUM_FRESH
+
+    up = np.where(np.arange(8) == 0, F_QUORUM_FRESH, 0).astype(np.int32)
+    lanes = hostplane.LeaseAges(8, 4)
+    lanes.set_peers(_groups_of_four(8))
+    lanes.arm(0, 20)
+    # a tick feed whose answers have not come back: pending at 3
+    lanes.lanes_step(np.arange(4), np.asarray([3, 2, 2, 2]),
+                     np.asarray([3, 2, 2, 2]), NO_FLAGS, LOUD[:4])
+    assert lanes.since[0] == 3 and lanes.own[0] == NONE
+    twin = lanes.copy()
+    quiet = np.asarray([not heard])
+    armed, fresh, anchored = lanes.lanes_step(
+        np.asarray([0]), np.asarray([0]), np.asarray([0]), up, quiet)
+    hostplane.lease_rows_step(twin, {0: (0, 0)}, up,
+                              set() if heard else {0})
+    for name in ("age", "own", "since", "clk", "mark", "base"):
+        assert np.array_equal(getattr(lanes, name), getattr(twin, name))
+    # no feed, so nothing counted fresh; a pending feed anchored late
+    assert (armed, fresh, anchored) == (0, 0, 0 if heard else 1)
+    if heard:
+        assert lanes.since[0] == NONE and lanes.own[0] == NONE
+    else:
+        # anchored at the feed it belongs to: 3 ticks old on its own
+        # clock, as old as its peers fed most since that reading
+        assert lanes.own[0] == 3 and lanes.age[0] == 3
+
+
+def test_the_array_lease_step_is_row_step_with_dry_rows():
+    """``lanes_step``'s ``quiet`` against ``lease_rows_step``'s over
+    seeded launches in which some rows are stepped with no tick and no
+    message."""
+    from dragonboat_tpu.ops.types import F_QUORUM_FRESH
+
+    rng = np.random.default_rng(41)
+    G = 32
+    a, b = hostplane.LeaseAges(G, 4), hostplane.LeaseAges(G, 4)
+    for lanes in (a, b):
+        lanes.set_peers(_groups_of_four(G))
+        for g in range(0, G, 2):
+            lanes.arm(g, 20)
+    kept = 0
+    for _ in range(100):
+        gs = np.sort(rng.choice(G, size=20, replace=False)).astype(np.int64)
+        fed = rng.integers(0, 3, size=20).astype(np.int64)
+        clock = fed + (rng.random(20) < 0.1)
+        quiet = rng.random(20) < 0.5
+        flags = np.where(rng.random(G) < 0.5, F_QUORUM_FRESH,
+                         0).astype(np.int32)
+        hostplane.lease_rows_step(
+            b, dict(zip(gs.tolist(), zip(clock.tolist(), fed.tolist()))),
+            flags, set(gs[quiet].tolist()))
+        a.lanes_step(gs, clock, fed, flags, quiet)
+        for name in ("age", "own", "since", "clk", "mark", "base"):
+            assert np.array_equal(getattr(a, name), getattr(b, name)), name
+        kept += int((quiet & (fed == 0) & (a.since[gs] < NONE)).sum())
+    assert kept > 0

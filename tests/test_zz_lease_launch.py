@@ -276,11 +276,12 @@ def test_a_row_that_changes_hands_between_the_probes_loads_yields_no_lease():
     et = old.peer.raft.election_timeout
     fresh = np.full((4,), F_QUORUM_FRESH, np.int32)
     one = np.asarray([2])
+    loud = np.zeros((1,), bool)  # its host slots held a message
 
     lanes.arm(2, et)
     assert old.lease_cell == (lanes, 2, int(lanes.token[2]))
     assert Node.lease_probe(old, 2) == (LEASE_MISS_EXPIRING, 0)  # no anchor
-    lanes.lanes_step(one, np.asarray([3]), np.asarray([3]), fresh)
+    lanes.lanes_step(one, np.asarray([3]), np.asarray([3]), fresh, loud)
     assert Node.lease_probe(old, 2) == (LEASE_HELD, et - 3)
     stale_cell = old.lease_cell
 
@@ -295,7 +296,8 @@ def test_a_row_that_changes_hands_between_the_probes_loads_yields_no_lease():
             lanes.disarm(g)
             owner[g] = new
             lanes.arm(g, et)
-            lanes.lanes_step(one, np.asarray([1]), np.asarray([1]), fresh)
+            lanes.lanes_step(one, np.asarray([1]), np.asarray([1]), fresh,
+                             loud)
             return real_age[g]
 
     real_age = lanes.age
@@ -325,7 +327,8 @@ def test_the_lease_cell_keeps_the_scalar_gates():
     et = r.election_timeout
     lanes.arm(0, et)
     lanes.lanes_step(np.asarray([0]), np.asarray([4]), np.asarray([4]),
-                     np.full((2,), F_QUORUM_FRESH, np.int32))
+                     np.full((2,), F_QUORUM_FRESH, np.int32),
+                     np.zeros((1,), bool))
     assert Node.lease_probe(node, 2) == (LEASE_HELD, et - 4)
     # fresher anchors in the scalar remotes change nothing for a row
     # the engine steps; they stand once it has let the row go
@@ -338,7 +341,8 @@ def test_the_lease_cell_keeps_the_scalar_gates():
     lanes.arm(0, et)
     assert Node.lease_probe(node, 2) == (LEASE_MISS_EXPIRING, 0)
     lanes.lanes_step(np.asarray([0]), np.asarray([4]), np.asarray([4]),
-                     np.full((2,), F_QUORUM_FRESH, np.int32))
+                     np.full((2,), F_QUORUM_FRESH, np.int32),
+                     np.zeros((1,), bool))
     for rm in r.all_remotes().values():
         rm.last_resp_tick = -1
     r.leader_transfer_target = 2
